@@ -4,8 +4,9 @@ Runs the headline bench functions at alternative configs to find the
 best-throughput operating points (the headline BENCH artifact keeps its
 fixed config for round-over-round comparability; this sweep documents
 where the ceiling is). One JSON line per config to stdout + appended to
-the sweep artifact (`DL4J_SWEEP_OUT`, default repo-root SWEEP.jsonl —
-`scripts/tunnel_window.sh` points it into the live-window capture dir).
+the sweep artifact (`DL4J_SWEEP_OUT`, default repo-root SWEEP.jsonl).
+Needs an accelerator; a config that fails stops the sweep with its
+error.
 
 Usage: python benchtools/bench_sweep.py [resnet|transformer|all]
 """
@@ -34,19 +35,15 @@ def emit(tag, rec):
 
 
 def sweep_resnet(accel):
-    # batch sweep incl. the round-4 b256<b128 anomaly: vary steps at
-    # b256 to separate working-set effects (the fused window stacks
-    # steps x batch images on HBM) from per-step compute
+    # batch sweep: vary steps at b256 to separate working-set effects
+    # (the fused window stacks steps x batch images on HBM) from
+    # per-step compute
     for batch, steps in ((64, 20), (128, 20), (192, 20), (256, 20),
                          (256, 10), (256, 5)):
-        try:
-            r = bench.bench_resnet50(accel, batch=batch, steps=steps,
-                                     with_etl=False)
-            r.pop("device_diagnostics", None)
-            emit(f"resnet50_b{batch}_s{steps}", r)
-        except Exception as e:
-            emit(f"resnet50_b{batch}_s{steps}",
-                 {"error": f"{type(e).__name__}: {e}"[:300]})
+        r = bench.bench_resnet50(accel, batch=batch, steps=steps,
+                                 with_etl=False)
+        r.pop("device_diagnostics", None)
+        emit(f"resnet50_b{batch}_s{steps}", r)
 
 
 def sweep_transformer(accel):
@@ -58,26 +55,17 @@ def sweep_transformer(accel):
         (8, 2048, 512, 8, 8),     # long-context: flash attention tiling
     ]
     for B, T, d, L, H in configs:
-        try:
-            r = bench.bench_transformer_lm(accel, B=B, T=T, d_model=d,
-                                           n_layers=L, n_heads=H)
-            emit(f"transformer_B{B}_T{T}_d{d}_L{L}", r)
-        except Exception as e:
-            emit(f"transformer_B{B}_T{T}_d{d}_L{L}",
-                 {"error": f"{type(e).__name__}: {e}"[:300]})
+        r = bench.bench_transformer_lm(accel, B=B, T=T, d_model=d,
+                                       n_layers=L, n_heads=H)
+        emit(f"transformer_B{B}_T{T}_d{d}_L{L}", r)
 
 
 def main():
     what = sys.argv[1] if len(sys.argv) > 1 else "all"
-    info = bench._probe_backend()
-    if info is None:
-        return
-    plat, kind, accel, _ = info
-    try:
-        from deeplearning4j_tpu.nd import enable_compilation_cache
-        enable_compilation_cache()
-    except Exception:
-        pass
+    plat, kind = bench.require_accelerator()
+    accel = True
+    from deeplearning4j_tpu.nd import enable_compilation_cache
+    enable_compilation_cache()
     emit("env", {"platform": plat, "device_kind": kind,
                  "diagnostics": bench._device_diagnostics()})
     if what in ("resnet", "all"):

@@ -1,10 +1,10 @@
-"""One-shot on-chip profile of the ResNet-50 train step (VERDICT r4 #2:
-"profile one train step on chip, commit the top-10 HLO cost table").
+"""One-shot on-chip profile of the ResNet-50 train step.
 
 Runs the SAME AOT fused executable the headline bench times, under
 `jax.profiler.trace`, then post-processes the captured xplane into a
 per-op cost table (self-time aggregated by HLO category and by op
-name), printed as JSON and written to PROFILE_r05/.
+name), printed as JSON and written to `DL4J_PROFILE_OUT` (default
+PROFILE_live/).
 
 Usage: python benchtools/profile_resnet.py [batch] [steps]
 (defaults 128 / 20 — the headline operating point).
@@ -90,10 +90,7 @@ def main():
     os.makedirs(OUTDIR, exist_ok=True)
 
     from deeplearning4j_tpu import bench
-    info = bench._probe_backend()
-    if info is None:
-        return
-    plat, kind, accel, _ = info
+    bench.require_accelerator()
     from deeplearning4j_tpu.nd import enable_compilation_cache
     enable_compilation_cache()
 
@@ -102,30 +99,29 @@ def main():
     # run the headline bench once with the profiler wrapped around it —
     # the timed windows inside are exactly the fused executable
     with jax.profiler.trace(logdir):
-        result = bench.bench_resnet50(accel, batch=batch, steps=steps,
+        result = bench.bench_resnet50(True, batch=batch, steps=steps,
                                       with_etl=False)
     parsed = parse_xplane(logdir)
-    if parsed and not parsed[0]:
-        parsed = None   # trace captured but no device plane (CPU run)
+    if not parsed or not parsed[0]:
+        raise SystemExit(
+            f"profile_resnet: the trace under {logdir} holds no device "
+            f"plane — nothing to reduce")
     report = {"bench": {k: result[k] for k in
                         ("value", "mfu", "achieved_tflops", "batch",
                          "seconds") if k in result}}
-    if parsed:
-        totals, device_total = parsed
-        by_cat = {}
-        for name, ps in totals.items():
-            by_cat[categorize(name)] = by_cat.get(categorize(name), 0) + ps
-        top_ops = sorted(totals.items(), key=lambda kv: -kv[1])[:25]
-        report["device_total_ms"] = device_total / 1e9
-        report["by_category_pct"] = {
-            k: round(100.0 * v / max(device_total, 1), 2)
-            for k, v in sorted(by_cat.items(), key=lambda kv: -kv[1])}
-        report["top_ops"] = [
-            {"name": n[:120], "ms": round(ps / 1e9, 3),
-             "pct": round(100.0 * ps / max(device_total, 1), 2)}
-            for n, ps in top_ops]
-    else:
-        report["error"] = "no xplane captured (CPU backend or trace off)"
+    totals, device_total = parsed
+    by_cat = {}
+    for name, ps in totals.items():
+        by_cat[categorize(name)] = by_cat.get(categorize(name), 0) + ps
+    top_ops = sorted(totals.items(), key=lambda kv: -kv[1])[:25]
+    report["device_total_ms"] = device_total / 1e9
+    report["by_category_pct"] = {
+        k: round(100.0 * v / max(device_total, 1), 2)
+        for k, v in sorted(by_cat.items(), key=lambda kv: -kv[1])}
+    report["top_ops"] = [
+        {"name": n[:120], "ms": round(ps / 1e9, 3),
+         "pct": round(100.0 * ps / max(device_total, 1), 2)}
+        for n, ps in top_ops]
     out_path = os.path.join(OUTDIR, f"profile_b{batch}.json")
     with open(out_path, "w") as f:
         json.dump(report, f, indent=1)

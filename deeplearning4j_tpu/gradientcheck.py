@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.parallel.compat import enable_x64
 
 
 def check_gradients_fn(
@@ -44,7 +43,7 @@ def check_gradients_fn(
 
     Returns (ok, max_rel_err, failures).
     """
-    with enable_x64(True):
+    with jax.enable_x64(True):
         params64 = jax.tree_util.tree_map(
             lambda a: jnp.asarray(np.asarray(a), jnp.float64), params)
         loss64 = jax.jit(lambda p: jnp.asarray(loss_fn(p), jnp.float64))

@@ -39,7 +39,9 @@ Design (streaming flash blocking — VMEM use independent of T):
 
 Runs in Pallas interpret mode on CPU (how the tests validate parity —
 both forward values and gradients against the XLA reference);
-compiled mode on TPU.
+compiled by Mosaic everywhere else. Every `pallas_call` carries a
+stable `name` (`KERNEL_NAMES`) so a lowered program or a device trace
+can be searched for it.
 """
 
 from __future__ import annotations
@@ -50,32 +52,28 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-# pallas compat: new API spells a squeezed block dim `pl.squeezed`;
-# the 0.4.x line uses None in block_shape with identical semantics
-_SQUEEZED = getattr(pl, "squeezed", None)
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
+_FWD_NAME, _BWD_DQ_NAME, _BWD_DKV_NAME = KERNEL_NAMES = (
+    "dl4tpu_flash_fwd", "dl4tpu_flash_bwd_dq", "dl4tpu_flash_bwd_dkv")
+
 # batch/head/major-block grid dims are embarrassingly parallel; only the
 # minor accumulation dim must run sequentially (the scratch carries
 # state across it). Telling Mosaic this unlocks cross-step pipelining.
-try:
-    _COMPILER_PARAMS = pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
-except Exception:  # older pallas: TPUCompilerParams spelling
-    _COMPILER_PARAMS = pltpu.TPUCompilerParams(
-        dimension_semantics=("parallel", "parallel", "parallel",
-                             "arbitrary"))
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
 def _resolve_interpret(interpret):
-    """None → compiled on TPU, interpret elsewhere. One definition so
-    the primal and both vjp halves can never disagree."""
+    """None → the Pallas interpreter on the CPU backend (there is no
+    Mosaic there; it is how the parity tests run), compiled by Mosaic on
+    every other backend — where a kernel the compiler refuses raises.
+    One definition so the primal and both vjp halves can never
+    disagree."""
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return jax.default_backend() == "cpu"
     return interpret
 
 
@@ -187,14 +185,14 @@ def _fwd_pallas_call(q, k, v, state, *, block_q, block_k, causal,
     qt, kt, vt = (jnp.transpose(a, (0, 2, 1, 3)) for a in (q, k, v))
     n_q, n_k = Tqp // bq, Tkp // bk
 
-    q_blk = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bq, D),
+    q_blk = pl.BlockSpec((pl.squeezed, pl.squeezed, bq, D),
                          lambda b, h, i, j: (b, h, i, 0))
-    k_blk = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bk, D),
+    k_blk = pl.BlockSpec((pl.squeezed, pl.squeezed, bk, D),
                          lambda b, h, i, j: (b, h, j, 0))
     # trailing singleton: Mosaic wants the block's last two dims
     # divisible by (8, 128) or equal to the array's — [bq, 1]
     # qualifies, a rank-1 [bq] block does not
-    row_q = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bq, 1),
+    row_q = pl.BlockSpec((pl.squeezed, pl.squeezed, bq, 1),
                          lambda b, h, i, j: (b, h, i, 0))
 
     outs = pl.pallas_call(
@@ -219,6 +217,7 @@ def _fwd_pallas_call(q, k, v, state, *, block_q, block_k, causal,
         ],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=_FWD_NAME,
     )(qt, kt, vt, m, l, acc)
     if finalize:
         out, lse = outs
@@ -387,11 +386,11 @@ def _bwd_dq_chunk(q, k, v, do, lse, delta, *, causal, block_q, block_k,
     qt, kt, vt, dot = (jnp.transpose(a, (0, 2, 1, 3))
                        for a in (q, k, v, do))
     n_q, n_k = Tqp // bq, Tkp // bk
-    q_blk = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bq, D),
+    q_blk = pl.BlockSpec((pl.squeezed, pl.squeezed, bq, D),
                          lambda b, h, i, j: (b, h, i, 0))
-    k_blk = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bk, D),
+    k_blk = pl.BlockSpec((pl.squeezed, pl.squeezed, bk, D),
                          lambda b, h, i, j: (b, h, j, 0))
-    row_q = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bq, 1),
+    row_q = pl.BlockSpec((pl.squeezed, pl.squeezed, bq, 1),
                          lambda b, h, i, j: (b, h, i, 0))
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, block_q=bq, block_k=bk,
@@ -403,6 +402,7 @@ def _bwd_dq_chunk(q, k, v, do, lse, delta, *, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=_BWD_DQ_NAME,
     )(qt, kt, vt, dot, lse4, delta4)
     return jnp.transpose(dq, (0, 2, 1, 3))[:, :Tq]
 
@@ -425,11 +425,11 @@ def _bwd_dkv_chunk(q, k, v, do, lse, delta, *, causal, block_q, block_k,
     n_q, n_k = Tqp // bq, Tkp // bk
     # k-major grid: k/v (and dk/dv outputs) blocked by grid dim 2,
     # q/do/lse/Δ streamed by the minor dim 3
-    kv_blk = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bk, D),
+    kv_blk = pl.BlockSpec((pl.squeezed, pl.squeezed, bk, D),
                           lambda b, h, i, j: (b, h, i, 0))
-    q_stream = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bq, D),
+    q_stream = pl.BlockSpec((pl.squeezed, pl.squeezed, bq, D),
                             lambda b, h, i, j: (b, h, j, 0))
-    row_stream = pl.BlockSpec((_SQUEEZED, _SQUEEZED, bq, 1),
+    row_stream = pl.BlockSpec((pl.squeezed, pl.squeezed, bq, 1),
                               lambda b, h, i, j: (b, h, j, 0))
     dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, block_q=bq, block_k=bk,
@@ -446,6 +446,7 @@ def _bwd_dkv_chunk(q, k, v, do, lse, delta, *, causal, block_q, block_k,
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
+        name=_BWD_DKV_NAME,
     )(qt, kt, vt, dot, lse4, delta4)
     untr = lambda a: jnp.transpose(a, (0, 2, 1, 3))[:, :Tk]  # noqa: E731
     return untr(dk), untr(dv)
@@ -488,7 +489,7 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
     """[B, T, H, D] x3 → [B, T, H, D]. Pallas forward AND backward (the
     flash two-kernel recompute — no [T, T] materialization either way,
     and O(block) VMEM so long sequences stream). `interpret=None`
-    auto-selects (compiled on TPU, interpret elsewhere).
+    auto-selects (`_resolve_interpret`).
 
     Default blocks (512, 1024) are the measured v5e sweet spot: larger
     tiles amortize the per-step DMA/loop overhead while the fp32

@@ -22,8 +22,9 @@ Design (same conventions as `flash_attention.py`):
   LayerNorm gradient evaluated with jnp ops from the saved statistics
   (a handful of fused elementwise/reduce ops — XLA handles those well;
   the HBM win lives in the forward's fusion);
-- interpret mode on CPU (how the tests validate parity), compiled on
-  TPU; `kernels_enabled()` gates dispatch (DL4J_PALLAS_KERNELS).
+- interpret mode on CPU (how the tests validate parity), compiled by
+  Mosaic elsewhere; `kernels_enabled()` gates dispatch
+  (DL4J_PALLAS_KERNELS).
 """
 
 from __future__ import annotations
@@ -33,23 +34,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.kernels.flash_attention import (
-    _COMPILER_PARAMS as _FLASH_PARAMS,  # noqa: F401  (grid here is 1-D)
     _ceil_to,
     _resolve_interpret,
 )
 
-try:
-    from jax.experimental.pallas import tpu as pltpu
-    _LN_PARAMS = None
-    try:
-        _LN_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel",))
-    except Exception:  # noqa: BLE001 — older pallas spelling
-        _LN_PARAMS = pltpu.TPUCompilerParams(
-            dimension_semantics=("parallel",))
-except Exception:  # noqa: BLE001 — pallas tpu backend unavailable
-    _LN_PARAMS = None
+KERNEL_NAMES = ("dl4tpu_layer_norm", "dl4tpu_residual_layer_norm")
+
+_LN_PARAMS = pltpu.CompilerParams(dimension_semantics=("parallel",))
 
 
 def _ln_kernel(x_ref, g_ref, b_ref, y_ref, mean_ref, rstd_ref, *,
@@ -84,16 +78,14 @@ def _row_geometry(R: int, block_rows: int):
     return br, Rp
 
 
-def _ln_call(kernel, ins, R, D, dtype, br, Rp, interpret, n_dense_out):
+def _ln_call(kernel, name, ins, R, D, dtype, br, Rp, interpret,
+             n_dense_out):
     """Shared pallas_call driver: `n_dense_out` [Rp, D] outputs followed
     by the mean/rstd [Rp, 1] statistics."""
     row_blk = pl.BlockSpec((br, D), lambda i: (i, 0))
     vec_blk = pl.BlockSpec((1, D), lambda i: (0, 0))
     stat_blk = pl.BlockSpec((br, 1), lambda i: (i, 0))
     n_in_rows = len(ins) - 2          # trailing two are gamma/beta
-    kw = {}
-    if _LN_PARAMS is not None and not interpret:
-        kw["compiler_params"] = _LN_PARAMS
     return pl.pallas_call(
         kernel,
         grid=(Rp // br,),
@@ -102,8 +94,9 @@ def _ln_call(kernel, ins, R, D, dtype, br, Rp, interpret, n_dense_out):
         out_shape=(
             [jax.ShapeDtypeStruct((Rp, D), dtype)] * n_dense_out
             + [jax.ShapeDtypeStruct((Rp, 1), jnp.float32)] * 2),
+        compiler_params=_LN_PARAMS,
         interpret=interpret,
-        **kw,
+        name=name,
     )(*ins)
 
 
@@ -151,7 +144,7 @@ def _ln_forward(x, gamma, beta, eps, block_rows, interpret):
     g2 = gamma.reshape(1, D)
     b2 = beta.reshape(1, D)
     y, mean, rstd = _ln_call(
-        functools.partial(_ln_kernel, eps=float(eps)),
+        functools.partial(_ln_kernel, eps=float(eps)), KERNEL_NAMES[0],
         (x2, g2, b2), R, D, x.dtype, br, Rp, interpret, n_dense_out=1)
     return y[:R].reshape(shape), mean[:R], rstd[:R]
 
@@ -197,7 +190,7 @@ def _res_ln_forward(x, h, gamma, beta, eps, block_rows, interpret):
     b2 = beta.reshape(1, D)
     s, y, mean, rstd = _ln_call(
         functools.partial(_residual_ln_kernel, eps=float(eps)),
-        (x2, h2, g2, b2), R, D, x.dtype, br, Rp, interpret,
+        KERNEL_NAMES[1], (x2, h2, g2, b2), R, D, x.dtype, br, Rp, interpret,
         n_dense_out=2)
     return s[:R].reshape(shape), y[:R].reshape(shape), mean[:R], rstd[:R]
 

@@ -1,15 +1,20 @@
-"""Persistent XLA compilation cache.
+"""Persistent XLA compilation cache — one rule for where it lives.
 
 The reference pays no compile step (libnd4j kernels are prebuilt); the
-XLA equivalent cost is jit compilation — minutes for ResNet-class
-programs on a real TPU, paid again in every new process. Pointing JAX's
-persistent compilation cache at a directory makes that a one-time cost
-per (program, backend) pair: later processes deserialize the compiled
-executable instead of recompiling.
+XLA equivalent cost is jit compilation — minutes for a cold serving
+warmup grid or a ResNet-class train step, paid again in every new
+process. JAX's persistent compilation cache makes that a one-time cost
+per (program, backend) pair.
 
-This is the workspace-warmup analogue of the reference's ahead-of-time
-native kernels (SURVEY.md §0: libnd4j ships compiled; our compiles must
-be cached to compete on startup latency).
+The rule: where `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads
+it and this module sets NO directory in code — the operator (or the
+chip tool) placed the cache and it stays there. Where it is unset, the
+cache is one fixed directory inside the checkout (`CACHE_ROOT`,
+git-ignored): the path is part of what makes a later process find the
+entries again, so it must not move with `$HOME` or the run. Entry
+points call `enable_compilation_cache()` once, before they compile
+(`chip_smoke.py`, `bench.main`, the replica worker, the test conftest,
+the loadtest scripts); library code never re-points the cache.
 """
 
 from __future__ import annotations
@@ -17,29 +22,31 @@ from __future__ import annotations
 import os
 from pathlib import Path
 
-_DEFAULT_DIR = os.path.join(os.path.expanduser("~"), ".cache", "dl4tpu-xla")
+# <checkout>/.jax_cache — listed in .gitignore and .chiprunignore
+CACHE_ROOT = Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-def enable_compilation_cache(cache_dir: str | None = None,
+def enable_compilation_cache(subdir: str = "",
                              min_compile_time_secs: float = 1.0) -> str:
-    """Persist compiled XLA executables under `cache_dir` (created if
-    missing; default `~/.cache/dl4tpu-xla`). Programs whose compile took
-    at least `min_compile_time_secs` are cached — keep the threshold
-    above zero in production so trivial compiles don't churn the disk;
-    tests pass 0 to observe the cache deterministically.
+    """Turn the persistent compilation cache on and return its
+    directory. With `JAX_COMPILATION_CACHE_DIR` set (or a directory
+    already in effect from an earlier call) the directory is left
+    alone; otherwise it becomes `CACHE_ROOT / subdir` (`subdir` is the
+    test suite's per-machine namespace — XLA:CPU executables are not
+    portable across host CPUs).
 
-    Returns the cache directory path. Safe to call more than once."""
+    Programs whose compile took at least `min_compile_time_secs` are
+    cached: serving grids are many small programs, so serving entry
+    points pass 0; the default keeps trivial compiles off the disk."""
     import jax
 
-    path = Path(cache_dir or _DEFAULT_DIR).expanduser()
-    path.mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(path))
+    if (not os.environ.get("JAX_COMPILATION_CACHE_DIR")
+            and jax.config.jax_compilation_cache_dir is None):
+        path = CACHE_ROOT / subdir
+        path.mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", str(path))
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_time_secs))
-    # cache everything the backend supports serializing, not just
-    # autotuned programs
-    try:
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass  # older jax: option absent, defaults are fine
-    return str(path)
+    # cache everything the backend can serialize, not only large entries
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return str(jax.config.jax_compilation_cache_dir)

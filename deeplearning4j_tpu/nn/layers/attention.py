@@ -24,82 +24,6 @@ from deeplearning4j_tpu.nd import quant
 from deeplearning4j_tpu.nn.conf.inputs import InputType, InputTypeRecurrent
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 
-_FLASH_OK: dict = {}   # backend name -> probe verdict (once per backend)
-
-
-def _flash_available() -> bool:
-    """Eagerly compile-and-run the Pallas flash kernel once on tiny
-    shapes for the current backend. This is the helper seam's
-    availability check (reference `ConvolutionLayer.java:76-80` probes
-    for the cuDNN helper class): a kernel that fails to COMPILE would
-    otherwise only surface at jit-compile time of the whole train step —
-    outside any try/except a traced forward could place — so auto mode
-    must decide eagerly, before tracing."""
-    import jax.numpy as jnp
-
-    backend = jax.default_backend()
-    if backend not in _FLASH_OK:
-        try:
-            from deeplearning4j_tpu.kernels import flash_attention
-            q = jnp.zeros((1, 128, 1, 8), jnp.float32)
-            jax.block_until_ready(flash_attention(q, q, q, False))
-            _FLASH_OK[backend] = True
-        except Exception as e:
-            import logging
-            logging.getLogger(__name__).warning(
-                "flash attention kernel unavailable on %s (%s: %s); "
-                "auto mode will use the XLA attention path",
-                backend, type(e).__name__, e)
-            _FLASH_OK[backend] = False
-    return _FLASH_OK[backend]
-
-
-_SP_FLASH_OK: dict = {}   # backend name -> carry/chunk-kernel verdict
-
-
-def _sp_flash_available() -> bool:
-    """Availability probe for the kernels the SEQUENCE-PARALLEL flash
-    path actually runs — `flash_attention_carry` plus the chunked
-    backward kernels — which `_flash_available` (plain forward only)
-    does not vouch for. Same eager-compile rationale: a kernel that
-    fails to compile must be discovered before the whole train step is
-    traced."""
-    import jax.numpy as jnp
-
-    backend = jax.default_backend()
-    if backend not in _SP_FLASH_OK:
-        try:
-            from deeplearning4j_tpu.kernels.flash_attention import (
-                _NEG_INF, _bwd_dkv_chunk, _bwd_dq_chunk,
-                flash_attention_carry,
-            )
-            q = jnp.zeros((1, 128, 1, 8), jnp.float32)
-            m = jnp.full((1, 1, 128), _NEG_INF, jnp.float32)
-            l = jnp.zeros((1, 1, 128), jnp.float32)
-            acc = jnp.zeros((1, 1, 128, 8), jnp.float32)
-            m, l, acc = flash_attention_carry(q, q, q, m, l, acc,
-                                              diag=True)
-            jax.block_until_ready(acc)
-            lse = jnp.zeros((1, 1, 128), jnp.float32)
-            delta = jnp.zeros((1, 1, 128), jnp.float32)
-            jax.block_until_ready(
-                _bwd_dq_chunk(q, q, q, q, lse, delta, causal=True,
-                              block_q=512, block_k=1024, interpret=None))
-            jax.block_until_ready(
-                _bwd_dkv_chunk(q, q, q, q, lse, delta, causal=False,
-                               block_q=512, block_k=1024,
-                               interpret=None)[0])
-            _SP_FLASH_OK[backend] = True
-        except Exception as e:
-            import logging
-            logging.getLogger(__name__).warning(
-                "flash carry/chunk kernels unavailable on %s (%s: %s); "
-                "sequence-parallel auto mode will use the XLA path",
-                backend, type(e).__name__, e)
-            _SP_FLASH_OK[backend] = False
-    return _SP_FLASH_OK[backend]
-
-
 _SP_FALLBACK_WARNED = set()
 
 
@@ -107,7 +31,7 @@ def _warn_sp_fallback(layer_name, reason):
     """One-time notice when a layer CONFIGURED for sequence parallelism
     takes the local-attention path — exactly the long-context cases the
     user enabled SP for, so silence would read as 'SP is on' while
-    memory/perf stay unchanged (same pattern as _flash_available)."""
+    memory/perf stay unchanged."""
     key = (layer_name, reason)
     if key not in _SP_FALLBACK_WARNED:
         _SP_FALLBACK_WARNED.add(key)
@@ -130,7 +54,7 @@ class MultiHeadAttention(Layer):
     causal: bool = False
     has_bias: bool = True
     attention_dropout: Optional[float] = None  # retain prob on attn weights
-    use_flash: Optional[bool] = None  # Pallas kernel; None → auto (TPU only)
+    use_flash: Optional[bool] = None  # Pallas kernel; None → on a TPU
     # long-context: "ring" (ppermute K/V rotation) or "ulysses"
     # (all-to-all head sharding) over the ambient mesh installed by
     # `parallel.sequence_sharding(mesh, axis)`. The config carries only
@@ -356,9 +280,7 @@ class MultiHeadAttention(Layer):
                 # behavior compose (both fwd and bwd are kernel-backed)
                 sp_flash = self.use_flash
                 if sp_flash is None:
-                    sp_flash = (jax.default_backend() == "tpu"
-                                and _flash_available()
-                                and _sp_flash_available())
+                    sp_flash = jax.default_backend() == "tpu"
                 if self.sequence_parallel == "ring":
                     from deeplearning4j_tpu.parallel import (
                         sequence_parallel_attention)
@@ -390,11 +312,9 @@ class MultiHeadAttention(Layer):
                               "; ".join(reasons))
         use_flash = self.use_flash
         if use_flash is None:
-            # auto mode probes kernel availability eagerly (a compile
-            # failure inside a jitted train step could not be caught);
-            # use_flash=True skips the probe so a forced-but-broken
-            # kernel surfaces its real error
-            use_flash = jax.default_backend() == "tpu" and _flash_available()
+            # auto = the platform: on a TPU the kernel IS the attention
+            # path, and a kernel Mosaic refuses fails the step's compile
+            use_flash = jax.default_backend() == "tpu"
         if (use_flash and plain):
             # Pallas fused fast path (the cuDNN-helper role)
             from deeplearning4j_tpu.kernels import flash_attention

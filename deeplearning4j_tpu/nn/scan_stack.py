@@ -44,10 +44,8 @@ touching code).
 
 from __future__ import annotations
 
-import contextlib
 import json
 import os
-import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -133,33 +131,9 @@ def layer_forward_with_carry(layer, params, state, h, carry, *, train,
 
 
 # ----------------------------------------------------------- run detection
-_TRACE_OVERRIDE = threading.local()
-
-
-@contextlib.contextmanager
-def force_unrolled(active: bool = True):
-    """Trace-time override forcing the unrolled layer path for whatever
-    is traced inside the block, regardless of conf/env. Needed by
-    programs XLA's SPMD partitioner cannot handle with an inner
-    `lax.scan`: on the jaxlib 0.4.x line, a scan body inside a
-    partially-manual `shard_map` (``auto`` axes — the threshold
-    gradient exchange under DP x TP) hard-crashes the partitioner
-    (``Check failed: sharding.IsManualSubgroup()``). Such callers wrap
-    their step body in this context; everything else keeps scanning."""
-    prev = getattr(_TRACE_OVERRIDE, "unrolled", False)
-    _TRACE_OVERRIDE.unrolled = bool(active)
-    try:
-        yield
-    finally:
-        _TRACE_OVERRIDE.unrolled = prev
-
-
 def scan_enabled(conf) -> bool:
     """Config-level toggle with environment override (DL4J_SCAN_LAYERS=0
-    disables globally — benchmark A/B without code changes) and the
-    `force_unrolled` trace-time override on top."""
-    if getattr(_TRACE_OVERRIDE, "unrolled", False):
-        return False
+    disables globally — benchmark A/B without code changes)."""
     env = os.environ.get("DL4J_SCAN_LAYERS")
     if env is not None and env.strip().lower() in ("0", "false", "off", "no"):
         return False

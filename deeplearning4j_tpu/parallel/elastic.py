@@ -653,7 +653,7 @@ def make_drain_check(mesh, data_axis: str = "data"):
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from deeplearning4j_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     n = int(np.prod([mesh.shape[a] for a in (data_axis,)]))
     sharding = NamedSharding(mesh, P(data_axis))

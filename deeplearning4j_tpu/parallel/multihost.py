@@ -21,7 +21,6 @@ membership coordinator drives on join/leave.
 from __future__ import annotations
 
 import logging
-import os
 import time
 from typing import Optional
 
@@ -37,31 +36,6 @@ _TRANSIENT_MARKERS = ("DEADLINE_EXCEEDED", "UNAVAILABLE", "timed out",
                       "Timed out", "failed to connect", "Connection refused",
                       "connection attempt", "Socket closed",
                       "Address already in use")
-
-
-def _enable_cpu_collectives() -> None:
-    """The CPU backend has no built-in cross-process collectives ("
-    Multiprocess computations aren't implemented on the CPU backend") —
-    they only exist behind the gloo/mpi plugin selected by
-    `jax_cpu_collectives_implementation`, whose default is "none".
-    Select gloo when the process targets CPU and nothing was chosen
-    explicitly, so the same multi-host programs run on CPU clusters
-    (and in the 2-process CI smoke) without per-caller setup."""
-    import jax._src.xla_bridge as xb
-
-    if "cpu" not in str(os.environ.get("JAX_PLATFORMS",
-                                       jax.config.jax_platforms or "cpu")):
-        return
-    try:
-        current = xb.CPU_COLLECTIVES_IMPLEMENTATION.value
-    except AttributeError:     # newer jax: option renamed/absorbed
-        current = None
-    if current not in (None, "none"):
-        return                 # an explicit mpi/gloo choice wins
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — jaxlib without gloo: keep going,
-        pass           # initialize() will surface the real capability
 
 
 def _transient(err: BaseException) -> bool:
@@ -156,14 +130,6 @@ def initialize_multihost(coordinator_address: Optional[str] = None,
     changed (the elastic membership path)."""
     if getattr(initialize_multihost, "_done", False):
         return
-    # persistent XLA compile cache (DL4J_COMPILE_CACHE_DIR): elastic
-    # re-formation re-jits the train step per membership generation —
-    # revisited replica counts load their executables from disk
-    # instead of paying the full re-compile (the ROADMAP's
-    # per-width-compile-cache lever; no-op without the env var)
-    from deeplearning4j_tpu.nd.compile_cache import enable_compile_cache
-    enable_compile_cache()
-    _enable_cpu_collectives()
     last_err: Optional[BaseException] = None
     for attempt in range(max(1, int(max_attempts))):
         try:

@@ -18,6 +18,10 @@ training runs, and (c) the loss trajectory matches a single-process run
 on the same 4-device mesh (same global batch, same seeds) to float
 tolerance — proving the multi-process path computes the same global
 program.
+
+A CPU drill: every process it starts is pinned to `JAX_PLATFORMS=cpu`
+with virtual devices. On a TPU host a chip belongs to one process at a
+time, so N children cannot share the host's chips this way.
 """
 
 from __future__ import annotations

@@ -22,10 +22,9 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from deeplearning4j_tpu.parallel.compat import axis_size, shard_map
 
 
 def pipeline_apply(block_fn: Callable, stage_params, x_mb, axis_name: str):
@@ -80,31 +79,14 @@ def pipeline_forward(block_fn, stacked_params, x, mesh: Mesh, *,
     B = x.shape[0]
     assert B % microbatches == 0, "batch must divide microbatches"
     x_mb = x.reshape((microbatches, B // microbatches) + x.shape[1:])
-    # jax 0.4.x GSPMD miscompiles the reshard of a jit-traced
-    # intermediate into a shard_map in_spec that partitions one mesh
-    # axis while leaving another unmentioned (the value arrives SUMMED
-    # over the unmentioned axis instead of sliced — observed on the
-    # 0.4.37 CPU backend with a ("data", "pipe") mesh). On that line,
-    # hand every stage the full replicated stack (in_spec P()) and
-    # slice its stage inside the body; new-line JAX keeps the intended
-    # P(pipe) param sharding.
-    replicate_params = not hasattr(jax, "shard_map")
-    p_spec = jax.tree_util.tree_map(
-        lambda _: P() if replicate_params else P(pipe_axis),
-        stacked_params)
+    p_spec = jax.tree_util.tree_map(lambda _: P(pipe_axis), stacked_params)
     mb_spec = P(None, data_axis) if data_axis else P()
 
     @partial(shard_map, mesh=mesh,
              in_specs=(p_spec, mb_spec), out_specs=mb_spec,
              check_vma=False)
     def run(params_stage, mb):
-        if replicate_params:
-            s = lax.axis_index(pipe_axis)
-            local = jax.tree_util.tree_map(
-                lambda a: lax.dynamic_index_in_dim(a, s, 0, keepdims=False),
-                params_stage)
-        else:
-            local = jax.tree_util.tree_map(lambda a: a[0], params_stage)
+        local = jax.tree_util.tree_map(lambda a: a[0], params_stage)
         out = pipeline_apply(block_fn, local, mb, pipe_axis)
         # outputs are valid only on the last stage; broadcast them
         return _broadcast_from(out, pipe_axis, axis_size(pipe_axis) - 1)

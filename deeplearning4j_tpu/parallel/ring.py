@@ -21,10 +21,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from deeplearning4j_tpu.parallel.compat import axis_size, shard_map
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,

@@ -23,6 +23,7 @@ from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -366,20 +367,9 @@ class ShardedParallelTrainer:
         parallelism composes with the compressed gradient exchange
         without hand-written model-axis collectives."""
         from deeplearning4j_tpu.parallel import gradient_sharing as gs
-        from deeplearning4j_tpu.parallel.compat import shard_map
 
         mesh, axis = self.mesh, self.data_axis
         n = int(mesh.shape[axis])
-        autoaxes = frozenset(mesh.axis_names) - {axis}
-        # jaxlib 0.4.x SPMD partitioner limitation: an inner lax.scan
-        # under a partially-manual shard_map hard-crashes (`Check
-        # failed: sharding.IsManualSubgroup()`) — but newer jaxlibs
-        # partition it fine and keep the scan-over-layers compiled-size
-        # win, so the decision is a trace-time PROBE
-        # (gs.partial_manual_scan_supported: version-gated for the
-        # crash-prone line, compile-probed beyond it) instead of an
-        # unconditional unroll
-        allow_scan = (not autoaxes) or gs.partial_manual_scan_supported()
         if self.bucketed and any(
                 not jnp.issubdtype(jnp.result_type(l), jnp.floating)
                 for l in jax.tree_util.tree_leaves(
@@ -397,22 +387,18 @@ class ShardedParallelTrainer:
                  else gs.make_threshold_step)
         step = maker(
             self.model, axis, self.threshold_config, n_workers=n,
-            is_graph=self._is_graph, allow_scan=allow_scan,
+            is_graph=self._is_graph,
             diag=self.model._diag,
             **({"mode": "threshold"} if self.bucketed else {}))
         self._build_shardings()
         rep = P(axis)
         strip = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)
         expand = lambda t: jax.tree_util.tree_map(lambda a: a[None], t)
-        kwargs = dict(mesh=mesh,
-                      in_specs=(P(), rep, P(), None, rep, P(),
-                                P(axis), P(axis), None),
-                      out_specs=(P(), rep, P(), rep, P(), P(), P(), P()),
-                      check_vma=False)
-        if autoaxes:
-            kwargs["auto"] = autoaxes
-
-        @partial(shard_map, **kwargs)
+        @partial(shard_map, mesh=mesh,
+                 in_specs=(P(), rep, P(), None, rep, P(),
+                           P(axis), P(axis), None),
+                 out_specs=(P(), rep, P(), rep, P(), P(), P(), P()),
+                 axis_names={axis}, check_vma=False)
         def thr_step(params, upd_r, state, it, res_r, tau, x, y, rng):
             params, upd, state, res, tau, loss, sp, dv = step(
                 params, strip(upd_r), state, it, strip(res_r), tau,

@@ -29,6 +29,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -230,7 +231,6 @@ class ParallelTrainer:
         mode's rep-spec idiom); ``stacked::`` run packing happens inside
         the step, so the residual the trainer holds stays per-layer."""
         from deeplearning4j_tpu.parallel import gradient_sharing as gs
-        from deeplearning4j_tpu.parallel.compat import shard_map
 
         mesh, axis = self.mesh, self.data_axis
         step = gs.make_threshold_step(
@@ -261,7 +261,6 @@ class ParallelTrainer:
         updater state (gradient_sharing.make_threshold_multi); packing
         of ``stacked::`` runs is paid once per program."""
         from deeplearning4j_tpu.parallel import gradient_sharing as gs
-        from deeplearning4j_tpu.parallel.compat import shard_map
 
         mesh, axis = self.mesh, self.data_axis
         multi = gs.make_threshold_multi(
@@ -380,7 +379,6 @@ class ParallelTrainer:
         updater shards, the error-feedback residual) and leaves
         replicated trees alone."""
         from deeplearning4j_tpu.parallel import gradient_sharing as gs
-        from deeplearning4j_tpu.parallel.compat import shard_map
 
         mesh, axis = self.mesh, self.data_axis
         rs_plan = self._rs_plan() if mode in gs.RS_MODES else None
@@ -461,7 +459,6 @@ class ParallelTrainer:
         axis = self.data_axis
         local_one_step = self._make_local_one_step()
 
-        from deeplearning4j_tpu.parallel.compat import shard_map
 
         # per-replica params: leading axis of size n_workers, sharded over "data"
         rep_spec = P(axis)
@@ -504,7 +501,6 @@ class ParallelTrainer:
         avg_upd = self.average_updater_state
         local_one_step = self._make_local_one_step()
 
-        from deeplearning4j_tpu.parallel.compat import shard_map
         from jax import lax
 
         rep_spec = P(axis)

@@ -26,10 +26,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from deeplearning4j_tpu.parallel.compat import axis_size, shard_map
 
 from deeplearning4j_tpu.parallel.ring import reference_attention
 

@@ -21,16 +21,23 @@ resubmit verbatim to a survivor, partial streams continue as
 prompt+received with emit_start (same-version replicas only, the
 continuation contract).
 
-Warmup cost across replicas is amortized by the persistent XLA compile
-cache: point every worker's `DL4J_COMPILE_CACHE_DIR` at one shared
-volume and replica N's warmup replays replica 1's compilations.
+Warmup cost across replicas is amortized by JAX's persistent compile
+cache, which the worker entry point turns on (`nd/cache.py`: where
+`JAX_COMPILATION_CACHE_DIR` says, else one fixed directory in the
+checkout) — replica N's warmup replays replica 1's compilations.
+
+One process for each chip: a chip belongs to one process, so
+subprocess replicas (`spawn_replica`) are a CPU drill for now — on a
+TPU host a child that needs the chip its parent holds fails or hangs.
+Children inherit the parent's platform untouched; callers that want
+CPU children set `JAX_PLATFORMS=cpu` themselves. In-process replicas,
+one per device, are ROADMAP R8.
 """
 
 from __future__ import annotations
 
 import json
 import logging
-import os
 import socket
 import subprocess
 import sys
@@ -700,14 +707,16 @@ def spawn_replica(registry_root: str, model: str, *,
                   warmup_prompt_len: Optional[int] = None,
                   warmup_tokens: int = 2,
                   token: Optional[str] = None,
-                  compile_cache_dir: Optional[str] = None,
                   step_floor_ms: Optional[float] = None,
                   ready_timeout_s: float = 300.0) -> ReplicaProcess:
     """Launch one replica worker subprocess serving `model` from the
     on-disk registry; blocks until its READY line (a JSON
-    {host, port, token}) arrives. Pass ONE `compile_cache_dir` to every
-    replica of a model so warmups after the first replay cached XLA
-    compilations instead of re-tracing (`DL4J_COMPILE_CACHE_DIR`)."""
+    {host, port, token}) arrives. The child inherits this process's
+    environment as it is — its JAX platform included (a CPU drill sets
+    `JAX_PLATFORMS=cpu` before calling; see the module docstring on
+    one process per chip) — and shares the persistent compile cache
+    with every other replica on the host, so warmups after the first
+    replay cached XLA compilations."""
     cmd = [sys.executable, "-m", "deeplearning4j_tpu.serving.replica",
            "--registry", str(registry_root), "--model", str(model),
            "--version", str(version), "--n-slots", str(n_slots),
@@ -722,12 +731,8 @@ def spawn_replica(registry_root: str, model: str, *,
         cmd += ["--token", token]
     if step_floor_ms is not None:
         cmd += ["--step-floor-ms", str(step_floor_ms)]
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
-    if compile_cache_dir is not None:
-        env["DL4J_COMPILE_CACHE_DIR"] = str(compile_cache_dir)
     proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                            stderr=sys.stderr, env=env, text=True)
+                            stderr=sys.stderr, text=True)
     # readline() has no timeout of its own, and a child hung in model
     # load/warmup prints NOTHING to stdout (its logs go to stderr) —
     # a watchdog kills it at the deadline so the blocked readline
@@ -787,6 +792,11 @@ def main(argv=None) -> int:
                         "dispatch (sandbox benchmarking seam — see "
                         "GenerationServer.dispatch_floor_s)")
     args = p.parse_args(argv)
+
+    # serving grids are many small programs: cache them all, so the
+    # next replica (or restart) on this host replays the warmup
+    from deeplearning4j_tpu.nd import enable_compilation_cache
+    enable_compilation_cache(min_compile_time_secs=0.0)
 
     # a serving worker always publishes its gauges: the coordinator
     # federation (heartbeat-piggybacked snapshots) is how the fleet

@@ -445,12 +445,6 @@ class GenerationServer(ParallelInference):
         from deeplearning4j_tpu.serving.engine import bucket_len
         if self._running:
             raise RuntimeError("warmup() must run before start()")
-        # persistent XLA compile cache (DL4J_COMPILE_CACHE_DIR): a
-        # fleet successor re-warming the same (width x bucket) grid
-        # loads executables from disk instead of re-tracing them —
-        # near-instant swap warmup on revisited configurations
-        from deeplearning4j_tpu.nd.compile_cache import enable_compile_cache
-        enable_compile_cache()
         eng = self.engine
         n_tokens = max(2, int(n_tokens))
         self.engine.check_budget(int(prompt_len), n_tokens)

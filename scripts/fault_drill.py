@@ -25,6 +25,10 @@ Smoke recipe (scripts/verify.sh stage [6/6]):
 
 `--child` is the internal worker entry point; see
 docs/FAULT_TOLERANCE.md for custom drill recipes.
+
+A CPU drill: it starts one OS process per lineage / elastic member and
+pins every one to `JAX_PLATFORMS=cpu`. A chip belongs to one process at
+a time, so this driver cannot run its children on a TPU host's chips.
 """
 
 from __future__ import annotations

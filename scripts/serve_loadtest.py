@@ -534,9 +534,9 @@ def run_replicated(args):
 
     A. ONE `spawn_replica` subprocess — flood S streams x T tokens
        through FleetRouter/ReplicaSet, greedy parity vs generate();
-    B. TWO subprocesses (second warms against the SAME
-       `DL4J_COMPILE_CACHE_DIR` volume) — same flood; the aggregate
-       tok/s must scale >= 1.7x.
+    B. TWO subprocesses (the second warms against the persistent
+       compile cache the first filled — nd/cache.py) — same flood; the
+       aggregate tok/s must scale >= 1.7x.
 
     Every replica runs with a `--step-floor-ms` emulated device-step
     floor: on the 1-core CPU sandbox two processes cannot beat one on
@@ -598,7 +598,6 @@ def run_replicated(args):
     ref = reference_tokens(net, prompts, n_tok)
 
     root = tempfile.mkdtemp(prefix="replica-registry-")
-    cache = tempfile.mkdtemp(prefix="replica-compile-cache-")
     ModelRegistry(root).publish("m", net)
     coord = ElasticCoordinator(settle_s=0.2, grace_s=2.0).start()
     bps = -(-max_len // block_len)
@@ -609,8 +608,7 @@ def run_replicated(args):
             root, "m", coordinator=coord.address, n_slots=n_slots,
             n_blocks=n_slots * bps + 1, block_len=block_len,
             steps_per_dispatch=4, warmup_prompt_len=prompt_len,
-            token=token, compile_cache_dir=cache,
-            step_floor_ms=floor_ms)
+            token=token, step_floor_ms=floor_ms)
         return proc, round(time.monotonic() - t0, 3)
 
     def flood(router, n_replicas, n=n_tok, ps=prompts):

@@ -236,37 +236,3 @@ class TestDistributions:
         d = NormalDistribution(1.0, 2.0)
         d2 = distribution_from_dict(d.to_dict())
         assert d2 == d
-
-
-class TestCompilationCache:
-    def test_enable_populates_cache_dir(self, tmp_path):
-        """enable_compilation_cache points JAX's persistent cache at the
-        dir; a fresh jitted program writes an entry there."""
-        import jax
-        import jax.numpy as jnp
-        from deeplearning4j_tpu.nd import enable_compilation_cache
-
-        import os
-
-        from jax._src import compilation_cache as _cc
-
-        # conftest already bound the persistent-cache singleton to the
-        # suite-wide dir; re-pointing the config only takes effect
-        # after a reset
-        _cc.reset_cache()
-        d = enable_compilation_cache(tmp_path / "xla", min_compile_time_secs=0)
-        try:
-            @jax.jit
-            def f(a, b):
-                return jnp.tanh(a @ b) + a.sum()
-
-            f(jnp.ones((64, 64)), jnp.ones((64, 64))).block_until_ready()
-            assert os.path.isdir(d)
-            assert len(os.listdir(d)) >= 1, "no cache entry written"
-        finally:
-            # restore the suite-wide cache for later tests
-            _cc.reset_cache()
-            enable_compilation_cache(
-                os.environ.get("DL4J_TEST_XLA_CACHE",
-                               os.path.expanduser("~/.cache/dl4tpu-xla-tests")),
-                min_compile_time_secs=0.5)

@@ -1,131 +1,179 @@
-"""Persistent XLA compile cache seam (nd/compile_cache.py).
+"""The compile cache's one rule (nd/cache.py).
 
-The ROADMAP names the same lever twice — fleet swap warmup pays the
-full (width x bucket) program grid per successor, elastic re-formation
-pays full re-jits per generation. `DL4J_COMPILE_CACHE_DIR` routes both
-through jax's persistent compilation cache: the SECOND warmup of the
-same configuration loads executables from disk. The cold-vs-warm
-timing assert here is the seam's acceptance surface."""
+Where `JAX_COMPILATION_CACHE_DIR` is set JAX already reads it and no
+code sets a directory; unset, the cache is ONE fixed directory inside
+the checkout. Entry points turn it on; library code (server warmup,
+multihost init) never re-points it. Every case that needs a fresh
+process-wide JAX config runs in a subprocess — in-process the suite's
+own cache (conftest) is already bound, and resetting JAX's cache object
+is the private-API dance this rule replaced.
+"""
 
+import json
 import os
-
-import pytest
+import subprocess
+import sys
 
 import jax
+import pytest
 
-from deeplearning4j_tpu.nd import compile_cache
+from deeplearning4j_tpu.nd import cache as cache_mod
 
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 V, D, MAXLEN, BL = 23, 16, 32, 4
 
 
-@pytest.fixture
-def cache_dir(tmp_path, monkeypatch):
-    d = tmp_path / "xla-cache"
-    prior = jax.config.jax_compilation_cache_dir   # conftest's session cache
-    monkeypatch.setenv("DL4J_COMPILE_CACHE_DIR", str(d))
-    yield d
-    # restore the prior destination (the suite-wide cache the conftest
-    # enabled) so later tests neither read from nor write to this
-    # test's tmpdir
-    jax.config.update("jax_compilation_cache_dir", prior)
-    compile_cache._reset_cache_instance()
-    compile_cache._enabled_dir = None
+def _child(code, *, env_cache=None, timeout=600):
+    """Run `code` in a fresh interpreter from the checkout root; returns
+    the JSON object it prints last."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if env_cache is not None:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_cache)
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
+                          capture_output=True, text=True, timeout=timeout)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return json.loads(proc.stdout.strip().splitlines()[-1])
 
 
-class TestCompileCacheSeam:
-    def test_disabled_without_env(self, monkeypatch):
-        monkeypatch.delenv("DL4J_COMPILE_CACHE_DIR", raising=False)
-        compile_cache._enabled_dir = None
-        assert compile_cache.enable_compile_cache() is None
-        assert compile_cache.compile_cache_dir() is None
+_SPY = (
+    "import json, jax\n"
+    "sets = []\n"
+    "real = jax.config.update\n"
+    "def spy(name, val):\n"
+    "    sets.append(name)\n"
+    "    return real(name, val)\n"
+    "jax.config.update = spy\n"
+    "from deeplearning4j_tpu.nd import enable_compilation_cache\n")
 
-    def test_enable_is_idempotent_and_creates_dir(self, cache_dir):
-        got = compile_cache.enable_compile_cache()
-        assert got == str(cache_dir)
-        assert os.path.isdir(cache_dir)
-        assert compile_cache.enable_compile_cache() == str(cache_dir)
-        assert compile_cache.compile_cache_dir() == str(cache_dir)
 
-    def test_cold_vs_warm_swap_warmup(self, cache_dir, tmp_path):
-        """The fleet-swap scenario, measured the way a swap actually
-        pays it — in FRESH processes (a successor starts with empty
-        in-memory caches; the persistent cache is all that carries
-        over): a cold child warms one server's full program grid
-        (every program XLA-compiles and lands in the cache), a second
-        identical child re-warms it. The warm grid must load from the
-        persistent cache and come back measurably faster — plus the
-        cache directory must actually hold the executables (a silent
-        fallback to no-cache would still 'pass' a files-only check
-        the other way around). Subprocess isolation is deliberate:
-        an in-process `jax.clear_caches()` variant poisons every
-        later test in the suite with mass recompiles."""
-        import subprocess
-        import sys
+class TestTheRule:
+    def test_env_set_no_code_sets_a_directory(self, tmp_path):
+        placed = tmp_path / "placed-from-outside"
+        got = _child(
+            _SPY
+            + "d = enable_compilation_cache('tests-tag', 0.0)\n"
+            "print(json.dumps({'returned': d, 'sets': sets, 'cfg': "
+            "jax.config.jax_compilation_cache_dir}))\n",
+            env_cache=placed)
+        assert got["returned"] == got["cfg"] == str(placed)
+        assert "jax_compilation_cache_dir" not in got["sets"]
+        # thresholds are not directories: still set in code
+        assert "jax_persistent_cache_min_compile_time_secs" in got["sets"]
 
-        child = (
-            "import os, time\n"
-            "import numpy as np\n"
-            "from deeplearning4j_tpu.serving import GenerationServer\n"
-            "from deeplearning4j_tpu.zoo.transformer import "
-            "TransformerLM\n"
-            f"net = TransformerLM(vocab_size={V}, d_model={D}, "
-            f"n_layers=2, n_heads=4, max_len={MAXLEN}, seed=3).init()\n"
-            "t0 = time.perf_counter()\n"
-            f"GenerationServer(net, n_slots=4, n_blocks=48, "
-            f"block_len={BL}, speculative=4).warmup(6, 4)\n"
-            "print('ELAPSED', time.perf_counter() - t0)\n")
-        env = dict(os.environ,
-                   DL4J_COMPILE_CACHE_DIR=str(cache_dir),
-                   JAX_PLATFORMS="cpu")
+    def test_env_unset_one_fixed_directory_in_the_checkout(self):
+        got = _child(
+            _SPY
+            + "d = enable_compilation_cache()\n"
+            "print(json.dumps({'returned': d, 'sets': sets}))\n")
+        assert got["returned"] == os.path.join(ROOT, ".jax_cache")
+        assert got["sets"].count("jax_compilation_cache_dir") == 1
 
-        def warmup_child():
-            proc = subprocess.run(
-                [sys.executable, "-c", child], env=env,
-                capture_output=True, text=True, timeout=600,
-                cwd=os.path.dirname(os.path.dirname(
-                    os.path.abspath(__file__))))
-            assert proc.returncode == 0, proc.stderr[-2000:]
-            for line in proc.stdout.splitlines():
-                if line.startswith("ELAPSED"):
-                    return float(line.split()[1])
-            raise AssertionError(f"no ELAPSED line: {proc.stdout!r}")
+    def test_subdir_is_a_namespace_under_the_same_root(self):
+        got = _child(
+            "import json\n"
+            "from deeplearning4j_tpu.nd import enable_compilation_cache\n"
+            "print(json.dumps(enable_compilation_cache('tests-abc')))\n")
+        assert got == os.path.join(ROOT, ".jax_cache", "tests-abc")
+        assert os.path.isdir(got)
 
-        cold = warmup_child()
-        entries = [f for f in os.listdir(cache_dir)
-                   if not f.endswith("-atime")]
-        if not entries:
-            pytest.skip("this jax backend does not populate the "
-                        "persistent compilation cache on CPU")
-        warm = warmup_child()
-        assert warm < cold, (
-            f"warm swap-warmup ({warm:.2f}s) not faster than cold "
-            f"({cold:.2f}s) — persistent cache not serving the grid")
-        # the committed evidence bar: a cache hit skips XLA entirely,
-        # which on this grid is well over half the cold cost
-        assert warm < 0.75 * cold, (cold, warm)
+    def test_first_placement_wins_later_calls_do_not_repoint(self):
+        """An entry point placed the cache; a second caller (another
+        entry point's helper, a library that used to re-point) changes
+        thresholds at most."""
+        got = _child(
+            "import json\n"
+            "from deeplearning4j_tpu.nd import enable_compilation_cache\n"
+            "a = enable_compilation_cache('tests-first')\n"
+            "b = enable_compilation_cache('elsewhere', 0.0)\n"
+            "print(json.dumps([a, b]))\n")
+        assert got[0] == got[1] == os.path.join(ROOT, ".jax_cache",
+                                                "tests-first")
 
-    def test_multihost_init_enables_seam(self, cache_dir, monkeypatch):
-        """initialize_multihost routes through the seam (the elastic
-        re-formation call site) — verified without bringing up a real
-        distributed runtime by checking the seam state after the
-        latch-guarded prologue."""
+    def test_root_is_fixed_inside_the_checkout_and_git_ignored(self):
+        assert str(cache_mod.CACHE_ROOT) == os.path.join(ROOT, ".jax_cache")
+        with open(os.path.join(ROOT, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+
+    def test_retired_seams_are_gone(self):
+        with pytest.raises(ImportError):
+            import deeplearning4j_tpu.nd.compile_cache  # noqa: F401
+        import inspect
+
+        from deeplearning4j_tpu.serving.replica import spawn_replica
+        assert "compile_cache_dir" not in inspect.signature(
+            spawn_replica).parameters
+        assert "cache_dir" not in inspect.signature(
+            cache_mod.enable_compilation_cache).parameters
+
+
+class TestLibraryNeverRepoints:
+    def test_server_warmup_leaves_the_cache_where_it_was(self):
+        from deeplearning4j_tpu.serving import GenerationServer
+        from deeplearning4j_tpu.zoo.transformer import TransformerLM
+        before = (jax.config.jax_compilation_cache_dir,
+                  jax.config.jax_persistent_cache_min_compile_time_secs)
+        net = TransformerLM(vocab_size=V, d_model=D, n_layers=1,
+                            n_heads=4, max_len=MAXLEN, seed=3).init()
+        GenerationServer(net, n_slots=2, n_blocks=20,
+                         block_len=BL).warmup(2)
+        assert before == (
+            jax.config.jax_compilation_cache_dir,
+            jax.config.jax_persistent_cache_min_compile_time_secs)
+
+    def test_multihost_init_leaves_the_cache_where_it_was(self,
+                                                          monkeypatch):
         from deeplearning4j_tpu.parallel import multihost
-
-        compile_cache._enabled_dir = None
-        # force the early-return path AFTER the seam call by marking
-        # the runtime active once the cache is enabled
-        calls = {}
-        monkeypatch.setattr(multihost, "_enable_cpu_collectives",
-                            lambda: calls.setdefault("hit", True))
+        before = jax.config.jax_compilation_cache_dir
 
         def boom(*a, **k):
             raise RuntimeError("stop before real distributed init")
 
         monkeypatch.setattr(multihost, "_raw_initialize", boom)
-        monkeypatch.setattr(multihost, "_transient",
-                            lambda e: False)
+        monkeypatch.setattr(multihost, "_transient", lambda e: False)
         with pytest.raises(RuntimeError, match="stop before"):
             multihost.initialize_multihost("127.0.0.1:1", 1, 0,
                                            max_attempts=1)
-        assert compile_cache.compile_cache_dir() == str(cache_dir)
-        assert calls.get("hit")
+        assert jax.config.jax_compilation_cache_dir == before
+
+
+class TestColdVsWarm:
+    def test_second_process_hits_what_the_first_compiled(self, tmp_path):
+        """The fleet-swap / replica-2 / next-chip-call scenario, in
+        FRESH processes (a successor starts with empty in-memory
+        caches; the persistent cache is all that carries over): a cold
+        child warms one server's program grid, an identical second
+        child re-warms it. The second must HIT the cache — counted from
+        JAX's own cache events, not inferred from a timing — and spend
+        less time in XLA. Subprocess isolation is deliberate: an
+        in-process `jax.clear_caches()` variant poisons every later
+        test in the suite with mass recompiles."""
+        child = (
+            "import json, jax\n"
+            "ev = {'requests': 0, 'hits': 0}\n"
+            "def on(event, **kw):\n"
+            "    if event.endswith('compile_requests_use_cache'):\n"
+            "        ev['requests'] += 1\n"
+            "    elif event.endswith('cache_hits'):\n"
+            "        ev['hits'] += 1\n"
+            "jax.monitoring.register_event_listener(on)\n"
+            "from deeplearning4j_tpu.monitor import (JitCompileCollector,\n"
+            "                                        MetricsRegistry)\n"
+            "from deeplearning4j_tpu.nd import enable_compilation_cache\n"
+            "from deeplearning4j_tpu.serving import GenerationServer\n"
+            "from deeplearning4j_tpu.zoo.transformer import TransformerLM\n"
+            "enable_compilation_cache(min_compile_time_secs=0.0)\n"
+            "coll = JitCompileCollector(MetricsRegistry()).install()\n"
+            f"net = TransformerLM(vocab_size={V}, d_model={D}, "
+            f"n_layers=2, n_heads=4, max_len={MAXLEN}, seed=3).init()\n"
+            f"GenerationServer(net, n_slots=4, n_blocks=48, "
+            f"block_len={BL}, speculative=4).warmup(6, 4)\n"
+            "print(json.dumps(dict(ev, seconds=coll.compile_seconds())))\n")
+        cache = tmp_path / "xla-cache"
+        cold = _child(child, env_cache=cache)
+        assert cold["requests"] > 0 and cold["hits"] == 0, cold
+        assert any(not f.endswith("-atime") for f in os.listdir(cache))
+        warm = _child(child, env_cache=cache)
+        assert warm["requests"] == cold["requests"], (cold, warm)
+        assert warm["hits"] >= 0.9 * warm["requests"], (cold, warm)
+        assert warm["seconds"] < 0.75 * cold["seconds"], (cold, warm)

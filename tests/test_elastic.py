@@ -220,15 +220,6 @@ class TestControlPlaneRetry:
 
 # ================================================ multihost latch lifecycle
 class TestMultihostLatch:
-    @pytest.fixture(autouse=True)
-    def _stub_collectives(self, monkeypatch):
-        # the real gloo selection poisons later single-process CPU
-        # backend creation in this test process (gloo needs a
-        # distributed client) — these tests exercise the LATCH, not
-        # the collectives
-        from deeplearning4j_tpu.parallel import multihost as mh
-        monkeypatch.setattr(mh, "_enable_cpu_collectives", lambda: None)
-
     def test_shutdown_rearms_initialize(self, monkeypatch):
         from deeplearning4j_tpu.parallel import multihost as mh
         calls = []

@@ -165,12 +165,11 @@ class TestFlashAttention:
                                    rtol=1e-4, atol=1e-5)
 
 
-class TestFlashFallbackSeam:
-    """The helper seam degrades like the reference's cuDNN fallback
-    (`ConvolutionLayer.java:76-80`): auto mode probes the kernel
-    eagerly once per backend — a probe failure routes attention through
-    the XLA path with one warning — while an explicit use_flash=True
-    surfaces the real kernel error."""
+class TestFlashNoFallback:
+    """On a TPU the Pallas kernel IS the attention path: a kernel that
+    fails to build raises out of the forward in auto mode exactly as
+    it does when forced — nothing catches it and substitutes the XLA
+    path (a fallback there hid the device for three rounds)."""
 
     def _layer(self, use_flash):
         import jax
@@ -184,27 +183,29 @@ class TestFlashFallbackSeam:
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 6, 8))
         return layer, params, x
 
-    def test_auto_mode_probe_failure_falls_back(self, monkeypatch):
-        import numpy as np
+    def test_auto_mode_kernel_failure_propagates(self, monkeypatch):
         import jax
+        import pytest
         import deeplearning4j_tpu.kernels as kmod
-        from deeplearning4j_tpu.nn.layers import attention as attn_mod
-
-        # true XLA reference first (no patches)
-        layer, params, x = self._layer(False)
-        want = np.asarray(layer.forward(params, {}, x)[0])
 
         def boom(*a, **k):
             raise RuntimeError("kernel exploded")
 
         monkeypatch.setattr(kmod, "flash_attention", boom)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        attn_mod._FLASH_OK.clear()
         layer, params, x = self._layer(None)       # auto
-        got = np.asarray(layer.forward(params, {}, x)[0])
-        np.testing.assert_allclose(got, want, rtol=1e-6)
-        assert attn_mod._FLASH_OK.get("tpu") is False
-        attn_mod._FLASH_OK.clear()                 # don't poison later tests
+        with pytest.raises(RuntimeError, match="kernel exploded"):
+            layer.forward(params, {}, x)
+
+    def test_auto_mode_off_tpu_takes_xla_path(self, monkeypatch):
+        import deeplearning4j_tpu.kernels as kmod
+
+        def boom(*a, **k):
+            raise RuntimeError("kernel must not run off-TPU in auto mode")
+
+        monkeypatch.setattr(kmod, "flash_attention", boom)
+        layer, params, x = self._layer(None)
+        layer.forward(params, {}, x)               # cpu: XLA path
 
     def test_forced_flash_failure_surfaces(self, monkeypatch):
         import pytest
@@ -371,8 +372,44 @@ class TestLayerNormKernel:
 
 class TestFusedAdamKernel:
     """One-kernel packed-run Adam (kernels/fused_adam.py) vs the
-    per-leaf jnp path — BIT-comparable inside jit (both sides compile;
-    the containers always run the updater inside the jitted step)."""
+    per-leaf jnp path, both inside jit (the containers always run the
+    updater inside the jitted step).
+
+    The contract is rounding-level, not bit-level, and the reason is
+    the compiler's, not the kernel's: `m = b1*m + (1-b1)*g` is two
+    products and a sum, and whether XLA:CPU contracts one product into
+    an FMA (one rounding instead of two) — and WHICH one — is decided
+    per program. Measured under jax 0.9 (PR 21): the per-leaf path
+    computes fma(b1, m, round(c*g)), the kernel body
+    fma(c, g, round(b1*m)), the eager op-by-op path neither;
+    `optimization_barrier` changes none of it. Each is within one
+    rounding of the LARGER addend of the exactly-rounded value, so two
+    of them differ by at most 2 eps x (|b1*m| + |c*g|) per element
+    (eps = 2^-23; where the addends cancel that is many ulps of the
+    small RESULT, which is why the bound is on the addends). v has the
+    same shape; the parameters inherit it through Adam's normalized
+    update, far below one ulp of an O(1) weight. Where the same program
+    runs both sides (flat vs per-leaf STATE layout) the equality stays
+    bit-exact."""
+
+    EPS = float(np.finfo(np.float32).eps)
+
+    def _assert_one_fma_apart(self, upd, got_p, got_s, ref_p, ref_s, p, g,
+                              s):
+        """Kernel vs per-leaf outputs for one leaf: m and v inside the
+        FMA-contraction bound, p inside 2 ulps."""
+        p, g = np.asarray(p), np.asarray(g, np.float32)
+        m, v = np.asarray(s["m"]), np.asarray(s["v"])
+        bound_m = 2 * self.EPS * (upd.beta1 * np.abs(m)
+                                  + (1 - upd.beta1) * np.abs(g))
+        bound_v = 2 * self.EPS * (upd.beta2 * np.abs(v)
+                                  + (1 - upd.beta2) * g * g)
+        dm = np.abs(np.asarray(got_s["m"]) - np.asarray(ref_s["m"]))
+        dv = np.abs(np.asarray(got_s["v"]) - np.asarray(ref_s["v"]))
+        assert (dm <= bound_m).all(), float((dm - bound_m).max())
+        assert (dv <= bound_v).all(), float((dv - bound_v).max())
+        np.testing.assert_allclose(np.asarray(got_p), np.asarray(ref_p),
+                                   rtol=2 * self.EPS, atol=2 * self.EPS)
 
     def _run(self, seed=3, gdtype=jnp.float32):
         rng = np.random.default_rng(seed)
@@ -391,7 +428,7 @@ class TestFusedAdamKernel:
         return params, grads, state
 
     @pytest.mark.parametrize("gdtype", [jnp.float32, jnp.bfloat16])
-    def test_bit_parity_vs_jnp_path(self, gdtype):
+    def test_rounding_parity_vs_jnp_path(self, gdtype):
         from deeplearning4j_tpu.common.updaters import Adam
         from deeplearning4j_tpu.kernels.fused_adam import (
             adam_update_packed)
@@ -415,11 +452,9 @@ class TestFusedAdamKernel:
         kp, ks = kern(params, grads, state)
         rp, rs = ref(params, grads, state)
         for pk in params:
-            assert np.array_equal(np.asarray(kp[pk]), np.asarray(rp[pk]))
-            assert np.array_equal(np.asarray(ks[pk]["m"]),
-                                  np.asarray(rs[pk]["m"]))
-            assert np.array_equal(np.asarray(ks[pk]["v"]),
-                                  np.asarray(rs[pk]["v"]))
+            self._assert_one_fma_apart(upd, kp[pk], ks[pk], rp[pk],
+                                       rs[pk], params[pk], grads[pk],
+                                       state[pk])
             assert kp[pk].dtype == jnp.float32    # fp32 master
 
     def test_schedule_lr(self):
@@ -505,9 +540,13 @@ class TestFusedAdamKernel:
                 assert np.array_equal(np.asarray(fs[pk][s]),
                                       np.asarray(rs[pk][s]))
 
-    def test_container_on_off_bit_identical(self, monkeypatch):
+    def test_container_on_off_track_to_rounding(self, monkeypatch):
         # whole train loop: fused-Adam kernel vs jnp path over a packed
-        # deep-MLP run — params AND updater state bit-identical
+        # deep-MLP run, 4 optimizer steps. The per-step difference is
+        # the <= 2-ulp FMA-contraction choice above; through 4 steps of
+        # Adam's normalized update (lr 0.01) it stays inside 4 steps x
+        # (1 ulp of an O(1) weight + lr x a few eps) < 1e-6 absolute —
+        # measured 6e-8 (PR 21). fp32 eps is 1.2e-7.
         from deeplearning4j_tpu.common.updaters import Adam
         from deeplearning4j_tpu.nn.conf import (InputType,
                                                 NeuralNetConfiguration)
@@ -535,10 +574,12 @@ class TestFusedAdamKernel:
         on, off = run("1"), run("0")
         for a, b in zip(jax.tree_util.tree_leaves(on.params),
                         jax.tree_util.tree_leaves(off.params)):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=0, atol=1e-6)
         for a, b in zip(jax.tree_util.tree_leaves(on.updater_state),
                         jax.tree_util.tree_leaves(off.updater_state)):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=0, atol=1e-7)
 
 
 class TestFlashBf16:

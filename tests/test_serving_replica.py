@@ -626,3 +626,52 @@ class TestAutoscalerReplicas:
         assert mgr.count() == 1
         with pytest.raises(ValueError):
             ReplicaManager(lambda: None, min_replicas=2, max_replicas=1)
+
+
+class TestSpawnedChildPlatform:
+    """`spawn_replica` children are a CPU drill only when the CALLER
+    says so. The child used to get `JAX_PLATFORMS=cpu` by default — on
+    a chip host a replica fleet then served from the CPU and said
+    nothing. Now it inherits the parent's environment as it is."""
+
+    class _Proc:
+        def __init__(self):
+            import io
+            self.stdout = io.StringIO(
+                'REPLICA_READY {"host": "h", "port": 7, "token": "t"}\n')
+
+        def poll(self):
+            return None
+
+        def kill(self):
+            pass
+
+    def _spawn(self, monkeypatch):
+        from deeplearning4j_tpu.serving import replica
+        seen = {}
+
+        def popen(cmd, **kw):
+            seen.update(cmd=cmd, kw=kw)
+            return self._Proc()
+
+        monkeypatch.setattr(replica.subprocess, "Popen", popen)
+        proc = replica.spawn_replica("/registry", "m", token="t")
+        assert (proc.host, proc.port, proc.token) == ("h", 7, "t")
+        return seen
+
+    def test_child_inherits_parent_environment_untouched(self,
+                                                         monkeypatch):
+        import os
+        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+        seen = self._spawn(monkeypatch)
+        # no env override at all: Popen's default is inheritance
+        assert seen["kw"].get("env") is None
+        assert "JAX_PLATFORMS" not in os.environ     # nor set on the way
+
+    def test_cpu_drill_is_the_callers_choice(self, monkeypatch):
+        import os
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        seen = self._spawn(monkeypatch)
+        assert seen["kw"].get("env") is None
+        assert os.environ["JAX_PLATFORMS"] == "cpu"  # what the child sees
+        assert "--registry" in seen["cmd"]

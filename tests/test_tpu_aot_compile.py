@@ -1,0 +1,147 @@
+"""The programs the chip runs, compiled for a v5e WITHOUT a chip.
+
+libtpu ships a compile-only client: `jax.experimental.topologies`
+describes a v5e and `.lower(...).compile()` runs the real XLA:TPU +
+Mosaic compilers on this CPU host. Nothing executes, so no time, rate
+or utilisation comes out of it — but every refusal the compilers can
+make (a primitive Mosaic cannot lower, a tiling or VMEM limit, a crash)
+shows up here, in tier-1, before it costs a chip call. Both blockers
+PR 21 met on the chip reproduce under it exactly.
+
+Each case is a subprocess: trace-time platform decisions are made as on
+the chip (`jax.default_backend` answers "tpu"), and one case expects the
+compiler to kill its process.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_PRELUDE = '''
+import json, re, sys
+import jax, jax.numpy as jnp
+{import_package}
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+try:
+    topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+except Exception as e:
+    print(json.dumps({{"skip": f"{{type(e).__name__}}: {{e}}"[:300]}}))
+    sys.exit(0)
+S = SingleDeviceSharding(topo.devices[0])
+jax.default_backend = lambda: "tpu"      # trace as on the chip
+from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+from deeplearning4j_tpu.zoo.transformer import TransformerLM
+
+
+def sds(a):
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=S)
+
+
+def tree(t):
+    return jax.tree_util.tree_map(sds, t)
+
+
+def lm(**kw):
+    conf = TransformerLM(seed=11, **kw).conf()
+    conf.dtype_policy = "mixed_bf16"
+    return MultiLayerNetwork(conf).init(11)
+'''
+
+_TRAIN = '''
+# T = 1024 reaches the Pallas flash BACKWARD (_PALLAS_BWD_MIN_T)
+net = lm(vocab_size=128, d_model=128, n_layers=2, n_heads=2, max_len=1024)
+k, B, T, V = 2, 1, 1024, 128
+key = jax.random.PRNGKey(0)
+net._jit_multi_step = net._make_multi_step()
+low = net._jit_multi_step.lower(
+    tree(net.params), tree(net.updater_state), tree(net.net_state), 0,
+    jax.ShapeDtypeStruct((k, B, T), jnp.float32, sharding=S),
+    jax.ShapeDtypeStruct((k, B, T, V), jnp.float32, sharding=S),
+    jax.ShapeDtypeStruct((k,) + key.shape, key.dtype, sharding=S))
+names = sorted(set(re.findall(r'kernel_name = "([^"]+)"', low.as_text())))
+low.compile()
+print(json.dumps({"kernels": names, "compiled": True}))
+'''
+
+_PREFILL = '''
+# the serving prefill of an 8-wide admission wave at the 32-token
+# bucket: 256 rows through FFN out-projection -> vocabulary projection
+# -> softmax, which XLA:TPU nests three fusions deep
+from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer
+from deeplearning4j_tpu.zoo.transformer import get_prefill_bucketed
+net = lm(vocab_size=512, d_model=512, n_layers=1, n_heads=8, max_len=64)
+k2, Pb = 8, 32
+carries = {
+    str(i): jax.tree_util.tree_map(
+        sds, jax.eval_shape(lambda l=l: l.init_carry(
+            k2, net.dtype.compute_dtype)))
+    for i, l in enumerate(net.layers) if isinstance(l, BaseRecurrentLayer)}
+get_prefill_bucketed(net).lower(
+    tree(net.params), tree(net.net_state),
+    jax.ShapeDtypeStruct((k2, Pb), jnp.int32, sharding=S), carries,
+    jax.ShapeDtypeStruct((k2,), jnp.int32, sharding=S)).compile()
+print(json.dumps({"compiled": True}))
+'''
+
+
+def _child(body, *, import_package=True):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("LIBTPU_INIT_ARGS", None)
+    code = _PRELUDE.format(
+        import_package="import deeplearning4j_tpu" if import_package
+        else "") + body
+    proc = subprocess.run([sys.executable, "-c", code], env=env, cwd=ROOT,
+                          capture_output=True, text=True, timeout=900)
+    out = None
+    for line in reversed(proc.stdout.strip().splitlines()):
+        if line.startswith("{"):
+            out = json.loads(line)
+            break
+    if out and "skip" in out:
+        pytest.skip(f"no compile-only TPU client here: {out['skip']}")
+    return proc, out
+
+
+def test_train_step_kernels_are_mosaic_compiled_for_v5e():
+    """Every Pallas kernel of the fused train step lowers to a
+    `tpu_custom_call` under its stable name and the whole step passes
+    the TPU compilers. (PR 21: the fused-Adam kernel could not —
+    Mosaic has no lowering for `optimization_barrier` — and being ON by
+    default on a TPU it took every `fit()` of a packed Adam run down
+    with it.)"""
+    from deeplearning4j_tpu.kernels import fused_adam, layernorm
+    from deeplearning4j_tpu.kernels.flash_attention import KERNEL_NAMES
+    proc, out = _child(_TRAIN)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["compiled"] is True
+    assert out["kernels"] == sorted(
+        KERNEL_NAMES + layernorm.KERNEL_NAMES + (fused_adam.KERNEL_NAME,))
+
+
+def test_256_row_prefill_compiles_with_the_packages_libtpu_stacks():
+    """Importing the package widens libtpu's compiler fiber stacks
+    before the TPU client starts (`deeplearning4j_tpu/__init__.py`), so
+    the program that killed the first chip smoke compiles."""
+    proc, out = _child(_PREFILL)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out == {"compiled": True}
+
+
+def test_the_libtpu_crash_the_workaround_exists_for_is_still_there():
+    """Control: the same program under libtpu's DEFAULT fiber stack —
+    the TPU client is started BEFORE the package is imported, so the
+    package's flag comes too late to apply — dies in the compiler with
+    SIGSEGV, uncatchable from Python, which is why the fix is a
+    start-up flag and not an `except`. When a newer libtpu makes this
+    test fail, delete `_widen_tpu_compiler_stacks` and this control
+    with it."""
+    proc, out = _child(_PREFILL, import_package=False)
+    assert out is None
+    assert proc.returncode < 0, (proc.returncode, proc.stderr[-2000:])
+    assert "STACK OVERFLOW" in proc.stderr

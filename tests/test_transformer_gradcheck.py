@@ -6,7 +6,6 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.gradientcheck import check_gradients_fn
-from deeplearning4j_tpu.parallel.compat import enable_x64
 from deeplearning4j_tpu.nn.layers import (
     LayerNormalization,
     TransformerEncoderBlock,
@@ -15,7 +14,7 @@ from deeplearning4j_tpu.nn.layers import (
 
 class TestTransformerGradients:
     def test_layernorm_gradients(self):
-        with enable_x64(True):
+        with jax.enable_x64(True):
             ln = LayerNormalization(n_out=6)
             p = jax.tree_util.tree_map(
                 lambda a: jnp.asarray(a, jnp.float64),
@@ -31,7 +30,7 @@ class TestTransformerGradients:
             assert check_gradients_fn(loss, p, max_rel_error=1e-5)
 
     def test_encoder_block_gradients(self):
-        with enable_x64(True):
+        with jax.enable_x64(True):
             blk = TransformerEncoderBlock(n_in=8, n_heads=2, use_flash=False)
             p = jax.tree_util.tree_map(
                 lambda a: jnp.asarray(a, jnp.float64),
