@@ -151,6 +151,11 @@ def enable(registry: Optional[MetricsRegistry] = None,
         if tracer is not None:
             _STATE.tracer = tracer
         _STATE.tracer.enabled = True
+        # one emission, two clocks: every span also enters the profiler's
+        # annotation, so it lies beside the device planes of a
+        # `jax.profiler` trace (jax is imported here, not with the module)
+        from jax.profiler import TraceAnnotation
+        _STATE.tracer.annotation = TraceAnnotation
         # surface ring-buffer overflow: the tracer drops its OLDEST
         # event silently, so the loss count must be a visible metric
         _STATE.tracer._drop_counter = _STATE.registry.counter(
@@ -183,6 +188,7 @@ def disable():
     with _STATE.lock:
         _STATE.enabled = False
         _STATE.tracer.enabled = False
+        _STATE.tracer.annotation = None
         if _STATE.compile_collector is not None:
             _STATE.compile_collector.uninstall()
         _STATE.listener = None
@@ -233,7 +239,9 @@ def memory_collector() -> Optional[DeviceMemoryCollector]:
 
 def span(name: str, **args):
     """`with monitor.span("fit/forward_backward"): ...` — NOOP_SPAN when
-    disabled (no allocation, no clock read)."""
+    disabled (no allocation, no clock read); enabled, one span in the
+    tracer's ring and, while a `jax.profiler` trace is open, the event
+    `dl4tpu/<name>` on this thread's line of its host plane."""
     if not _STATE.enabled:
         return NOOP_SPAN
     return _STATE.tracer.span(name, **args)

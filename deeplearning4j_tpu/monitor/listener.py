@@ -15,6 +15,7 @@
 
 from __future__ import annotations
 
+import time
 from typing import Optional
 
 from deeplearning4j_tpu.monitor.registry import MetricsRegistry
@@ -67,11 +68,10 @@ def record_master_event(ev, registry: MetricsRegistry,
                         tracer: Optional[Tracer] = None,
                         t0_perf: Optional[float] = None):
     """Land one `TrainingMasterStats` phase event in the registry
-    (+ tracer). `t0_perf` is the stats object's `time.perf_counter()`
-    epoch: with it, spans are placed via absolute perf_counter readings
-    (`complete_between`) so they align with the fit spans on the same
-    tracer timeline; without it they fall back to the event's own
-    relative clock."""
+    (+ tracer), on the tracer's one clock. `t0_perf` is the stats
+    object's `time.perf_counter()` epoch, against which the event's
+    `start_ms` is read; a stats object that keeps none gets the span
+    ended now, which is when a phase's event is recorded."""
     phase = ev.get("phase", "unknown")
     dur_s = ev.get("duration_ms", 0.0) / 1e3
     registry.counter("parallel_phase_total",
@@ -83,14 +83,10 @@ def record_master_event(ev, registry: MetricsRegistry,
     if tracer is not None:
         extra = {k: v for k, v in ev.items()
                  if k not in ("phase", "start_ms", "duration_ms")}
-        if t0_perf is not None:
-            start = t0_perf + ev.get("start_ms", 0.0) / 1e3
-            tracer.complete_between(f"master/{phase}", start, start + dur_s,
-                                    **extra)
-        else:
-            tracer.add_complete_event(
-                f"master/{phase}", ev.get("start_ms", 0.0) / 1e3, dur_s,
-                **extra)
+        start = (time.perf_counter() - dur_s if t0_perf is None
+                 else t0_perf + ev.get("start_ms", 0.0) / 1e3)
+        tracer.complete_between(f"master/{phase}", start, start + dur_s,
+                                **extra)
 
 
 def bind_master_stats(stats, registry: MetricsRegistry,

@@ -5,9 +5,17 @@ TensorFlow's runtime tracing established, arXiv:1605.08695): spans nest
 per-thread, timestamps come from `time.perf_counter_ns()` (monotonic —
 NTP steps can't produce negative durations), and the whole buffer
 exports as Chrome trace-event JSON that loads directly in Perfetto
-(`ui.perfetto.dev`) next to the XLA traces ProfilerListener captures.
+(`ui.perfetto.dev`).
 
-Pure stdlib, bounded memory (ring buffer), thread-safe.
+One emission, two clocks: a tracer whose `annotation` is set
+(`monitor.enable()` sets it to `jax.profiler.TraceAnnotation`) also
+enters `annotation(PROFILE_PREFIX + name)` round every span, so the same
+span lands in the profile's `/host:CPU` plane on the clock the device
+planes use whenever a `jax.profiler` trace is being taken (with none
+open the annotation is a flag check).
+
+This module imports stdlib only; bounded memory (ring buffer),
+thread-safe.
 """
 
 from __future__ import annotations
@@ -20,8 +28,13 @@ from collections import deque
 from typing import Dict, List, Optional
 
 
+#: what a span's name is prefixed with in the profile's host plane
+PROFILE_PREFIX = "dl4tpu/"
+
+
 class Span:
-    __slots__ = ("name", "start_ns", "end_ns", "args", "thread_id", "_tracer")
+    __slots__ = ("name", "start_ns", "end_ns", "args", "thread_id",
+                 "_tracer", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict):
         self._tracer = tracer
@@ -30,21 +43,34 @@ class Span:
         self.thread_id = threading.get_ident()
         self.start_ns = 0
         self.end_ns = 0
+        ann = tracer.annotation
+        # the args given here reach the profile as the event's stats;
+        # what `set()` adds later reaches the ring only
+        self._ann = None if ann is None else ann(PROFILE_PREFIX + name,
+                                                 **args)
 
     @property
     def duration_ms(self) -> float:
         return (self.end_ns - self.start_ns) / 1e6
+
+    @property
+    def duration_s(self) -> float:
+        return (self.end_ns - self.start_ns) / 1e9
 
     def set(self, **args):
         self.args.update(args)
         return self
 
     def __enter__(self) -> "Span":
+        if self._ann is not None:
+            self._ann.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.end_ns = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.args["error"] = exc_type.__name__
         self._tracer._commit(self)
@@ -56,6 +82,9 @@ class _NoopSpan:
     paths stay allocation-free when monitoring is off."""
 
     __slots__ = ()
+    #: so a caller may read a span's length without asking which kind
+    #: it was handed
+    duration_s = 0.0
 
     def __enter__(self):
         return self
@@ -80,6 +109,9 @@ class Tracer:
 
     def __init__(self, max_events: int = 200_000, enabled: bool = True):
         self.enabled = enabled
+        #: the profiler's annotation class (`monitor.enable()` sets it),
+        #: or None: spans then go to the ring alone
+        self.annotation = None
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=max_events)
         self._origin_ns = time.perf_counter_ns()
@@ -117,22 +149,6 @@ class Tracer:
                 "pid": self._pid,
                 "tid": span.thread_id,
                 "args": span.args,
-            })
-
-    def add_complete_event(self, name: str, start_s: float, duration_s: float,
-                           **args):
-        """Record a span whose window was timed externally (e.g. a
-        TrainingMasterStats phase event) — start_s is seconds since an
-        arbitrary epoch consistent within the caller."""
-        if not self.enabled:
-            return
-        with self._lock:
-            self._note_drop()
-            self._events.append({
-                "name": name, "ph": "X",
-                "ts": start_s * 1e6, "dur": duration_s * 1e6,
-                "pid": self._pid, "tid": threading.get_ident(),
-                "args": args,
             })
 
     def complete_between(self, name: str, t0_perf: float, t1_perf: float,

@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu import monitor
 from deeplearning4j_tpu.nd import quant
 from deeplearning4j_tpu.nd.donation import donate_argnums
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer
@@ -336,6 +337,15 @@ class PagedDecodeEngine:
         # bookkeeping only — what request tracing reads to say whether
         # an admission rode a shared prefix / forked CoW blocks)
         self.admit_info: dict = {}
+        # shared with the scheduler for its spans and timers: the
+        # scheduler's iteration number, which it advances and every
+        # span of the iteration carries as `it`; the padded prompt
+        # length of the last wave; and the seconds the last
+        # `admit_many` / `step` call's `*/wait` spans spent blocked on
+        # readbacks (0.0 with monitoring off: nothing is timed then)
+        self.loop_it = 0
+        self.admit_bucket = 0
+        self.wait_s = 0.0
 
     # ------------------------------------------------------------ queries
     @property
@@ -997,44 +1007,48 @@ class PagedDecodeEngine:
         if not requests:
             return []
         self.admit_info = {}
+        self.wait_s = 0.0
+        it = self.loop_it
         wave = []
         try:
-            for r in requests:
-                prompt = np.asarray(r["prompt_ids"])
-                if prompt.ndim == 2 and prompt.shape[0] == 1:
-                    prompt = prompt[0]
-                if prompt.ndim != 1 or prompt.size == 0:
-                    raise ValueError(
-                        f"prompt must be a non-empty 1-D id sequence; "
-                        f"got shape {prompt.shape}")
-                P = int(prompt.shape[0])
-                n_tokens = int(r["n_tokens"])
-                self.check_budget(P, n_tokens, prompt_ids=prompt)
-                slot = next((i for i, s in enumerate(self.slots)
-                             if s is None
-                             and all(i != w["slot"] for w in wave)),
-                            None)
-                if slot is None:
-                    break
-                entry = self._match_prefix(prompt)
-                if entry is None:
-                    # no registered exact match — the radix tree
-                    # catches block-aligned mid-prompt sharing across
-                    # ALL prior admissions (prefix_cache="radix")
-                    entry = self._match_radix(prompt)
-                if entry is None:
-                    nb = self._admit_blocks(P, n_tokens)
-                    blocks = self._alloc_admit(nb)
-                    if blocks is None:
+            with monitor.span("serve/admit/plan", it=it):
+                for r in requests:
+                    prompt = np.asarray(r["prompt_ids"])
+                    if prompt.ndim == 2 and prompt.shape[0] == 1:
+                        prompt = prompt[0]
+                    if prompt.ndim != 1 or prompt.size == 0:
+                        raise ValueError(
+                            f"prompt must be a non-empty 1-D id sequence; "
+                            f"got shape {prompt.shape}")
+                    P = int(prompt.shape[0])
+                    n_tokens = int(r["n_tokens"])
+                    self.check_budget(P, n_tokens, prompt_ids=prompt)
+                    slot = next((i for i, s in enumerate(self.slots)
+                                 if s is None
+                                 and all(i != w["slot"] for w in wave)),
+                                None)
+                    if slot is None:
                         break
-                    w = dict(blocks=blocks, grants=nb, entry=None,
-                             fork=None)
-                else:
-                    w = self._cow_admit_blocks(entry, P, n_tokens)
-                    if w is None:
-                        break
-                w.update(slot=slot, prompt=prompt, n_tokens=n_tokens, r=r)
-                wave.append(w)
+                    entry = self._match_prefix(prompt)
+                    if entry is None:
+                        # no registered exact match — the radix tree
+                        # catches block-aligned mid-prompt sharing across
+                        # ALL prior admissions (prefix_cache="radix")
+                        entry = self._match_radix(prompt)
+                    if entry is None:
+                        nb = self._admit_blocks(P, n_tokens)
+                        blocks = self._alloc_admit(nb)
+                        if blocks is None:
+                            break
+                        w = dict(blocks=blocks, grants=nb, entry=None,
+                                 fork=None)
+                    else:
+                        w = self._cow_admit_blocks(entry, P, n_tokens)
+                        if w is None:
+                            break
+                    w.update(slot=slot, prompt=prompt, n_tokens=n_tokens,
+                             r=r)
+                    wave.append(w)
             if not wave:
                 return []
             out = self._admit_dispatch(wave)
@@ -1043,14 +1057,15 @@ class PagedDecodeEngine:
                 # the tree on the way in (automatic dedup — no manual
                 # register/release); the partial tail block, which the
                 # slot will keep writing, never enters
-                for w in wave:
-                    slot = self.slots[w["slot"]]
-                    if slot is None:      # n_tokens == 1: already done
-                        continue
-                    n_full = len(w["prompt"]) // self.block_len
-                    if n_full:
-                        self._radix.insert(w["prompt"],
-                                           slot.blocks[:n_full])
+                with monitor.span("serve/admit/post", it=it):
+                    for w in wave:
+                        slot = self.slots[w["slot"]]
+                        if slot is None:  # n_tokens == 1: already done
+                            continue
+                        n_full = len(w["prompt"]) // self.block_len
+                        if n_full:
+                            self._radix.insert(w["prompt"],
+                                               slot.blocks[:n_full])
             return out
         except Exception:
             # a mid-wave failure (validation of a later request, a
@@ -1146,70 +1161,79 @@ class PagedDecodeEngine:
         # past each slot's position, where every later read masks them
         Pb = bucket_len(max(int(w["prompt"].shape[0]) for w in wave),
                         self.max_total_tokens)
+        self.admit_bucket = Pb
+        it = self.loop_it
 
-        net = self.net
-        from deeplearning4j_tpu.zoo.transformer import get_prefill_bucketed
-        prefill = get_prefill_bucketed(net)
-        carries = {str(i): layer.init_carry(k2, net.dtype.compute_dtype)
-                   for i, layer in enumerate(net.layers)
-                   if isinstance(layer, BaseRecurrentLayer)}
-        prompts = np.zeros((k2, Pb), np.int32)
-        last_idx = np.zeros(k2, np.int32)
-        for j, w in enumerate(wave):
-            prompts[j, :w["prompt"].shape[0]] = w["prompt"]
-            last_idx[j] = w["prompt"].shape[0] - 1
-        for j in range(k, k2):                # dummy width-padding rows
-            prompts[j] = prompts[k - 1]
-            last_idx[j] = last_idx[k - 1]
-        probs, carries = prefill(self._params, net.net_state,
-                                 jnp.asarray(prompts), carries,
-                                 jnp.asarray(last_idx))
+        with monitor.span("serve/admit/dispatch", it=it, width=k2,
+                          bucket=Pb):
+            net = self.net
+            from deeplearning4j_tpu.zoo.transformer import (
+                get_prefill_bucketed)
+            prefill = get_prefill_bucketed(net)
+            carries = {str(i): layer.init_carry(k2, net.dtype.compute_dtype)
+                       for i, layer in enumerate(net.layers)
+                       if isinstance(layer, BaseRecurrentLayer)}
+            prompts = np.zeros((k2, Pb), np.int32)
+            last_idx = np.zeros(k2, np.int32)
+            for j, w in enumerate(wave):
+                prompts[j, :w["prompt"].shape[0]] = w["prompt"]
+                last_idx[j] = w["prompt"].shape[0] - 1
+            for j in range(k, k2):            # dummy width-padding rows
+                prompts[j] = prompts[k - 1]
+                last_idx[j] = last_idx[k - 1]
+            probs, carries = prefill(self._params, net.net_state,
+                                     jnp.asarray(prompts), carries,
+                                     jnp.asarray(last_idx))
 
-        block_carries = [carries[str(i)] for i in self.pool.layer_indices]
-        max_rows = max(c[0].shape[1] // self.block_len
-                       for c in block_carries)
-        rows = np.full((k2, max_rows), GARBAGE_BLOCK, np.int32)
-        keys = np.zeros((k2, 2), np.uint32)
-        emit0 = np.zeros(k2, np.int32)
-        temps = np.zeros(k2, np.float32)
-        top_ps = np.ones(k2, np.float32)
-        for j, w in enumerate(wave):
-            rows[j, :len(w["blocks"])] = w["blocks"]
-            r = w["r"]
-            if r.get("rng") is not None:
-                keys[j] = np.asarray(r["rng"], np.uint32).reshape(2)
-            emit0[j] = int(r.get("emit_start") or 0)
-            temps[j] = r.get("temperature") or 0.0
-            p = r.get("top_p")
-            top_ps[j] = 1.0 if p is None else p
-        # all-greedy waves skip the sampling chain (sort + threefry) on
-        # the TTFT-critical path — same static-variant split the
-        # decode program uses
-        greedy = not bool((temps > 0).any())
-        fin = self._admit_finish.get((k2, greedy))
-        if fin is None:
-            fin = self._admit_finish[(k2, greedy)] = \
-                self._build_admit_finish(k2, greedy)
-        self.pool.kv, firsts = fin(
-            self.pool.kv, jnp.asarray(rows),
-            tuple((c[0], c[1]) for c in block_carries), probs,
-            jnp.asarray(keys), jnp.asarray(emit0), jnp.asarray(temps),
-            jnp.asarray(top_ps))
-        firsts = np.asarray(firsts)
+            block_carries = [carries[str(i)]
+                             for i in self.pool.layer_indices]
+            max_rows = max(c[0].shape[1] // self.block_len
+                           for c in block_carries)
+            rows = np.full((k2, max_rows), GARBAGE_BLOCK, np.int32)
+            keys = np.zeros((k2, 2), np.uint32)
+            emit0 = np.zeros(k2, np.int32)
+            temps = np.zeros(k2, np.float32)
+            top_ps = np.ones(k2, np.float32)
+            for j, w in enumerate(wave):
+                rows[j, :len(w["blocks"])] = w["blocks"]
+                r = w["r"]
+                if r.get("rng") is not None:
+                    keys[j] = np.asarray(r["rng"], np.uint32).reshape(2)
+                emit0[j] = int(r.get("emit_start") or 0)
+                temps[j] = r.get("temperature") or 0.0
+                p = r.get("top_p")
+                top_ps[j] = 1.0 if p is None else p
+            # all-greedy waves skip the sampling chain (sort + threefry)
+            # on the TTFT-critical path — same static-variant split the
+            # decode program uses
+            greedy = not bool((temps > 0).any())
+            fin = self._admit_finish.get((k2, greedy))
+            if fin is None:
+                fin = self._admit_finish[(k2, greedy)] = \
+                    self._build_admit_finish(k2, greedy)
+            self.pool.kv, firsts = fin(
+                self.pool.kv, jnp.asarray(rows),
+                tuple((c[0], c[1]) for c in block_carries), probs,
+                jnp.asarray(keys), jnp.asarray(emit0), jnp.asarray(temps),
+                jnp.asarray(top_ps))
+        with monitor.span("serve/admit/wait", it=it) as sp:
+            firsts = np.asarray(firsts)
+        self.wait_s += sp.duration_s
 
-        # ledger: the prefill program touched k2*Pb token-positions —
-        # live prompt positions are useful, a requeued continuation's
-        # re-prefill is preempt_discard (that work was already done
-        # once), width/length padding is pad_waste
-        fresh = sum(int(w["prompt"].shape[0]) for w in wave
-                    if not int(w["r"].get("emit_start") or 0))
-        redone = sum(int(w["prompt"].shape[0]) for w in wave
-                     if int(w["r"].get("emit_start") or 0))
-        self.goodput.account(useful=fresh, preempt_discard=redone,
-                             pad_waste=k2 * Pb - fresh - redone)
+        with monitor.span("serve/admit/post", it=it):
+            # ledger: the prefill program touched k2*Pb token-positions
+            # — live prompt positions are useful, a requeued
+            # continuation's re-prefill is preempt_discard (that work
+            # was already done once), width/length padding is pad_waste
+            fresh = sum(int(w["prompt"].shape[0]) for w in wave
+                        if not int(w["r"].get("emit_start") or 0))
+            redone = sum(int(w["prompt"].shape[0]) for w in wave
+                         if int(w["r"].get("emit_start") or 0))
+            self.goodput.account(useful=fresh, preempt_discard=redone,
+                                 pad_waste=k2 * Pb - fresh - redone)
 
-        for j, w in enumerate(wave):
-            self._finish_admission(w, int(firsts[j]), keys[j], results)
+            for j, w in enumerate(wave):
+                self._finish_admission(w, int(firsts[j]), keys[j], results)
 
     def _finish_admission(self, w, first, key, results):
         """Slot bookkeeping shared by the fresh-prefill and shared-
@@ -1264,53 +1288,60 @@ class PagedDecodeEngine:
         suffix scores, or from the prefix's cached last-position probs
         when the prompt IS the prefix. No monolithic prefill runs at
         all: that is the `serving_prefix_prefill_reduction` lever."""
-        # fork copies must land BEFORE any suffix/decode write reaches
-        # a block another holder still maps
-        pairs = [w["fork"] for w in wave if w["fork"] is not None]
-        if pairs:
-            self._run_fork(pairs)
-        for w in wave:
-            slot = w["slot"]
-            self.block_tables[slot] = GARBAGE_BLOCK
-            self.block_tables[slot, :len(w["blocks"])] = w["blocks"]
-            w["suffix"] = w["prompt"][w["entry"]["len"]:]
-        keys_by_slot = {}
-        firsts = {}
-        ext = [w for w in wave if w["suffix"].shape[0] > 0]
+        it = self.loop_it
+        S = self.n_slots
+        with monitor.span("serve/admit/dispatch", it=it, width=len(wave)):
+            # fork copies must land BEFORE any suffix/decode write
+            # reaches a block another holder still maps
+            pairs = [w["fork"] for w in wave if w["fork"] is not None]
+            if pairs:
+                self._run_fork(pairs)
+            for w in wave:
+                slot = w["slot"]
+                self.block_tables[slot] = GARBAGE_BLOCK
+                self.block_tables[slot, :len(w["blocks"])] = w["blocks"]
+                w["suffix"] = w["prompt"][w["entry"]["len"]:]
+            keys_by_slot = {}
+            firsts = {}
+            ext = [w for w in wave if w["suffix"].shape[0] > 0]
+            if ext:
+                K = bucket_len(max(int(w["suffix"].shape[0]) for w in ext),
+                               self.max_total_tokens)
+                self.admit_bucket = K
+                token_mat = np.zeros((S, K), np.int32)
+                n_valid = np.zeros(S, np.int32)
+                pos = np.zeros(S, np.int32)
+                keys = np.zeros((S, 2), np.uint32)
+                emit0 = np.zeros(S, np.int32)
+                temps = np.zeros(S, np.float32)
+                top_ps = np.ones(S, np.float32)
+                for w in ext:
+                    s, r = w["slot"], w["r"]
+                    Ts = int(w["suffix"].shape[0])
+                    token_mat[s, :Ts] = w["suffix"]
+                    n_valid[s] = Ts
+                    pos[s] = w["entry"]["len"]
+                    if r.get("rng") is not None:
+                        keys[s] = np.asarray(r["rng"],
+                                             np.uint32).reshape(2)
+                    emit0[s] = int(r.get("emit_start") or 0)
+                    temps[s] = r.get("temperature") or 0.0
+                    p = r.get("top_p")
+                    top_ps[s] = 1.0 if p is None else p
+                    keys_by_slot[s] = keys[s].copy()
+                greedy = not bool((temps > 0).any())
+                score = self._get_score(K, greedy)
+                kv, _, chosen = score(
+                    self._params, self.net.net_state, self.pool.kv,
+                    jnp.asarray(self.block_tables), jnp.asarray(token_mat),
+                    jnp.asarray(pos), jnp.asarray(n_valid),
+                    jnp.asarray(keys), jnp.asarray(emit0),
+                    jnp.asarray(temps), jnp.asarray(top_ps))
+                self.pool.kv = kv
         if ext:
-            S = self.n_slots
-            K = bucket_len(max(int(w["suffix"].shape[0]) for w in ext),
-                           self.max_total_tokens)
-            token_mat = np.zeros((S, K), np.int32)
-            n_valid = np.zeros(S, np.int32)
-            pos = np.zeros(S, np.int32)
-            keys = np.zeros((S, 2), np.uint32)
-            emit0 = np.zeros(S, np.int32)
-            temps = np.zeros(S, np.float32)
-            top_ps = np.ones(S, np.float32)
-            for w in ext:
-                s, r = w["slot"], w["r"]
-                Ts = int(w["suffix"].shape[0])
-                token_mat[s, :Ts] = w["suffix"]
-                n_valid[s] = Ts
-                pos[s] = w["entry"]["len"]
-                if r.get("rng") is not None:
-                    keys[s] = np.asarray(r["rng"], np.uint32).reshape(2)
-                emit0[s] = int(r.get("emit_start") or 0)
-                temps[s] = r.get("temperature") or 0.0
-                p = r.get("top_p")
-                top_ps[s] = 1.0 if p is None else p
-                keys_by_slot[s] = keys[s].copy()
-            greedy = not bool((temps > 0).any())
-            score = self._get_score(K, greedy)
-            kv, _, chosen = score(
-                self._params, self.net.net_state, self.pool.kv,
-                jnp.asarray(self.block_tables), jnp.asarray(token_mat),
-                jnp.asarray(pos), jnp.asarray(n_valid),
-                jnp.asarray(keys), jnp.asarray(emit0),
-                jnp.asarray(temps), jnp.asarray(top_ps))
-            self.pool.kv = kv
-            chosen = np.asarray(chosen)
+            with monitor.span("serve/admit/wait", it=it) as sp:
+                chosen = np.asarray(chosen)
+            self.wait_s += sp.duration_s
             for w in ext:
                 firsts[w["slot"]] = int(chosen[w["slot"]])
             # ledger: the suffix-extension score program touched S*K
@@ -1328,38 +1359,45 @@ class PagedDecodeEngine:
         # just run the sampling tail on the cached distribution
         empt = [w for w in wave if w["suffix"].shape[0] == 0]
         if empt:
-            width = 1
-            while width < len(empt):
-                width *= 2
-            probs0 = empt[0]["entry"]["probs"]
-            probs = np.zeros((width,) + probs0.shape, probs0.dtype)
-            keys = np.zeros((width, 2), np.uint32)
-            emit0 = np.zeros(width, np.int32)
-            temps = np.zeros(width, np.float32)
-            top_ps = np.ones(width, np.float32)
-            for j, w in enumerate(empt):
-                r = w["r"]
-                probs[j] = w["entry"]["probs"]
-                if r.get("rng") is not None:
-                    keys[j] = np.asarray(r["rng"], np.uint32).reshape(2)
-                emit0[j] = int(r.get("emit_start") or 0)
-                temps[j] = r.get("temperature") or 0.0
-                p = r.get("top_p")
-                top_ps[j] = 1.0 if p is None else p
-                keys_by_slot[w["slot"]] = keys[j].copy()
-            greedy = not bool((temps > 0).any())
-            fn = self._first_token.get(greedy)
-            if fn is None:
-                fn = self._first_token[greedy] = \
-                    self._build_first_token(greedy)
-            ids = np.asarray(fn(jnp.asarray(probs), jnp.asarray(keys),
-                                jnp.asarray(emit0), jnp.asarray(temps),
-                                jnp.asarray(top_ps)))
+            with monitor.span("serve/admit/dispatch", it=it,
+                              width=len(empt)):
+                width = 1
+                while width < len(empt):
+                    width *= 2
+                probs0 = empt[0]["entry"]["probs"]
+                probs = np.zeros((width,) + probs0.shape, probs0.dtype)
+                keys = np.zeros((width, 2), np.uint32)
+                emit0 = np.zeros(width, np.int32)
+                temps = np.zeros(width, np.float32)
+                top_ps = np.ones(width, np.float32)
+                for j, w in enumerate(empt):
+                    r = w["r"]
+                    probs[j] = w["entry"]["probs"]
+                    if r.get("rng") is not None:
+                        keys[j] = np.asarray(r["rng"],
+                                             np.uint32).reshape(2)
+                    emit0[j] = int(r.get("emit_start") or 0)
+                    temps[j] = r.get("temperature") or 0.0
+                    p = r.get("top_p")
+                    top_ps[j] = 1.0 if p is None else p
+                    keys_by_slot[w["slot"]] = keys[j].copy()
+                greedy = not bool((temps > 0).any())
+                fn = self._first_token.get(greedy)
+                if fn is None:
+                    fn = self._first_token[greedy] = \
+                        self._build_first_token(greedy)
+                ids = fn(jnp.asarray(probs), jnp.asarray(keys),
+                         jnp.asarray(emit0), jnp.asarray(temps),
+                         jnp.asarray(top_ps))
+            with monitor.span("serve/admit/wait", it=it) as sp:
+                ids = np.asarray(ids)
+            self.wait_s += sp.duration_s
             for j, w in enumerate(empt):
                 firsts[w["slot"]] = int(ids[j])
-        for w in wave:
-            self._finish_admission(w, firsts[w["slot"]],
-                                   keys_by_slot[w["slot"]], results)
+        with monitor.span("serve/admit/post", it=it):
+            for w in wave:
+                self._finish_admission(w, firsts[w["slot"]],
+                                       keys_by_slot[w["slot"]], results)
 
     # -------------------------------------------- incremental block grants
     def _lowest_progress_active(self) -> int:
@@ -1491,35 +1529,50 @@ class PagedDecodeEngine:
         `drain_preempted()` instead of deadlocking."""
         if speculate is None:
             speculate = self.spec_k is not None
+        self.wait_s = 0.0
         if speculate and self.spec_k:
             return self._spec_step(proposers=proposers)
-        if (self.allocation == "incremental" or self._prefixes
-                or self._radix is not None):
-            # upfront allocation never grows, but the CoW fork pass
-            # (shared write-window blocks) must still run
-            self._grow_block_tables()
+        it = self.loop_it
+        with monitor.span("serve/decode/grow", it=it):
+            if (self.allocation == "incremental" or self._prefixes
+                    or self._radix is not None):
+                # upfront allocation never grows, but the CoW fork pass
+                # (shared write-window blocks) must still run
+                self._grow_block_tables()
         if not self.active.any():
             return {}, []
-        # two static program variants: the greedy-only decode skips the
-        # sampling chain (sort + threefry) — picked whenever no sampled
-        # request is in flight, the common serving case
-        if (self.temp[self.active] > 0).any():
-            if self._decode_full is None:
-                self._decode_full = self._build_decode(greedy_only=False)
-            decode = self._decode_full
-        else:
-            if self._decode_greedy is None:
-                self._decode_greedy = self._build_decode(greedy_only=True)
-            decode = self._decode_greedy
-        kv, toks, valids = decode(
-            self._params, self.net.net_state, self.pool.kv,
-            jnp.asarray(self.block_tables), jnp.asarray(self.last_token),
-            jnp.asarray(self.pos), jnp.asarray(self.remaining),
-            jnp.asarray(self.keys), jnp.asarray(self.emit_idx),
-            jnp.asarray(self.temp), jnp.asarray(self.top_p))
-        self.pool.kv = kv
-        toks = np.asarray(toks)                     # [J, S]
-        valids = np.asarray(valids)
+        with monitor.span("serve/decode/dispatch", it=it):
+            # two static program variants: the greedy-only decode skips
+            # the sampling chain (sort + threefry) — picked whenever no
+            # sampled request is in flight, the common serving case
+            if (self.temp[self.active] > 0).any():
+                if self._decode_full is None:
+                    self._decode_full = self._build_decode(
+                        greedy_only=False)
+                decode = self._decode_full
+            else:
+                if self._decode_greedy is None:
+                    self._decode_greedy = self._build_decode(
+                        greedy_only=True)
+                decode = self._decode_greedy
+            kv, toks, valids = decode(
+                self._params, self.net.net_state, self.pool.kv,
+                jnp.asarray(self.block_tables),
+                jnp.asarray(self.last_token),
+                jnp.asarray(self.pos), jnp.asarray(self.remaining),
+                jnp.asarray(self.keys), jnp.asarray(self.emit_idx),
+                jnp.asarray(self.temp), jnp.asarray(self.top_p))
+            self.pool.kv = kv
+        with monitor.span("serve/decode/wait", it=it) as sp:
+            toks = np.asarray(toks)                     # [J, S]
+            valids = np.asarray(valids)
+        self.wait_s = sp.duration_s
+        with monitor.span("serve/decode/post", it=it):
+            return self._after_decode(toks, valids)
+
+    def _after_decode(self, toks, valids):
+        """Slot bookkeeping for one decode chunk's `[J, S]` tokens and
+        validity mask, read back to the host."""
         taken = valids.sum(axis=0).astype(np.int32)  # [S] tokens emitted
         act = self.active
         # ledger: the decode chunk touched J*S token-positions; emitted
@@ -1620,138 +1673,145 @@ class PagedDecodeEngine:
         Emits 1..k tokens per slot per dispatch."""
         if not self.active.any():
             return {}, []
-        K = self.spec_k
-        S = self.n_slots
-        allow_ngram = proposers is None or "ngram" in proposers
-        allow_trunc = (self._draft_plan is not None
-                       and (proposers is None or "truncated" in proposers))
-        token_mat = np.zeros((S, K), np.int32)
-        n_valid = np.zeros(S, np.int32)
-        by_proposer: Dict[int, str] = {}
-        trunc_slots: List[Tuple[int, int]] = []
-        for s in np.flatnonzero(self.active):
-            s = int(s)
-            token_mat[s, 0] = self.last_token[s]
-            if self.temp[s] > 0 and not self.spec_sampled:
-                n_valid[s] = 1          # sampling has no greedy oracle
-                continue
-            depth = int(min(K, self.remaining[s]))
-            draft = self._propose(s, depth - 1) if allow_ngram else []
-            if draft:
-                by_proposer[s] = "ngram"
-                n_valid[s] = 1 + len(draft)
-                token_mat[s, 1:1 + len(draft)] = draft
-            elif allow_trunc and depth >= 2:
-                # n-gram came up empty — the truncated-layer drafter
-                # takes the slot (drafts filled in below, after its
-                # write window is granted)
-                trunc_slots.append((s, depth))
-                n_valid[s] = depth
-            else:
-                n_valid[s] = 1
-        if trunc_slots:
-            # grant (and CoW-fork) the drafting slots' FULL windows
-            # first: the truncated pass writes draft K/V into the
-            # slot's own not-yet-committed positions [pos, pos+d-2],
-            # all of which the verify dispatch below rewrites with
-            # full-model K/V (write-before-read)
-            self._grow_block_tables(dict(trunc_slots))
-            trunc_slots = [(s, d) for s, d in trunc_slots
-                           if self.slots[s] is not None
-                           and self.active[s]]
-        if trunc_slots:
-            drafts = self._run_draft(trunc_slots)
-            for s, d in trunc_slots:
-                by_proposer[s] = "truncated"
-                token_mat[s, 1:d] = drafts[:d - 1, s]
-        # grant (and CoW-fork) each slot's write window [pos,
-        # pos+n_valid) — pool pressure preempts exactly like the
-        # chunked path
-        self._grow_block_tables(
-            {int(s): int(n_valid[s]) for s in np.flatnonzero(self.active)})
-        n_valid = np.where(self.active, n_valid, 0).astype(np.int32)
+        it = self.loop_it
+        with monitor.span("serve/decode/propose", it=it):
+            K = self.spec_k
+            S = self.n_slots
+            allow_ngram = proposers is None or "ngram" in proposers
+            allow_trunc = (self._draft_plan is not None
+                           and (proposers is None or "truncated" in proposers))
+            token_mat = np.zeros((S, K), np.int32)
+            n_valid = np.zeros(S, np.int32)
+            by_proposer: Dict[int, str] = {}
+            trunc_slots: List[Tuple[int, int]] = []
+            for s in np.flatnonzero(self.active):
+                s = int(s)
+                token_mat[s, 0] = self.last_token[s]
+                if self.temp[s] > 0 and not self.spec_sampled:
+                    n_valid[s] = 1          # sampling has no greedy oracle
+                    continue
+                depth = int(min(K, self.remaining[s]))
+                draft = self._propose(s, depth - 1) if allow_ngram else []
+                if draft:
+                    by_proposer[s] = "ngram"
+                    n_valid[s] = 1 + len(draft)
+                    token_mat[s, 1:1 + len(draft)] = draft
+                elif allow_trunc and depth >= 2:
+                    # n-gram came up empty — the truncated-layer drafter
+                    # takes the slot (drafts filled in below, after its
+                    # write window is granted)
+                    trunc_slots.append((s, depth))
+                    n_valid[s] = depth
+                else:
+                    n_valid[s] = 1
+            if trunc_slots:
+                # grant (and CoW-fork) the drafting slots' FULL windows
+                # first: the truncated pass writes draft K/V into the
+                # slot's own not-yet-committed positions [pos, pos+d-2],
+                # all of which the verify dispatch below rewrites with
+                # full-model K/V (write-before-read)
+                self._grow_block_tables(dict(trunc_slots))
+                trunc_slots = [(s, d) for s, d in trunc_slots
+                               if self.slots[s] is not None
+                               and self.active[s]]
+            if trunc_slots:
+                drafts = self._run_draft(trunc_slots)
+                for s, d in trunc_slots:
+                    by_proposer[s] = "truncated"
+                    token_mat[s, 1:d] = drafts[:d - 1, s]
+        with monitor.span("serve/decode/grow", it=it):
+            # grant (and CoW-fork) each slot's write window [pos,
+            # pos+n_valid) — pool pressure preempts exactly like the
+            # chunked path
+            self._grow_block_tables(
+                {int(s): int(n_valid[s]) for s in np.flatnonzero(self.active)})
+            n_valid = np.where(self.active, n_valid, 0).astype(np.int32)
         if not self.active.any():
             return {}, []
-        greedy_only = not bool((self.temp[self.active] > 0).any())
-        use_rs = self.spec_sampled and not greedy_only
-        score = self._get_score(K, "rs" if use_rs else greedy_only)
-        out = score(
-            self._params, self.net.net_state, self.pool.kv,
-            jnp.asarray(self.block_tables), jnp.asarray(token_mat),
-            jnp.asarray(self.pos), jnp.asarray(n_valid),
-            jnp.asarray(self.keys), jnp.asarray(self.emit_idx),
-            jnp.asarray(self.temp), jnp.asarray(self.top_p))
-        if use_rs:
-            kv, greedy_mat, n_acc, final = out
-            n_acc, final = np.asarray(n_acc), np.asarray(final)
-            chosen = None
-        else:
-            kv, greedy_mat, chosen = out
-            chosen = np.asarray(chosen)
-        self.pool.kv = kv
-        greedy_mat = np.asarray(greedy_mat)
-        self.spec_dispatches_total += 1
-        # ledger: the score program touched S*K token-positions; per
-        # slot, emitted tokens are useful, valid-but-rejected draft
-        # lanes are spec_rejected, positions past n_valid (and whole
-        # inactive rows) are pad_waste — tallied in the accept loop
-        gp_useful = 0
-        gp_rejected = 0
-        emitted: Dict[int, List[int]] = {}
-        finished = []
-        for s in np.flatnonzero(self.active):
-            s = int(s)
-            v = int(n_valid[s])
-            prop = by_proposer.get(s)
-            if self.temp[s] > 0:
-                if use_rs:
-                    # rejection sampling: the first n_acc drafts
-                    # survived their u < q_t(d) tests; `final` is the
-                    # residual resample at the divergence (or the
-                    # bonus token when every draft survived)
-                    acc = min(int(n_acc[s]), v - 1)
-                    toks = [int(token_mat[s, j])
-                            for j in range(1, 1 + acc)] + [int(final[s])]
+        with monitor.span("serve/decode/dispatch", it=it):
+            greedy_only = not bool((self.temp[self.active] > 0).any())
+            use_rs = self.spec_sampled and not greedy_only
+            score = self._get_score(K, "rs" if use_rs else greedy_only)
+            out = score(
+                self._params, self.net.net_state, self.pool.kv,
+                jnp.asarray(self.block_tables), jnp.asarray(token_mat),
+                jnp.asarray(self.pos), jnp.asarray(n_valid),
+                jnp.asarray(self.keys), jnp.asarray(self.emit_idx),
+                jnp.asarray(self.temp), jnp.asarray(self.top_p))
+        with monitor.span("serve/decode/wait", it=it) as sp:
+            if use_rs:
+                kv, greedy_mat, n_acc, final = out
+                n_acc, final = np.asarray(n_acc), np.asarray(final)
+                chosen = None
+            else:
+                kv, greedy_mat, chosen = out
+                chosen = np.asarray(chosen)
+            self.pool.kv = kv
+            greedy_mat = np.asarray(greedy_mat)
+        self.wait_s = sp.duration_s
+        with monitor.span("serve/decode/post", it=it):
+            self.spec_dispatches_total += 1
+            # ledger: the score program touched S*K token-positions; per
+            # slot, emitted tokens are useful, valid-but-rejected draft
+            # lanes are spec_rejected, positions past n_valid (and whole
+            # inactive rows) are pad_waste — tallied in the accept loop
+            gp_useful = 0
+            gp_rejected = 0
+            emitted: Dict[int, List[int]] = {}
+            finished = []
+            for s in np.flatnonzero(self.active):
+                s = int(s)
+                v = int(n_valid[s])
+                prop = by_proposer.get(s)
+                if self.temp[s] > 0:
+                    if use_rs:
+                        # rejection sampling: the first n_acc drafts
+                        # survived their u < q_t(d) tests; `final` is the
+                        # residual resample at the divergence (or the
+                        # bonus token when every draft survived)
+                        acc = min(int(n_acc[s]), v - 1)
+                        toks = [int(token_mat[s, j])
+                                for j in range(1, 1 + acc)] + [int(final[s])]
+                    else:
+                        toks = [int(chosen[s])]
+                    if v > 1:
+                        self.spec_proposed_total += v - 1
+                        self.spec_accepted_total += len(toks) - 1
                 else:
-                    toks = [int(chosen[s])]
-                if v > 1:
+                    # acceptance: draft j survives iff it EQUALS the
+                    # target's argmax after position j-1; the first miss
+                    # truncates and the target's token takes its place
+                    row = greedy_mat[s]
+                    toks = [int(row[0])]
+                    for j in range(1, v):
+                        if int(token_mat[s, j]) != toks[-1]:
+                            break
+                        toks.append(int(row[j]))
                     self.spec_proposed_total += v - 1
                     self.spec_accepted_total += len(toks) - 1
-            else:
-                # acceptance: draft j survives iff it EQUALS the
-                # target's argmax after position j-1; the first miss
-                # truncates and the target's token takes its place
-                row = greedy_mat[s]
-                toks = [int(row[0])]
-                for j in range(1, v):
-                    if int(token_mat[s, j]) != toks[-1]:
-                        break
-                    toks.append(int(row[j]))
-                self.spec_proposed_total += v - 1
-                self.spec_accepted_total += len(toks) - 1
-            if prop is not None and v > 1:
-                self.spec_proposed_by[prop] += v - 1
-                self.spec_accepted_by[prop] += len(toks) - 1
-            n = len(toks)
-            gp_useful += n
-            gp_rejected += v - n
-            self.spec_emitted_total += n
-            self.pos[s] += n
-            self.emit_idx[s] += n
-            self.remaining[s] -= n
-            self.last_token[s] = toks[-1]
-            slot = self.slots[s]
-            slot.emitted += n
-            slot.pos = int(self.pos[s])
-            slot.history.extend(toks)
-            emitted[s] = toks
-            if self.remaining[s] <= 0:
-                finished.append(s)
-                self._release(s)
-        self.goodput.account(
-            useful=gp_useful, spec_rejected=gp_rejected,
-            pad_waste=S * K - gp_useful - gp_rejected)
-        return emitted, finished
+                if prop is not None and v > 1:
+                    self.spec_proposed_by[prop] += v - 1
+                    self.spec_accepted_by[prop] += len(toks) - 1
+                n = len(toks)
+                gp_useful += n
+                gp_rejected += v - n
+                self.spec_emitted_total += n
+                self.pos[s] += n
+                self.emit_idx[s] += n
+                self.remaining[s] -= n
+                self.last_token[s] = toks[-1]
+                slot = self.slots[s]
+                slot.emitted += n
+                slot.pos = int(self.pos[s])
+                slot.history.extend(toks)
+                emitted[s] = toks
+                if self.remaining[s] <= 0:
+                    finished.append(s)
+                    self._release(s)
+            self.goodput.account(
+                useful=gp_useful, spec_rejected=gp_rejected,
+                pad_waste=S * K - gp_useful - gp_rejected)
+            return emitted, finished
 
     # ------------------------------------------------------------ evict
     def evict(self, slot: int):

@@ -322,6 +322,10 @@ class GenerationServer(ParallelInference):
         # registry); the scheduler publishes the deltas each loop
         self._grants_seen = 0
         self._requeue_seen = 0
+        # seconds of the current scheduler iteration inside the
+        # `serve/admit` and `serve/decode` spans that their timers
+        # observe: the rest of `serve/loop` is the scheduler's own
+        self._dispatch_s = 0.0
         # lifecycle: draining refuses admissions while in-flight
         # streams finish (the hot-swap handoff); stopped is terminal
         self._draining = False
@@ -861,6 +865,36 @@ class GenerationServer(ParallelInference):
             "step": reg.timer("serving_step_seconds",
                               "one continuous-batching decode dispatch",
                               **lbl),
+            # the loop from inside, observed from the `serve/*` spans'
+            # own start and end: per iteration sched_host + admit_wave
+            # + decode_host + decode_wait is the `serve/loop` span
+            "sched_host": reg.timer(
+                "serving_sched_host_seconds",
+                "one scheduler iteration less its admission waves and "
+                "decode dispatch", **lbl),
+            "decode_host": reg.timer(
+                "serving_decode_host_seconds",
+                "one decode dispatch's host work: block grants, uploads "
+                "and the launch, slot bookkeeping", **lbl),
+            "decode_wait": reg.timer(
+                "serving_decode_wait_seconds",
+                "one decode dispatch's host blocked on the readback",
+                **lbl),
+            "admit_wave": reg.timer(
+                "serving_admit_wave_seconds",
+                "one admission wave, first-token fan-out included",
+                **lbl),
+            "admit_wait": reg.timer(
+                "serving_admit_wait_seconds",
+                "one admission wave's host blocked on the readback",
+                **lbl),
+            "admit_waves": reg.counter(
+                "serving_admit_waves_total",
+                "admission waves dispatched", **lbl),
+            "batch_slots": reg.histogram(
+                "serving_decode_batch_slots",
+                "active slots at each decode dispatch",
+                buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256), **lbl),
             "goodput_frac": reg.gauge(
                 GOODPUT_FRACTION_GAUGE,
                 "useful token-positions / dispatched token-positions "
@@ -940,22 +974,35 @@ class GenerationServer(ParallelInference):
     def _collect_loop(self):
         """The scheduler loop (replaces the coalescing collector):
         admissions, one decode dispatch, stream fan-out, eviction,
-        gauges — then block on the queue only when fully idle."""
+        gauges — then block on the queue only when fully idle. Each
+        iteration is one `serve/loop` span whose number `it` every
+        span and request-lane phase of the iteration carries."""
         eng = self.engine
         while self._running:
+            eng.loop_it += 1
+            it = eng.loop_it
             try:
-                progressed = self._schedule_once(eng)
+                with monitor.span("serve/loop", it=it,
+                                  active=eng.active_slots,
+                                  pending=len(self._pending)) as loop:
+                    progressed = self._schedule_once(eng)
             except Exception as e:  # noqa: BLE001 — a poisoned dispatch
                 # must fail every waiting consumer, not hang them on a
                 # dead scheduler (ParallelInference._execute's contract)
                 self._fail_all(e)
                 continue
-            if not progressed:
+            if progressed:
+                m = self._serving_metrics()
+                if m is not None:
+                    m["sched_host"].observe(loop.duration_s
+                                            - self._dispatch_s)
+            else:
                 # fully idle: park on the queue (a submit wakes us)
-                try:
-                    item = self._queue.get(timeout=self.idle_wait_s)
-                except queue.Empty:
-                    continue
+                with monitor.span("serve/sched/park", it=it):
+                    try:
+                        item = self._queue.get(timeout=self.idle_wait_s)
+                    except queue.Empty:
+                        continue
                 self._queue_item_taken(item)
                 if item is not None:
                     self._pending.append(item)
@@ -985,6 +1032,110 @@ class GenerationServer(ParallelInference):
 
     def _schedule_once(self, eng) -> bool:
         m = self._serving_metrics()
+        it = eng.loop_it
+        self._dispatch_s = 0.0
+        with monitor.span("serve/sched/intake", it=it):
+            progressed = self._intake(eng, m)
+            wave, requests, shed = self._next_wave(eng, m)
+        while wave:
+            with monitor.span("serve/admit", it=it,
+                              width=len(wave)) as sp:
+                admitted = self._admit(eng, m, it, wave, requests)
+                sp.set(admitted=admitted, bucket=eng.admit_bucket)
+            if not admitted:
+                break
+            self._dispatch_s += sp.duration_s
+            if m is not None:
+                m["admit_waves"].inc()
+                m["admit_wave"].observe(sp.duration_s)
+                m["admit_wait"].observe(eng.wait_s)
+            progressed = True
+            with monitor.span("serve/sched/intake", it=it):
+                wave, requests, more = self._next_wave(eng, m)
+                shed = shed or more
+        progressed = progressed or shed
+        # --------------------------------------------------- decode
+        if eng.active.any():
+            n_active = eng.active_slots
+            t0 = time.perf_counter()
+            with monitor.span("serve/decode", it=it,
+                              active=n_active) as sp:
+                emitted, finished = eng.step(
+                    speculate=self._spec_policy(),
+                    proposers=self._spec_proposers())
+            dt = time.perf_counter() - t0
+            if self.dispatch_floor_s is not None \
+                    and dt < self.dispatch_floor_s:
+                time.sleep(self.dispatch_floor_s - dt)
+                dt = self.dispatch_floor_s   # EWMA/trace see the
+                # emulated device rate, not the host-compute rate
+            with monitor.span("serve/sched/fanout", it=it):
+                # dispatch-level speculative deltas for trace
+                # attribution — read BEFORE _spec_update advances the
+                # *_seen cursors
+                d_spec_prop = (eng.spec_proposed_total
+                               - self._spec_proposed_seen)
+                d_spec_acc = (eng.spec_accepted_total
+                              - self._spec_accepted_seen)
+                self._spec_update(m)
+                now = time.monotonic()
+                # pool-pressure preemptions (incremental allocation):
+                # requeue each evicted request as a continuation at the
+                # HEAD of the admission queue — it predates everything
+                # queued, and its emitted tokens stand (the engine
+                # re-admits prompt+emitted at the same rng emit offset)
+                preempted = eng.drain_preempted()
+                if preempted:
+                    requeued = []
+                    for note in preempted:
+                        entry = self._slot2req.pop(note["slot"], None)
+                        if entry is not None:
+                            requeued.append(entry)
+                            tr = entry[0].stream.trace
+                            if tr is not None:
+                                tr.event("preempt_requeue",
+                                         emitted=int(
+                                             note.get("emitted", 0)))
+                    self._pending[:0] = requeued
+                n_tok = sum(len(ts) for ts in emitted.values())
+                if n_tok:
+                    self._dispatch_s += sp.duration_s
+                if m is not None and n_tok:
+                    m["step"].observe(dt)
+                    m["tokens"].inc(n_tok)
+                    # the same dispatch from inside: the engine's wait
+                    # span against the rest of `serve/decode`
+                    m["decode_wait"].observe(eng.wait_s)
+                    m["decode_host"].observe(sp.duration_s - eng.wait_s)
+                    m["batch_slots"].observe(n_active)
+                if n_tok and dt > 0:
+                    rate = n_tok / dt
+                    self._ewma_tok_s = (rate if self._ewma_tok_s is None
+                                        else 0.8 * self._ewma_tok_s
+                                        + 0.2 * rate)
+                t1 = t0 + dt
+                for slot, toks in emitted.items():
+                    stream = self._slot2req[slot][0].stream
+                    stream._emit_many(toks, now)
+                    tr = stream.trace
+                    if tr is not None:
+                        args = {"tokens": len(toks), "it": it}
+                        if d_spec_prop:
+                            args["spec_proposed"] = d_spec_prop
+                            args["spec_accepted"] = d_spec_acc
+                        tr.phase("decode", t0, t1, **args)
+                for slot in finished:
+                    req, fut, _ = self._slot2req.pop(slot)
+                    self._finish(req, m)
+            progressed = True
+        if m is not None:
+            with monitor.span("serve/sched/gauges", it=it):
+                self._publish_gauges(eng, m)
+        return progressed
+
+    def _intake(self, eng, m) -> bool:
+        """Control requests, cancellations, and the submit queue drained
+        into `_pending` (shedding what the SLO policy refuses)."""
         progressed = False
         # ------------------------------------------ control requests
         # (prefix registrations from foreign threads — the engine is
@@ -1034,6 +1185,14 @@ class GenerationServer(ParallelInference):
                 req.stream._fail(ShedError(reason))
                 continue
             self._pending.append(item)
+        return progressed
+
+    def _next_wave(self, eng, m):
+        """The FIFO prefix of `_pending` the engine is offered as one
+        admission wave -> (wave, its request dicts, shed): the wave is
+        empty when nothing is queued or the head has to wait; `shed`
+        says a head that can never be admitted was failed on the way."""
+        shed = False
         while self._pending:
             head = self._pending[0]
             if head[0].stream.cancelled:
@@ -1072,7 +1231,7 @@ class GenerationServer(ParallelInference):
                         m["shed"].inc()
                     self._note_shed(head[0], str(e))
                     head[0].stream._fail(ShedError(str(e)))
-                    progressed = True
+                    shed = True
                     continue
                 break    # FIFO: never leapfrog the head request
             # admission WAVE: the FIFO prefix — prompt lengths may be
@@ -1088,23 +1247,31 @@ class GenerationServer(ParallelInference):
                 if len(wave) >= eng.free_slots:
                     break   # admission can never exceed free slots —
                     # don't build request dicts for a deep backlog
-            t0p = time.perf_counter()
-            admitted = eng.admit_many([
+            return wave, [
                 dict(prompt_ids=it[0].effective_prompt(),
                      n_tokens=it[0].n_left, request_id=id(it[0]),
                      temperature=it[0].temperature,
                      top_p=it[0].top_p, rng=it[0].rng,
                      emit_start=it[0].emit_base + it[0].emitted)
-                for it in wave])
-            if not admitted:
-                break
-            if self.dispatch_floor_s is not None:
-                dtp = time.perf_counter() - t0p
-                if dtp < self.dispatch_floor_s:
-                    # the prefill wave is device work too — under the
-                    # emulated floor it must overlap across replicas
-                    # the same way decode dispatches do
-                    time.sleep(self.dispatch_floor_s - dtp)
+                for it in wave], shed
+        return [], [], shed
+
+    def _admit(self, eng, m, it, wave, requests) -> int:
+        """One admission wave through the engine and its first tokens
+        out to the streams -> how many of `wave` were admitted (the
+        engine admits a prefix of it)."""
+        t0p = time.perf_counter()
+        admitted = eng.admit_many(requests)
+        if not admitted:
+            return 0
+        if self.dispatch_floor_s is not None:
+            dtp = time.perf_counter() - t0p
+            if dtp < self.dispatch_floor_s:
+                # the prefill wave is device work too — under the
+                # emulated floor it must overlap across replicas
+                # the same way decode dispatches do
+                time.sleep(self.dispatch_floor_s - dtp)
+        with monitor.span("serve/admit/fanout", it=it):
             t1p = time.perf_counter()
             now = time.monotonic()
             for (slot, first, done), (req, fut, t_submit) in zip(
@@ -1119,7 +1286,7 @@ class GenerationServer(ParallelInference):
                     info = eng.admit_info.get(slot) or {}
                     if fresh:
                         tr.phase("queued", tr.t_created, t0p)
-                    tr.phase("prefill", t0p, t1p,
+                    tr.phase("prefill", t0p, t1p, it=it,
                              wave_width=len(admitted), slot=slot,
                              continuation=not fresh, **info)
                     if info.get("cow_fork"):
@@ -1136,112 +1303,52 @@ class GenerationServer(ParallelInference):
                 else:
                     req.slot = slot
                     self._slot2req[slot] = (req, fut, t_submit)
-            progressed = True
-        # --------------------------------------------------- decode
-        if eng.active.any():
-            t0 = time.perf_counter()
-            emitted, finished = eng.step(speculate=self._spec_policy(),
-                                         proposers=self._spec_proposers())
-            dt = time.perf_counter() - t0
-            if self.dispatch_floor_s is not None \
-                    and dt < self.dispatch_floor_s:
-                time.sleep(self.dispatch_floor_s - dt)
-                dt = self.dispatch_floor_s   # EWMA/trace see the
-                # emulated device rate, not the host-compute rate
-            # dispatch-level speculative deltas for trace attribution —
-            # read BEFORE _spec_update advances the *_seen cursors
-            d_spec_prop = eng.spec_proposed_total - self._spec_proposed_seen
-            d_spec_acc = eng.spec_accepted_total - self._spec_accepted_seen
-            self._spec_update(m)
-            now = time.monotonic()
-            # pool-pressure preemptions (incremental allocation):
-            # requeue each evicted request as a continuation at the
-            # HEAD of the admission queue — it predates everything
-            # queued, and its emitted tokens stand (the engine
-            # re-admits prompt+emitted at the same rng emit offset)
-            preempted = eng.drain_preempted()
-            if preempted:
-                requeued = []
-                for note in preempted:
-                    entry = self._slot2req.pop(note["slot"], None)
-                    if entry is not None:
-                        requeued.append(entry)
-                        tr = entry[0].stream.trace
-                        if tr is not None:
-                            tr.event("preempt_requeue",
-                                     emitted=int(note.get("emitted", 0)))
-                self._pending[:0] = requeued
-                progressed = True
-            n_tok = sum(len(ts) for ts in emitted.values())
-            if m is not None and n_tok:
-                m["step"].observe(dt)
-                m["tokens"].inc(n_tok)
-            if n_tok and dt > 0:
-                rate = n_tok / dt
-                self._ewma_tok_s = (rate if self._ewma_tok_s is None
-                                    else 0.8 * self._ewma_tok_s
-                                    + 0.2 * rate)
-            t1 = t0 + dt
-            for slot, toks in emitted.items():
-                stream = self._slot2req[slot][0].stream
-                stream._emit_many(toks, now)
-                tr = stream.trace
-                if tr is not None:
-                    args = {"tokens": len(toks)}
-                    if d_spec_prop:
-                        args["spec_proposed"] = d_spec_prop
-                        args["spec_accepted"] = d_spec_acc
-                    tr.phase("decode", t0, t1, **args)
-            for slot in finished:
-                req, fut, _ = self._slot2req.pop(slot)
-                self._finish(req, m)
-            progressed = True
-        # --------------------------------------------------- gauges
-        if m is not None:
-            m["queue"].set(self.queue_depth())
-            m["slots"].set(eng.active_slots)
-            m["blocks"].set(eng.free_blocks)
-            m["pool_free"].set(eng.pool.free_blocks)
-            m["pool_used"].set(eng.pool.used_blocks)
-            if eng.block_grants_total > self._grants_seen:
-                m["grants"].inc(eng.block_grants_total
-                                - self._grants_seen)
-                self._grants_seen = eng.block_grants_total
-            if eng.evict_requeue_total > self._requeue_seen:
-                m["requeue"].inc(eng.evict_requeue_total
-                                 - self._requeue_seen)
-                self._requeue_seen = eng.evict_requeue_total
-            if eng.has_prefixes or eng.prefix_hits_total:
-                m["prefix_shared"].set(eng.pool.allocator.shared_blocks)
-                if eng.prefix_hits_total > self._prefix_hits_seen:
-                    m["prefix_hits"].inc(eng.prefix_hits_total
-                                         - self._prefix_hits_seen)
-                    m["prefix_saved"].inc(eng.prefix_tokens_saved_total
-                                          - self._prefix_saved_seen)
-                    self._prefix_saved_seen = eng.prefix_tokens_saved_total
-                    self._prefix_hits_seen = eng.prefix_hits_total
-            if eng._radix is not None:
-                m["radix_nodes"].set(eng._radix.nodes)
-                if eng.radix_hit_tokens_total > self._radix_hits_seen:
-                    m["radix_hits"].inc(eng.radix_hit_tokens_total
-                                        - self._radix_hits_seen)
-                    self._radix_hits_seen = eng.radix_hit_tokens_total
-                if eng.radix_evictions_total > self._radix_evict_seen:
-                    m["radix_evict"].inc(eng.radix_evictions_total
-                                         - self._radix_evict_seen)
-                    self._radix_evict_seen = eng.radix_evictions_total
-            # goodput ledger mirror: per-class counter deltas + the
-            # rolling fraction (host ints the dispatch sites already
-            # wrote — zero extra syncs)
-            gp = eng.goodput
-            for cls, ctr in m["goodput"].items():
-                total = gp.classes[cls]
-                seen = self._goodput_seen.get(cls, 0)
-                if total > seen:
-                    ctr.inc(total - seen)
-                    self._goodput_seen[cls] = total
-            m["goodput_frac"].set(gp.goodput_fraction())
-        return progressed
+        return len(admitted)
+
+    def _publish_gauges(self, eng, m):
+        m["queue"].set(self.queue_depth())
+        m["slots"].set(eng.active_slots)
+        m["blocks"].set(eng.free_blocks)
+        m["pool_free"].set(eng.pool.free_blocks)
+        m["pool_used"].set(eng.pool.used_blocks)
+        if eng.block_grants_total > self._grants_seen:
+            m["grants"].inc(eng.block_grants_total
+                            - self._grants_seen)
+            self._grants_seen = eng.block_grants_total
+        if eng.evict_requeue_total > self._requeue_seen:
+            m["requeue"].inc(eng.evict_requeue_total
+                             - self._requeue_seen)
+            self._requeue_seen = eng.evict_requeue_total
+        if eng.has_prefixes or eng.prefix_hits_total:
+            m["prefix_shared"].set(eng.pool.allocator.shared_blocks)
+            if eng.prefix_hits_total > self._prefix_hits_seen:
+                m["prefix_hits"].inc(eng.prefix_hits_total
+                                     - self._prefix_hits_seen)
+                m["prefix_saved"].inc(eng.prefix_tokens_saved_total
+                                      - self._prefix_saved_seen)
+                self._prefix_saved_seen = eng.prefix_tokens_saved_total
+                self._prefix_hits_seen = eng.prefix_hits_total
+        if eng._radix is not None:
+            m["radix_nodes"].set(eng._radix.nodes)
+            if eng.radix_hit_tokens_total > self._radix_hits_seen:
+                m["radix_hits"].inc(eng.radix_hit_tokens_total
+                                    - self._radix_hits_seen)
+                self._radix_hits_seen = eng.radix_hit_tokens_total
+            if eng.radix_evictions_total > self._radix_evict_seen:
+                m["radix_evict"].inc(eng.radix_evictions_total
+                                     - self._radix_evict_seen)
+                self._radix_evict_seen = eng.radix_evictions_total
+        # goodput ledger mirror: per-class counter deltas + the
+        # rolling fraction (host ints the dispatch sites already
+        # wrote — zero extra syncs)
+        gp = eng.goodput
+        for cls, ctr in m["goodput"].items():
+            total = gp.classes[cls]
+            seen = self._goodput_seen.get(cls, 0)
+            if total > seen:
+                ctr.inc(total - seen)
+                self._goodput_seen[cls] = total
+        m["goodput_frac"].set(gp.goodput_fraction())
 
     # ------------------------------------------------ speculative policy
     def _spec_policy(self) -> Optional[bool]:

@@ -173,7 +173,7 @@ class TestTracer:
         with tr.span("x"):
             pass
         tr.instant("y")
-        tr.add_complete_event("z", 0.0, 1.0)
+        tr.complete_between("z", 0.0, 1.0)
         assert tr.events() == []
 
     def test_ring_buffer_bounds_memory(self):

@@ -285,19 +285,30 @@ class TestReplicaPlane:
             client.close()
             w.stop()
 
-    def test_mid_stream_death_is_typed(self, coord, net, prompts):
-        w = _worker(net, coord.address)
+    def test_mid_stream_death_is_typed(self, coord, net, prompts,
+                                       monkeypatch):
+        # the kill point is an event, not a sleep: every stream holds a
+        # token and none can have finished. Warm, three streams of 24
+        # tokens finish in 0.05 s (measured), before a 0.1 s sleep ends;
+        # under the sandbox's step floor 40 tokens take 2 s
+        monkeypatch.setenv("DL4J_SANDBOX_MODEL", "1")
+        w = _worker(net, coord.address, dispatch_floor_s=0.05)
         client = ReplicaClient(w.host, w.port)
         try:
-            streams = [client.submit("m", p, 24) for p in prompts[:3]]
-            time.sleep(0.1)
+            streams = [client.submit("m", p, 40) for p in prompts[:3]]
+            deadline = time.monotonic() + 60
+            while not all(s.tokens for s in streams):
+                assert time.monotonic() < deadline, "no first tokens"
+                time.sleep(0.001)
+            assert not any(s.done() for s in streams)
             w.stop()            # hard mid-stream death
             for s in streams:
                 with pytest.raises(ReplicaLostError) as ei:
                     s.result(30)
                 assert ei.value.request_id == s.request_id
-                assert ei.value.last_seq >= -1
+                assert 0 <= ei.value.last_seq
                 assert ei.value.tokens == s.tokens
+                assert 1 <= len(s.tokens) < 40
         finally:
             client.close()
             w.stop()
