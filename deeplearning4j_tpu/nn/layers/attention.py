@@ -25,6 +25,7 @@ from deeplearning4j_tpu.nn.conf.inputs import InputType, InputTypeRecurrent
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 
 _SP_FALLBACK_WARNED = set()
+_PAGED_FALLBACK_WARNED = set()
 
 
 def _warn_sp_fallback(layer_name, reason):
@@ -41,6 +42,19 @@ def _warn_sp_fallback(layer_name, reason):
             "local attention: %s — sequence-parallel memory/perf benefits "
             "do NOT apply to this forward",
             layer_name, reason)
+
+
+def _warn_paged_fallback(reason):
+    """One-time notice when kernels are on but a pool's shape keeps
+    the single-token decode on the gather path — the step then moves
+    every slot's whole block table per layer, which is what the
+    kernel exists to avoid."""
+    if reason not in _PAGED_FALLBACK_WARNED:
+        _PAGED_FALLBACK_WARNED.add(reason)
+        import logging
+        logging.getLogger(__name__).warning(
+            "paged decode attention fell back to the gather path: %s",
+            reason)
 
 
 @register_layer
@@ -167,47 +181,89 @@ class MultiHeadAttention(Layer):
         return self.activation(
             self._project(params, o.reshape(B, T, -1), "Wo"))
 
+    def paged_decode_in_place(self, k_pool) -> bool:
+        """Which single-token paged path a program traced NOW takes:
+        True = the `dl4tpu_paged_decode` kernel over the pool in place
+        (kernels on, and a pool the kernel can tile), False = gather +
+        `_attend_cached`. Decided from what the code observes — the
+        backend/env switch the kernels share and the pool's own shape
+        — and asked by the engine for its read-share counter, so the
+        layer and the counter cannot disagree."""
+        from deeplearning4j_tpu import kernels
+        from deeplearning4j_tpu.kernels import paged_attention
+        if not kernels.kernels_enabled():
+            return False
+        reason = paged_attention.unsupported_reason(
+            k_pool.shape, k_pool.dtype, self.n_heads)
+        if reason is not None:
+            _warn_paged_fallback(reason)
+            return False
+        return True
+
+    def _paged_view(self, pool, block_table):
+        """Gather-by-block-table view of a pool: [S, maxB, bl, H*Dh] ->
+        [S, L, H, Dh] with L = maxB * bl; position p of slot s sits at
+        gathered index p (tables map position-space blocks in order),
+        so the layout — and therefore the attention math — matches the
+        monolithic cache exactly."""
+        seq = pool[block_table]
+        return seq.reshape(seq.shape[0], -1, self.n_heads, self.head_dim)
+
     def forward_with_paged_cache(self, params, x, k_pool, v_pool,
-                                 block_table, pos):
+                                 block_table, pos, live=None):
         """Incremental causal attention over a PAGED KV-cache pool — the
         continuous-batching serving mode (`cache_pages=`): instead of one
         monolithic `[B, L, H, Dh]` buffer per sequence, K/V live in a
-        shared pool of fixed-size blocks `[n_blocks, block_len, H, Dh]`
-        and each slot addresses its blocks through a block table.
+        shared pool of fixed-size blocks `[n_blocks, block_len, H*Dh]`
+        (a page holds all heads side by side) and each slot addresses
+        its blocks through a block table.
 
         `x` [S, 1, D] holds ONE new token per serving slot; `pos` [S]
         is each slot's own stream position (slots decode different
         sequences at different depths — the per-slot generalization of
         `forward_with_cache`'s single scalar `pos`). `block_table`
         [S, max_blocks] maps slot-local block index -> pool block id.
+        `live` [S] bool (None: every slot) says which slots are
+        decoding; the others' outputs are never used by the caller.
         Returns (y, k_pool', v_pool').
+
+        Two attention cores, selected by `paged_decode_in_place`:
+        - the `dl4tpu_paged_decode` kernel (the chip): each live slot
+          reads the `ceil((pos+1)/block_len)` pages it holds, in
+          place; a slot that is not live reads nothing. Held to a
+          tolerance against the other core (online softmax, fp32);
+        - gather + `_attend_cached` (CPU, kernels off, shapes the
+          kernel cannot tile): the plain reference, bit-identical to
+          the monolithic cache.
 
         Invariants the scheduler maintains (serving/paged.py): active
         slots own disjoint block sets; block id 0 is the reserved
         garbage block that inactive slots and table padding point at —
-        every gathered position past a slot's `pos` is masked to -inf
-        before the softmax, so garbage content never reaches the
-        output (0-weight * finite garbage == exactly 0.0, which is
-        what keeps this path bit-identical to the monolithic cache)."""
+        every position past a slot's `pos` is masked before the
+        softmax, so garbage content never reaches the output
+        (0-weight * finite garbage == exactly 0.0)."""
         assert self.causal, "paged KV-cache decoding requires causal=True"
         S, bl = x.shape[0], k_pool.shape[1]
-        q = self.heads(self._project(params, x, "Wq"))   # [S,1,H,Dh]
-        k = self.heads(self._project(params, x, "Wk"))
-        v = self.heads(self._project(params, x, "Wv"))
+        q = self._project(params, x, "Wq")               # [S,1,H*Dh]
+        k = self._project(params, x, "Wk")
+        v = self._project(params, x, "Wv")
         blk = block_table[jnp.arange(S), pos // bl]      # [S] pool ids
         off = pos % bl
         k_pool = k_pool.at[blk, off].set(k[:, 0].astype(k_pool.dtype))
         v_pool = v_pool.at[blk, off].set(v[:, 0].astype(v_pool.dtype))
-        # gather-by-block-table view: [S, maxB, bl, H, Dh] -> [S, L, ...]
-        # with L = maxB * bl; position p of slot s sits at gathered
-        # index p (tables map position-space blocks in order), so the
-        # layout — and therefore the attention math — matches the
-        # monolithic cache exactly
-        k_seq = k_pool[block_table]
-        k_seq = k_seq.reshape(S, -1, *k_seq.shape[3:])
-        v_seq = v_pool[block_table]
-        v_seq = v_seq.reshape(S, -1, *v_seq.shape[3:])
-        return (self._attend_cached(params, q, k_seq, v_seq,
+        if self.paged_decode_in_place(k_pool):
+            from deeplearning4j_tpu.kernels.paged_attention import (
+                paged_decode_attention)
+            lengths = pos + 1
+            if live is not None:
+                lengths = jnp.where(live, lengths, 0)
+            o = paged_decode_attention(q, k_pool, v_pool, block_table,
+                                       lengths, n_heads=self.n_heads)
+            return (self.activation(self._project(params, o, "Wo")),
+                    k_pool, v_pool)
+        return (self._attend_cached(params, self.heads(q),
+                                    self._paged_view(k_pool, block_table),
+                                    self._paged_view(v_pool, block_table),
                                     pos[:, None]),
                 k_pool, v_pool)
 
@@ -231,13 +287,16 @@ class MultiHeadAttention(Layer):
         sequential single-token dispatches and one K-wide dispatch
         write the same bytes and read the same masked view, which is
         what makes the speculative greedy contract BIT-equality rather
-        than tolerance. Returns (y [S, K, D], k_pool', v_pool')."""
+        than tolerance wherever the single-token path runs this same
+        gather + `_attend_cached` core (where it runs the
+        `dl4tpu_paged_decode` kernel the two agree to a tolerance:
+        docs/SERVING.md). Returns (y [S, K, D], k_pool', v_pool')."""
         assert self.causal, "paged KV-cache decoding requires causal=True"
         S, K = x.shape[0], x.shape[1]
         bl = k_pool.shape[1]
         q = self.heads(self._project(params, x, "Wq"))   # [S,K,H,Dh]
-        k = self.heads(self._project(params, x, "Wk"))
-        v = self.heads(self._project(params, x, "Wv"))
+        k = self._project(params, x, "Wk")               # [S,K,H*Dh]
+        v = self._project(params, x, "Wv")
         j = jnp.arange(K)[None, :]                       # [1, K]
         posj = pos[:, None] + j                          # [S, K]
         blk_idx = jnp.minimum(posj // bl, block_table.shape[1] - 1)
@@ -250,11 +309,10 @@ class MultiHeadAttention(Layer):
         # read (every gather masks by the reader's own position)
         k_pool = k_pool.at[blk, off].set(k.astype(k_pool.dtype))
         v_pool = v_pool.at[blk, off].set(v.astype(v_pool.dtype))
-        k_seq = k_pool[block_table]
-        k_seq = k_seq.reshape(S, -1, *k_seq.shape[3:])
-        v_seq = v_pool[block_table]
-        v_seq = v_seq.reshape(S, -1, *v_seq.shape[3:])
-        return (self._attend_cached(params, q, k_seq, v_seq, posj),
+        return (self._attend_cached(params, q,
+                                    self._paged_view(k_pool, block_table),
+                                    self._paged_view(v_pool, block_table),
+                                    posj),
                 k_pool, v_pool)
 
     def forward(self, params, state, x, *, train=False, rng=None, mask=None):

@@ -274,14 +274,24 @@ class TransformerEncoderBlock(BaseRecurrentLayer):
                                         rng=rng)
         return y, {}, new_carry
 
+    def paged_decode_in_place(self, k_pool) -> bool:
+        """`MultiHeadAttention.paged_decode_in_place` of this block's
+        attention: whether `forward_paged`, traced now, reads the pool
+        in place (the kernel) or gathers it."""
+        if self._mha is None:
+            self._build_sublayers()
+        return self._mha.paged_decode_in_place(k_pool)
+
     def forward_paged(self, params, x, k_pool, v_pool, block_table, pos,
-                      *, train=False, rng=None):
+                      live=None, *, train=False, rng=None):
         """Paged-KV decode step (`cache_pages=` mode): the same pre-LN
         block as `_carry_impl`, with attention reading/writing the
         shared block pool through this slot-batch's block table
         (`MultiHeadAttention.forward_with_paged_cache`). `pos` [S] is
         per-slot — sequences admitted mid-stream sit at different
-        depths. The non-attention math IS the carry path's
+        depths; `live` [S] marks the slots that are decoding (None:
+        all), so the in-place kernel reads no page for the others. The
+        non-attention math IS the carry path's
         (`_stream_tail` — one body, not a synchronized copy), which is
         what the serving tier's decode-parity contract (docs/SERVING.md)
         rests on. Returns (y, k_pool', v_pool')."""
@@ -289,7 +299,8 @@ class TransformerEncoderBlock(BaseRecurrentLayer):
             self._build_sublayers()
         h, _ = self._ln1.forward(self._sub(params, "ln1"), {}, x)
         h, k_pool, v_pool = self._mha.forward_with_paged_cache(
-            self._sub(params, "attn"), h, k_pool, v_pool, block_table, pos)
+            self._sub(params, "attn"), h, k_pool, v_pool, block_table, pos,
+            live)
         return (self._stream_tail(params, x, h, train=train, rng=rng),
                 k_pool, v_pool)
 

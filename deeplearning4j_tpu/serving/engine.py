@@ -217,6 +217,16 @@ class PagedDecodeEngine:
                 f"depth dispatch")
         self.pool = PagedKVPool(net, n_blocks, block_len)
         self.block_len = int(block_len)
+        # per transformer block: do the single-token programs attend
+        # over the pool in place (`dl4tpu_paged_decode`) or gather it?
+        # The layer decides when a program is traced, from the kernels'
+        # shared switch and the pool's shape; asked once here, it keys
+        # those programs' cache entries and is what `kv_read_pct`
+        # counts from
+        self._in_place = tuple(
+            net.layers[i].paged_decode_in_place(k_pool)
+            for i, (k_pool, _) in zip(self.pool.layer_indices,
+                                      self.pool.kv))
         # a serving "plan": how each layer participates in the paged
         # decode walk. Input preprocessors would silently change the
         # math mid-walk — reject loudly (the zoo LMs have none).
@@ -346,6 +356,7 @@ class PagedDecodeEngine:
         self.loop_it = 0
         self.admit_bucket = 0
         self.wait_s = 0.0
+        self.kv_read_pct = 0.0
 
     # ------------------------------------------------------------ queries
     @property
@@ -563,7 +574,7 @@ class PagedDecodeEngine:
         J = self.steps_per_dispatch
 
         def one_token(params, state, kv, block_tables, token_ids, pos,
-                      keys, emit_idx, temp, top_p):
+                      live, keys, emit_idx, temp, top_p):
             h = token_ids[:, None]            # [S, 1] int ids
             kv = list(kv)
             for entry in plan:
@@ -579,7 +590,7 @@ class PagedDecodeEngine:
                     j = entry[2]
                     k_pool, v_pool = kv[j]
                     h, k_pool, v_pool = layer.forward_paged(
-                        lp, h, k_pool, v_pool, block_tables, pos)
+                        lp, h, k_pool, v_pool, block_tables, pos, live)
                     kv[j] = (k_pool, v_pool)
             probs = h[:, -1]                   # [S, V]
             return tuple(kv), self._sample_ids(probs, keys, emit_idx,
@@ -595,13 +606,17 @@ class PagedDecodeEngine:
             finishing mid-chunk keeps decoding — into its own pages or
             the garbage block, never another slot's — and the `valids`
             mask tells the host which emissions are real. J=1 is the
-            admit-every-token schedule the scheduler defaults to."""
+            admit-every-token schedule the scheduler defaults to.
+            `remaining > 0` is also each micro-step's `live` mask: a
+            freed slot keeps a stale `pos`, and the in-place attention
+            kernel must read no page for it."""
             params = net.dtype.cast_params(params)
 
             def micro(carry, _):
                 kv, tok, pos, rem, emit = carry
                 kv, nxt = one_token(params, state, kv, block_tables,
-                                    tok, pos, keys, emit, temp, top_p)
+                                    tok, pos, rem > 0, keys, emit, temp,
+                                    top_p)
                 return ((kv, nxt, pos + 1, rem - 1, emit + 1),
                         (nxt, rem > 0))
 
@@ -615,7 +630,7 @@ class PagedDecodeEngine:
     def _build_decode(self, greedy_only: bool):
         return self._shared_jit(
             ("decode", greedy_only, self.steps_per_dispatch,
-             tuple(self._plan), self.top_k),
+             tuple(self._plan), self.top_k, self._in_place),
             lambda: jax.jit(self._decode_body(greedy_only),
                             donate_argnums=donate_argnums(2)))
 
@@ -670,8 +685,8 @@ class PagedDecodeEngine:
             out = []
             for (k_pool, v_pool), (k_cache, v_cache) in zip(
                     kv, block_carries):
-                C = k_cache.shape[1]
-                shape = (k * (C // bl), bl) + k_cache.shape[2:]
+                C = k_cache.shape[1]       # [k, C, H, Dh] -> pages
+                shape = (k * (C // bl), bl, k_pool.shape[-1])
                 flat_rows = rows[:, :C // bl].reshape(-1)
                 out.append((
                     k_pool.at[flat_rows].set(
@@ -822,7 +837,8 @@ class PagedDecodeEngine:
         net, layers = self.net, self.net.layers
         dplan = self._draft_plan
 
-        def draft(params, state, kv, block_tables, token_ids, pos):
+        def draft(params, state, kv, block_tables, token_ids, pos,
+                  live):
             params = net.dtype.cast_params(params)
 
             def micro(carry, _):
@@ -843,7 +859,8 @@ class PagedDecodeEngine:
                         j = entry[2]
                         k_pool, v_pool = kv[j]
                         h, k_pool, v_pool = layer.forward_paged(
-                            lp, h, k_pool, v_pool, block_tables, pos)
+                            lp, h, k_pool, v_pool, block_tables, pos,
+                            live)
                         kv[j] = (k_pool, v_pool)
                 nxt = jnp.argmax(h[:, -1], axis=-1).astype(jnp.int32)
                 return (tuple(kv), nxt, pos + 1), nxt
@@ -871,13 +888,14 @@ class PagedDecodeEngine:
                           GARBAGE_BLOCK).astype(np.int32)
         if self._draft_fn is None:
             self._draft_fn = self._shared_jit(
-                ("draft", self.spec_k, tuple(self._draft_plan or ())),
+                ("draft", self.spec_k, tuple(self._draft_plan or ()),
+                 self._in_place),
                 lambda: jax.jit(self._draft_body(),
                                 donate_argnums=donate_argnums(2)))
         kv, drafts = self._draft_fn(
             self._params, self.net.net_state, self.pool.kv,
             jnp.asarray(tables), jnp.asarray(self.last_token),
-            jnp.asarray(self.pos))
+            jnp.asarray(self.pos), jnp.asarray(mask))
         self.pool.kv = kv
         self.spec_draft_dispatches_total += 1
         real = sum(d - 1 for _, d in trunc_slots)
@@ -1530,6 +1548,7 @@ class PagedDecodeEngine:
         if speculate is None:
             speculate = self.spec_k is not None
         self.wait_s = 0.0
+        self.kv_read_pct = 100.0     # the K-wide score path gathers
         if speculate and self.spec_k:
             return self._spec_step(proposers=proposers)
         it = self.loop_it
@@ -1555,6 +1574,7 @@ class PagedDecodeEngine:
                     self._decode_greedy = self._build_decode(
                         greedy_only=True)
                 decode = self._decode_greedy
+            self.kv_read_pct = self._kv_read_pct()
             kv, toks, valids = decode(
                 self._params, self.net.net_state, self.pool.kv,
                 jnp.asarray(self.block_tables),
@@ -1569,6 +1589,25 @@ class PagedDecodeEngine:
         self.wait_s = sp.duration_s
         with monitor.span("serve/decode/post", it=it):
             return self._after_decode(toks, valids)
+
+    def _kv_read_pct(self) -> float:
+        """100 x the pool blocks of K (and as many of V) the decode
+        dispatch about to launch reads in a layer, over the
+        `steps_per_dispatch x n_slots x max_blocks` a gather of every
+        slot's whole table moves. An in-place layer reads
+        `ceil((pos+1)/block_len)` blocks for each slot whose
+        `remaining > 0` at that micro-step (the program's own
+        validity) and none for the others; a gathering layer reads
+        everything — mean over the layers."""
+        whole = self.steps_per_dispatch * self.n_slots * self.max_blocks
+        j = np.arange(self.steps_per_dispatch)[:, None]      # [J, 1]
+        held = -(-(self.pos[None, :] + j + 1) // self.block_len)
+        read = int(np.where(self.remaining[None, :] > j, held, 0).sum())
+        n_in_place = sum(self._in_place)
+        n_layers = len(self._in_place)
+        return 100.0 * (n_in_place * read
+                        + (n_layers - n_in_place) * whole) / (
+            n_layers * whole)
 
     def _after_decode(self, toks, valids):
         """Slot bookkeeping for one decode chunk's `[J, S]` tokens and
@@ -1854,9 +1893,13 @@ class PagedDecodeEngine:
                 f"slot {slot} already finished — nothing to hand off")
         idx = np.asarray(s.blocks, np.int64)
         per_layer = []
-        for (k, v) in self.pool.kv:
-            per_layer.append(np.stack([np.asarray(k)[idx],
-                                       np.asarray(v)[idx]]))
+        for i, (k, v) in zip(self.pool.layer_indices, self.pool.kv):
+            # the wire keeps heads and head_dim apart; the pool keeps a
+            # page's heads side by side
+            page = (self.block_len, self.net.layers[i].n_heads, -1)
+            per_layer.append(np.stack(
+                [np.asarray(k)[idx].reshape(len(idx), *page),
+                 np.asarray(v)[idx].reshape(len(idx), *page)]))
         kv = np.stack(per_layer)
         header = {
             "request_id": s.request_id,
@@ -1896,10 +1939,12 @@ class PagedDecodeEngine:
             raise ValueError(
                 f"handoff block_len {header['block_len']} != engine "
                 f"block_len {self.block_len}")
-        if tuple(kv.shape[3:]) != tuple(k0.shape[1:]):
+        n_heads = self.net.layers[self.pool.layer_indices[0]].n_heads
+        page = (self.block_len, n_heads, k0.shape[2] // n_heads)
+        if tuple(kv.shape[3:]) != page:
             raise ValueError(
                 f"handoff block shape {kv.shape[3:]} != pool block "
-                f"shape {tuple(k0.shape[1:])}")
+                f"shape {page}")
         if np.dtype(kv.dtype) != np.dtype(k0.dtype):
             raise ValueError(
                 f"handoff dtype {kv.dtype} != pool compute dtype "
@@ -1916,9 +1961,10 @@ class PagedDecodeEngine:
                 f"({self.pool.free_blocks} free)")
         bidx = jnp.asarray(np.asarray(blocks, np.int32))
         new_kv = []
+        flat = kv.reshape(kv.shape[:4] + (-1,))    # heads side by side
         for l, (k, v) in enumerate(self.pool.kv):
-            new_kv.append((k.at[bidx].set(jnp.asarray(kv[l, 0])),
-                           v.at[bidx].set(jnp.asarray(kv[l, 1]))))
+            new_kv.append((k.at[bidx].set(jnp.asarray(flat[l, 0])),
+                           v.at[bidx].set(jnp.asarray(flat[l, 1]))))
         self.pool.kv = tuple(new_kv)
         s = Slot(header.get("request_id"), blocks,
                  int(header["prompt_len"]), int(header["n_tokens"]),

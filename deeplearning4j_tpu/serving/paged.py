@@ -4,9 +4,11 @@ The serving tier's memory plane (ROADMAP "production inference tier";
 the design TF-Serving layered over the TF runtime, PAPERS.md §serving):
 instead of one monolithic `[cache_len, H, Dh]` buffer pinned per
 sequence for its whole lifetime, K/V live in a shared pool of
-fixed-size blocks `[n_blocks, block_len, H, Dh]` per transformer
-layer. A sequence owns `ceil((prompt + n_tokens) / block_len)` blocks,
-addressed through a per-slot block table — so `stream_budget` becomes
+fixed-size blocks `[n_blocks, block_len, H*Dh]` per transformer
+layer (a page keeps all heads side by side: one contiguous,
+lane-dense slab the decode kernel reads with one DMA). A sequence
+owns `ceil((prompt + n_tokens) / block_len)` blocks, addressed
+through a per-slot block table — so `stream_budget` becomes
 a POOL-capacity question (how many sequences fit at once) instead of a
 per-sequence clamp, and a finished sequence's blocks immediately serve
 the next admission.
@@ -330,7 +332,7 @@ class PagedKVPool:
 
     `kv` is a flat tuple of (k_pool, v_pool) pairs — one per
     TransformerEncoderBlock in layer order — shaped
-    `[n_blocks, block_len, n_heads, head_dim]` in the net's compute
+    `[n_blocks, block_len, n_heads * head_dim]` in the net's compute
     dtype (the same dtype `init_carry` gives the monolithic caches, so
     prefill copies are exact). It is a plain pytree: jitted programs
     take it as an argument and return the updated pools."""
@@ -350,8 +352,7 @@ class PagedKVPool:
         kv = []
         for i in self.layer_indices:
             layer = net.layers[i]
-            shape = (self.n_blocks, self.block_len, layer.n_heads,
-                     layer.n_in // layer.n_heads)
+            shape = (self.n_blocks, self.block_len, layer.n_in)
             kv.append((jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)))
         self.kv: Tuple = tuple(kv)
         self.allocator = BlockAllocator(self.n_blocks)

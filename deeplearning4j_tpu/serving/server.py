@@ -895,6 +895,12 @@ class GenerationServer(ParallelInference):
                 "serving_decode_batch_slots",
                 "active slots at each decode dispatch",
                 buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256), **lbl),
+            "kv_read_pct": reg.histogram(
+                "serving_decode_kv_read_pct",
+                "100 x pool blocks a decode dispatch's attention reads "
+                "/ (n_slots x max_blocks): 100 where it gathers every "
+                "slot's whole table",
+                buckets=(1, 2, 5, 10, 20, 35, 50, 75, 100), **lbl),
             "goodput_frac": reg.gauge(
                 GOODPUT_FRACTION_GAUGE,
                 "useful token-positions / dispatched token-positions "
@@ -1108,6 +1114,7 @@ class GenerationServer(ParallelInference):
                     m["decode_wait"].observe(eng.wait_s)
                     m["decode_host"].observe(sp.duration_s - eng.wait_s)
                     m["batch_slots"].observe(n_active)
+                    m["kv_read_pct"].observe(eng.kv_read_pct)
                 if n_tok and dt > 0:
                     rate = n_tok / dt
                     self._ewma_tok_s = (rate if self._ewma_tok_s is None

@@ -595,3 +595,132 @@ class TestFlashBf16:
         np.testing.assert_allclose(np.asarray(got, np.float32),
                                    np.asarray(want, np.float32),
                                    rtol=2e-2, atol=2e-2)
+
+
+class TestPagedDecodeKernel:
+    """`dl4tpu_paged_decode` (interpret mode) against the plain
+    reference it replaces on the chip: gather by block table +
+    `MultiHeadAttention._attend_cached`, through the layer's own
+    single-token entry point, so the new token's write, the `live`
+    mask and the `Wo` projection are in the comparison."""
+
+    D, HEADS, BUDGET = 128, 2, 64          # Dh = 64
+
+    def _layer(self):
+        from deeplearning4j_tpu.nn.layers.attention import (
+            MultiHeadAttention)
+        mha = MultiHeadAttention(n_in=self.D, n_out=self.D,
+                                 n_heads=self.HEADS, causal=True)
+        return mha, mha.init_params(jax.random.PRNGKey(4))
+
+    def _case(self, dtype, bl, *, poison=False, d=None):
+        """Five decoding slots at ragged depths — 0, both sides of a
+        block boundary, mid-stream, the last position of the budget —
+        and two idle ones whose table is all GARBAGE_BLOCK under a
+        stale `pos`. `poison` fills every pool position a slot does
+        not hold (past its `pos`, unowned blocks, the garbage block)
+        with 1e30."""
+        from deeplearning4j_tpu.serving import GARBAGE_BLOCK
+        d = d or self.D
+        rng = np.random.default_rng(7)
+        max_blocks = self.BUDGET // bl
+        pos = np.array([0, 15, 16, 37, self.BUDGET - 1, 23,
+                        self.BUDGET - 1], np.int32)
+        live = np.array([1, 1, 1, 1, 1, 0, 0], bool)
+        S = len(pos)
+        n_blocks = 1 + 5 * max_blocks + 3
+        ids = rng.permutation(np.arange(1, n_blocks))
+        table = np.full((S, max_blocks), GARBAGE_BLOCK, np.int32)
+        fill = 1e30 if poison else 0.0
+        pools = [np.full((n_blocks, bl, d), fill, np.float32)
+                 for _ in range(2)]
+        c = 0
+        held = np.random.default_rng(11)       # same draws, poison or not
+        for s in np.flatnonzero(live):
+            n = -(-(int(pos[s]) + 1) // bl)
+            table[s, :n] = ids[c:c + n]
+            c += n
+            for p in range(int(pos[s])):       # what the slot holds
+                for pool in pools:
+                    pool[table[s, p // bl], p % bl] = (
+                        held.standard_normal(d))
+        x = jnp.asarray(rng.standard_normal((S, 1, d)), dtype)
+        return (x, jnp.asarray(pools[0], dtype),
+                jnp.asarray(pools[1], dtype), jnp.asarray(table),
+                jnp.asarray(pos), jnp.asarray(live))
+
+    def _run(self, monkeypatch, kernels, mha, params, case):
+        monkeypatch.setenv("DL4J_PALLAS_KERNELS", kernels)
+        x, k_pool, v_pool, table, pos, live = case
+        params = jax.tree_util.tree_map(
+            lambda a: a.astype(x.dtype), params)
+        y, k_new, v_new = mha.forward_with_paged_cache(
+            params, x, k_pool, v_pool, table, pos, live)
+        return (np.asarray(y.astype(jnp.float32)), np.asarray(live),
+                k_new, v_new)
+
+    @pytest.mark.parametrize("dtype,bl,tol,poison", [
+        (jnp.float32, 8, 2e-5, False),
+        (jnp.float32, 16, 2e-5, False),
+        (jnp.bfloat16, 16, 3e-2, False),
+        (jnp.float32, 8, 2e-5, True),
+        (jnp.bfloat16, 16, 3e-2, True),
+    ])
+    def test_matches_gather_and_reads_nothing_past_the_length(
+            self, monkeypatch, dtype, bl, tol, poison):
+        mha, params = self._layer()
+        clean = self._case(dtype, bl)
+        assert mha.paged_decode_in_place(clean[1]) is False  # CPU default
+        want, live, k_ref, v_ref = self._run(monkeypatch, "0", mha,
+                                             params, clean)
+        got, _, k_new, v_new = self._run(monkeypatch, "1", mha, params,
+                                         clean)
+        assert mha.paged_decode_in_place(clean[1]) is True
+        np.testing.assert_allclose(got[live], want[live], rtol=tol,
+                                   atol=tol)
+        # both cores write the new token's K and V the same way
+        np.testing.assert_array_equal(np.asarray(k_new), np.asarray(k_ref))
+        np.testing.assert_array_equal(np.asarray(v_new), np.asarray(v_ref))
+        # a slot that is not decoding reads nothing: zeros through Wo
+        idle = np.asarray(params["bo"], np.float32)
+        np.testing.assert_allclose(got[~live],
+                                   np.broadcast_to(idle, got[~live].shape),
+                                   atol=tol)
+        if poison:
+            # 1e30 in every position no slot holds: any read past a
+            # slot's length, of K or of V, would move the output
+            dirty, _, _, _ = self._run(monkeypatch, "1", mha, params,
+                                       self._case(dtype, bl, poison=True))
+            assert np.isfinite(dirty).all()
+            np.testing.assert_array_equal(dirty, got)
+
+    @pytest.mark.parametrize("dtype,bl,d,why", [
+        (jnp.bfloat16, 8, 128, "sublane tile"),
+        (jnp.float32, 4, 128, "sublane tile"),
+        (jnp.float32, 8, 96, "128 lanes"),
+    ])
+    def test_shapes_the_kernel_cannot_tile_take_the_gather_path(
+            self, monkeypatch, caplog, dtype, bl, d, why):
+        from deeplearning4j_tpu.kernels import paged_attention
+        from deeplearning4j_tpu.nn.layers import attention
+        from deeplearning4j_tpu.nn.layers.attention import (
+            MultiHeadAttention)
+        mha = MultiHeadAttention(n_in=d, n_out=d, n_heads=self.HEADS,
+                                 causal=True)
+        params = mha.init_params(jax.random.PRNGKey(4))
+        case = self._case(dtype, bl, d=d)
+        assert why in paged_attention.unsupported_reason(
+            case[1].shape, case[1].dtype, self.HEADS)
+        with pytest.raises(ValueError, match=why):
+            paged_attention.paged_decode_attention(
+                case[0], case[1], case[2], case[3], case[4] + 1,
+                n_heads=self.HEADS)
+        want, _, _, _ = self._run(monkeypatch, "0", mha, params, case)
+        monkeypatch.setattr(attention, "_PAGED_FALLBACK_WARNED", set())
+        with caplog.at_level("WARNING", logger=attention.__name__):
+            got, _, _, _ = self._run(monkeypatch, "1", mha, params, case)
+            self._run(monkeypatch, "1", mha, params, case)
+        np.testing.assert_array_equal(got, want)     # the same core
+        said = [r for r in caplog.records
+                if "fell back to the gather path" in r.getMessage()]
+        assert len(said) == 1 and why in said[0].getMessage()

@@ -99,7 +99,7 @@ class TestPagedAttentionParity:
         params = net.params[str(blk_i)]
         rng = np.random.default_rng(0)
         B, N = 2, 12
-        shape = (N, BL, HEADS, D // HEADS)
+        shape = (N, BL, D)
         k_pool = jnp.asarray(rng.standard_normal(shape), jnp.float32)
         v_pool = jnp.asarray(rng.standard_normal(shape), jnp.float32)
         bt = jnp.asarray([[3, 5, 7, 9], [2, 4, 6, 8]], jnp.int32)

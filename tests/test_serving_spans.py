@@ -380,3 +380,97 @@ class TestRequestLanes:
         lanes = [e for e in tracer.events()
                  if e["name"] in ("req/prefill", "req/decode")]
         assert lanes and all(e["args"]["it"] in loops for e in lanes)
+
+
+class TestDecodeReadsInPlace:
+    """ISSUE 28: where the kernels are on and the pool can be tiled the
+    single-token decode attends over the pool in place
+    (`dl4tpu_paged_decode`, interpret mode here), and
+    `serving_decode_kv_read_pct` says how much of the slots' whole
+    block tables each dispatch read."""
+
+    WIDE = dict(vocab_size=V, d_model=128, n_layers=LAYERS, n_heads=2,
+                max_len=MAXLEN, seed=3)            # Dh 64: tileable
+    BL = 8
+    PROMPT_LENS = (3, 9, 14)
+
+    def _staggered(self, monkeypatch, kernels):
+        """Three prompts admitted one iteration apart, six steps (the
+        last request's last):
+        (tokens by request, kv_read_pct by step, what the formula
+        gives by step, the pools)."""
+        from deeplearning4j_tpu.serving import PagedDecodeEngine
+        monkeypatch.setenv("DL4J_PALLAS_KERNELS", kernels)
+        net = TransformerLM(**self.WIDE).init()
+        eng = PagedDecodeEngine(net, n_slots=4, n_blocks=20,
+                                block_len=self.BL)
+        whole = eng.n_slots * eng.max_blocks
+        out, slot2req, read, formula = {}, {}, [], []
+        for it in range(6):
+            if it < len(self.PROMPT_LENS):
+                ids = np.random.default_rng(it).integers(
+                    0, V, self.PROMPT_LENS[it])
+                (slot, first, _), = eng.admit_many(
+                    [dict(prompt_ids=ids, n_tokens=5)])
+                slot2req[slot] = it
+                out[it] = [int(first)]
+            decoding = np.flatnonzero(eng.remaining > 0)
+            formula.append(100.0 * sum(
+                -(-(int(eng.pos[s]) + 1) // self.BL)
+                for s in decoding) / whole)
+            emitted, _ = eng.step()
+            read.append(eng.kv_read_pct)
+            for slot, toks in emitted.items():
+                out[slot2req[slot]].extend(toks)
+        return out, read, formula, eng.pool.kv
+
+    def test_same_greedy_tokens_and_the_read_share(self, monkeypatch):
+        toks0, read0, formula, kv0 = self._staggered(monkeypatch, "0")
+        toks1, read1, formula1, kv1 = self._staggered(monkeypatch, "1")
+        assert toks1 == toks0
+        assert all(len(t) == 5 for t in toks0.values())
+        assert formula1 == formula and 0 < min(formula) < max(formula) < 50
+        assert read0 == [100.0] * len(read0)       # the gather path
+        assert read1 == formula                    # blocks held, no more
+        # the streams' K and V agree too (layer 2's depend on layer
+        # 1's attention), which identical tokens of a random-init
+        # model alone would not show; block 0 is the garbage block,
+        # where the idle slot's lanes write
+        for (k0, v0), (k1, v1) in zip(kv0, kv1):
+            np.testing.assert_allclose(np.asarray(k1)[1:],
+                                       np.asarray(k0)[1:], atol=1e-4)
+            np.testing.assert_allclose(np.asarray(v1)[1:],
+                                       np.asarray(v0)[1:], atol=1e-4)
+
+    @pytest.mark.parametrize("kernels", ["0", "1"])
+    def test_the_family_is_observed_at_each_decode_dispatch(
+            self, mon, monkeypatch, kernels):
+        reg, _ = mon
+        monkeypatch.setenv("DL4J_PALLAS_KERNELS", kernels)
+        net = TransformerLM(**self.WIDE).init()
+        prompts = np.random.default_rng(5).integers(0, V, (4, 6))
+        seen = []
+        srv = GenerationServer(net, n_slots=2, n_blocks=16,
+                               block_len=self.BL)
+        eng = srv.engine
+
+        def stepping(*a, _real=eng.step, **kw):
+            out = _real(*a, **kw)
+            if out[0]:
+                seen.append(eng.kv_read_pct)
+            return out
+        eng.step = stepping
+        srv.start()
+        try:
+            for s in [srv.generate_async(p, N_TOK) for p in prompts]:
+                s.result(timeout=300)
+        finally:
+            srv.stop()
+        fam = reg.snapshot()["serving_decode_kv_read_pct"]["values"][0]
+        assert fam["count"] == len(seen) > 0
+        assert fam["sum"] == pytest.approx(sum(seen))
+        if kernels == "0":
+            assert seen == [100.0] * len(seen)
+        else:
+            # two slots of at most 12 positions: 1-2 blocks of 4 each
+            assert all(100 / 8 <= r <= 100 * 4 / 8 for r in seen)

@@ -90,6 +90,28 @@ print(json.dumps({"compiled": True}))
 '''
 
 
+_DECODE = '''
+# the serving decode step at a pool the in-place kernel can tile: bf16
+# pages of 16 positions, H*Dh = 128 lanes
+from deeplearning4j_tpu.serving import PagedDecodeEngine
+net = lm(vocab_size=128, d_model=128, n_layers=2, n_heads=2, max_len=64)
+eng = PagedDecodeEngine(net, n_slots=4, n_blocks=16, block_len=16)
+args = (tree(eng._params), tree(net.net_state), tree(eng.pool.kv)) + tuple(
+    sds(a) for a in (eng.block_tables, eng.last_token, eng.pos,
+                     eng.remaining, eng.keys, eng.emit_idx, eng.temp,
+                     eng.top_p))
+low = jax.jit(eng._decode_body(greedy_only=True),
+              donate_argnums=2).lower(*args)
+names = sorted(set(re.findall(r'kernel_name = "([^"]+)"', low.as_text())))
+hlo = low.compile().as_text()
+pool = "bf16[16,16,128]"
+print(json.dumps({"kernels": names, "in_place": list(eng._in_place),
+                  "pool_copies": len(re.findall(
+                      r"= " + re.escape(pool) + r"\\S* copy\\(", hlo)),
+                  "pool_seen": pool in hlo}))
+'''
+
+
 def _child(body, *, import_package=True):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("LIBTPU_INIT_ARGS", None)
@@ -122,6 +144,22 @@ def test_train_step_kernels_are_mosaic_compiled_for_v5e():
     assert out["compiled"] is True
     assert out["kernels"] == sorted(
         KERNEL_NAMES + layernorm.KERNEL_NAMES + (fused_adam.KERNEL_NAME,))
+
+
+def test_decode_step_attends_over_the_pool_in_place_on_v5e():
+    """The single-token decode program lowers `dl4tpu_paged_decode` to
+    a `tpu_custom_call`, passes the TPU compilers, and moves no pool:
+    the pools go from argument to scatter to kernel to result in their
+    own layout, with no `copy` of a pool's shape in the compiled
+    module (the 4-D pool of PR 27 and before had the block index
+    minor-most on the device, and every layer's scatter was bracketed
+    by two copies of the whole pool)."""
+    from deeplearning4j_tpu.kernels.paged_attention import KERNEL_NAME
+    proc, out = _child(_DECODE)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["in_place"] == [True, True]
+    assert KERNEL_NAME in out["kernels"]
+    assert out["pool_seen"] and out["pool_copies"] == 0
 
 
 def test_256_row_prefill_compiles_with_the_packages_libtpu_stacks():
