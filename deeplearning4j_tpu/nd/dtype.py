@@ -73,6 +73,10 @@ class DataTypePolicy:
                 and jnp.dtype(self.compute_dtype) == jnp.bfloat16
                 and jnp.dtype(self.output_dtype) == jnp.float32):
             return "mixed_bf16"
+        if (jnp.dtype(self.param_dtype) == jnp.bfloat16
+                and jnp.dtype(self.compute_dtype) == jnp.bfloat16
+                and jnp.dtype(self.output_dtype) == jnp.float32):
+            return "bf16_params"
         return "custom"
 
     # --------------------------------------------------------------- casts
@@ -180,17 +184,34 @@ def mixed_bf16() -> DataTypePolicy:
 
 
 def bf16_policy() -> DataTypePolicy:
-    """float32 params, bfloat16 compute — alias of `mixed_bf16()`
-    (kept for the bench/hlo_cost call sites that predate the preset
-    registry)."""
+    """float32 MASTER params, bfloat16 compute — an alias of
+    `mixed_bf16()`, kept for the bench/hlo_cost call sites that predate
+    the preset registry. Despite its name it does NOT hold parameters
+    in bfloat16: that is `bf16_params()`."""
     return mixed_bf16()
 
 
+def bf16_params() -> DataTypePolicy:
+    """Parameters HELD in bfloat16, bfloat16 compute, float32 outputs
+    and losses: the serving policy of a model whose weights only fit a
+    chip at two bytes each. No float32 master exists, `cast_params` is
+    the identity (the policy is not mixed) and a serving program reads
+    the leaves as they are. Training under it updates bfloat16 weights
+    in place, with the rounding that brings; the mixed recipe is
+    `mixed_bf16()`."""
+    return DataTypePolicy(param_dtype=jnp.bfloat16,
+                          compute_dtype=jnp.bfloat16)
+
+
+# "bf16" has meant `mixed_bf16` (float32 masters) since before a
+# bfloat16-parameter policy existed, and configurations on disk say it;
+# the policy that holds the parameters in bfloat16 is "bf16_params"
 _NAMED = {
     "float32": DataTypePolicy,
     "fp32": DataTypePolicy,
     "mixed_bf16": mixed_bf16,
     "bf16": mixed_bf16,
+    "bf16_params": bf16_params,
 }
 
 

@@ -239,14 +239,16 @@ def _expected_family(layer: Layer) -> str:
         return "cnn"
     if name in ("lstm", "graves_lstm", "graves_bidirectional_lstm", "simple_rnn",
                 "rnn_output", "convolution1d", "subsampling1d", "zeropadding1d",
-                "upsampling1d", "last_time_step", "multi_head_attention"):
+                "upsampling1d", "last_time_step", "multi_head_attention",
+                "lm_head"):
         return "rnn"
     if name in ("batchnorm", "activation", "dropout_layer", "global_pooling",
                 "loss", "reshape", "permute", "layernorm",
                 # shape-agnostic sequence layers: embedding gathers per
                 # position; positional-encoding/transformer blocks keep
                 # [B,T,D] — none of them wants a time-flattening insert
-                "embedding", "positional_encoding", "transformer_encoder"):
+                "embedding", "positional_encoding", "transformer_encoder",
+                "latent_attention_block", "rms_norm"):
         return "any"
     return "ff"
 

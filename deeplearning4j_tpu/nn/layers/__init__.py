@@ -63,3 +63,8 @@ from deeplearning4j_tpu.nn.layers.training import CenterLossOutputLayer
 from deeplearning4j_tpu.nn.layers.objdetect import Yolo2OutputLayer
 from deeplearning4j_tpu.nn.layers.attention import MultiHeadAttention
 from deeplearning4j_tpu.nn.layers.moe import MixtureOfExperts
+from deeplearning4j_tpu.nn.layers.latent import (
+    LatentAttentionBlock,
+    LMHead,
+    RMSNormLayer,
+)

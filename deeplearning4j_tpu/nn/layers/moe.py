@@ -115,3 +115,81 @@ class MixtureOfExperts(Layer):
             # them to the objective (no Python-object mutation under jit)
             state = {**state, "aux_loss": self.load_balance_coef * aux}
         return out, state
+
+
+# --------------------------------------------------------------------------
+# Held experts: the share of a routed expert layer that one chip computes.
+#
+# Expert parallelism divides a layer's experts over chips; every chip
+# routes each of its tokens over ALL the experts (the router keeps its
+# published width), then computes the tokens routed to the experts it
+# holds.  The functions below are that chip's part: no capacity, no
+# dropped token, rows sorted by expert and multiplied in groups
+# (`jax.lax.ragged_dot`), never every expert on every token.  What the
+# absent experts would add is left out: on one chip the layer runs
+# without its exchange, and nothing here stands in for it.
+# --------------------------------------------------------------------------
+def sigmoid_topk_route(h, router, bias, top_k: int, scaling: float):
+    """h [N, D] -> (chosen [N, k] expert ids, gates [N, k] float32).
+    Scores are `sigmoid(h router)` in float32; the `top_k` largest of
+    `score + bias` are chosen (the bias chooses and does not weigh:
+    DeepSeek-V3's auxiliary-loss-free balancing); the gates are the
+    chosen scores normalised over the chosen and scaled."""
+    logits = jnp.matmul(h, router, preferred_element_type=jnp.float32)
+    score = jax.nn.sigmoid(logits)
+    _, chosen = jax.lax.top_k(score + bias.astype(jnp.float32), top_k)
+    picked = jnp.take_along_axis(score, chosen, axis=-1)
+    gates = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    return chosen.astype(jnp.int32), gates
+
+
+def held_expert_groups(chosen, valid, first: int, count: int):
+    """Sort the (token, chosen expert) pairs by held expert.  -> (order
+    [N*k]: pair indices, the held experts' pairs first, grouped by
+    expert; sizes [count]: pairs of each held expert; held [N, k]: which
+    pairs a held expert serves).  A pair whose expert is absent, or whose
+    token is not `valid` (padding, an idle slot), belongs to no group and
+    sorts last."""
+    local = chosen - first
+    held = (local >= 0) & (local < count)
+    if valid is not None:
+        held = held & valid[:, None]
+    key = jnp.where(held, local, count).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(count)[None, :], axis=0,
+                    dtype=jnp.int32)
+    return order, sizes, held
+
+
+def held_experts_swiglu(h, chosen, gates, w_gate, w_up, w_down, *,
+                        first: int, valid=None):
+    """The held experts' part of `sum_e g_e SwiGLU_e(h)` for h [N, D]:
+    w_gate, w_up [E, D, F], w_down [E, F, D] are the experts
+    `first .. first+E-1` of the layer.  -> (y [N, D] in h.dtype, sizes
+    [E]: rows each held expert multiplied)."""
+    N, k = chosen.shape
+    E = w_gate.shape[0]
+    order, sizes, held = held_expert_groups(chosen, valid, first, E)
+    rows = h[order // k]                                     # [N*k, D]
+    a = jax.lax.ragged_dot(rows, w_gate, sizes)
+    b = jax.lax.ragged_dot(rows, w_up, sizes)
+    y = jax.lax.ragged_dot((jax.nn.silu(a) * b).astype(h.dtype), w_down,
+                           sizes)                            # [N*k, D]
+    # back to (token, chosen) order, then each token's held experts are
+    # weighed and summed in float32. Pairs of no group are selected out,
+    # not multiplied by nought: their rows are whatever the grouped
+    # product left there
+    y = y[jnp.argsort(order)].reshape(N, k, -1)
+    weight = jnp.where(held, gates, 0.0)[..., None]
+    out = jnp.sum(jnp.where(weight != 0, y.astype(jnp.float32) * weight,
+                            0.0), axis=1)
+    return out.astype(h.dtype), sizes
+
+
+def expert_load_stats(sizes):
+    """(rows, fullest over mean) of one dispatch's held experts, as
+    float32 scalars; the ratio reads 0 where no row was routed here."""
+    rows = jnp.sum(sizes).astype(jnp.float32)
+    mean = rows / sizes.shape[0]
+    return rows, jnp.where(rows > 0, jnp.max(sizes) / jnp.maximum(mean, 1e-9),
+                           0.0)

@@ -53,6 +53,10 @@ class PositionalEncodingLayer(BaseRecurrentLayer):
     def get_output_type(self, input_type):
         return input_type
 
+    @property
+    def stream_limit(self):
+        return self.max_len
+
     def _table(self, T, D, dtype):
         pos = np.arange(T)[:, None]
         i = np.arange(D // 2)[None, :]
@@ -274,6 +278,54 @@ class TransformerEncoderBlock(BaseRecurrentLayer):
                                         rng=rng)
         return y, {}, new_carry
 
+    # ------------------------------------------------- the paged protocol
+    # What the serving engine asks of a layer that keeps per-token state
+    # in the paged pool (docs/SERVING.md): the arrays it wants, how a
+    # filled monolithic carry is cut into their pages, and the cached
+    # steps.  `LatentAttentionBlock` (nn/layers/latent.py) implements
+    # the same names over one latent array.
+    paged_cache = True
+
+    @property
+    def stream_limit(self):
+        return self.cache_len
+
+    @property
+    def paged_stream_limit(self):
+        """Prefill runs through the monolithic carry, so the paged path
+        is bounded by `cache_len` too."""
+        return self.cache_len
+
+    @property
+    def paged_handoff_heads(self):
+        """The prefill->decode handoff wire keeps heads apart."""
+        return self.n_heads
+
+    def paged_pool_arrays(self, n_blocks, block_len, dtype):
+        """(K pool, V pool), `[n_blocks, block_len, H*Dh]` each."""
+        shape = (n_blocks, block_len, self.n_in)
+        return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+    def carry_pages(self, carry):
+        """A filled `init_carry` -> the `[B, cache_len, ...]` arrays the
+        pool arrays' pages are cut from, in `paged_pool_arrays` order."""
+        return (carry[0], carry[1])
+
+    def paged_in_place(self, arrays) -> bool:
+        return self.paged_decode_in_place(arrays[0])
+
+    def paged_step(self, params, x, arrays, block_table, pos, live=None, *,
+                   stats=None):
+        y, k_pool, v_pool = self.forward_paged(
+            params, x, arrays[0], arrays[1], block_table, pos, live)
+        return y, (k_pool, v_pool)
+
+    def paged_step_multi(self, params, x, arrays, block_table, pos, n_valid,
+                         *, stats=None):
+        y, k_pool, v_pool = self.forward_paged_multi(
+            params, x, arrays[0], arrays[1], block_table, pos, n_valid)
+        return y, (k_pool, v_pool)
+
     def paged_decode_in_place(self, k_pool) -> bool:
         """`MultiHeadAttention.paged_decode_in_place` of this block's
         attention: whether `forward_paged`, traced now, reads the pool
@@ -359,17 +411,16 @@ class TransformerEncoderBlock(BaseRecurrentLayer):
 def stream_budget(layers):
     """Smallest bounded stream length in a layer stack, or None.
 
-    KV caches (`TransformerEncoderBlock.cache_len`) and positional
-    tables (`PositionalEncodingLayer.max_len`) both clamp writes/reads
+    KV caches (`TransformerEncoderBlock.cache_len`, the latent cache of
+    `LatentAttentionBlock`) and positional tables
+    (`PositionalEncodingLayer.max_len`) declare a `stream_limit`: both
+    clamp writes/reads
     past their length (dynamic_update_slice / dynamic_slice semantics)
     — silently corrupting every later token while still emitting
     valid-looking activations. Streaming entry points (`rnn_time_step`,
     TBPTT drivers, zoo generate/beam_search) call this to enforce the
     budget eagerly on the host, where the accumulated position is
     known."""
-    limits = [l.cache_len for l in layers
-              if isinstance(l, TransformerEncoderBlock)]
-    limits += [l.max_len for l in layers
-               if isinstance(l, PositionalEncodingLayer)
-               and l.max_len is not None]
+    limits = [l.stream_limit for l in layers
+              if getattr(l, "stream_limit", None) is not None]
     return min(limits) if limits else None

@@ -61,11 +61,7 @@ from deeplearning4j_tpu import monitor
 from deeplearning4j_tpu.nd import quant
 from deeplearning4j_tpu.nd.donation import donate_argnums
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer
-from deeplearning4j_tpu.nn.layers.transformer import (
-    PositionalEncodingLayer,
-    TransformerEncoderBlock,
-    stream_budget,
-)
+from deeplearning4j_tpu.nn.layers.transformer import PositionalEncodingLayer
 from deeplearning4j_tpu.serving.paged import (
     GARBAGE_BLOCK,
     PagedKVPool,
@@ -83,6 +79,17 @@ def bucket_len(n: int, cap: int) -> int:
     while b < n:
         b *= 2
     return min(b, cap)
+
+
+def _moe_means(stats: dict):
+    """What the routed expert layers of one traced forward reported
+    (`stats`, filled by `paged_step` / `forward_prefill`) as the two
+    means over those layers: (rows routed to held experts, fullest held
+    expert over the mean); () for a net with no such layer."""
+    n = stats.get("moe_layers", 0)
+    if not n:
+        return ()
+    return (stats["moe_rows"] / n, stats["moe_load_max_over_mean"] / n)
 
 
 class Slot:
@@ -136,7 +143,10 @@ class PagedDecodeEngine:
                  spec_max_ngram: int = 3,
                  spec_sampled: bool = False,
                  spec_draft_layers: Optional[int] = None,
-                 prefix_cache: str = "registered"):
+                 prefix_cache: str = "registered",
+                 max_positions: Optional[int] = None,
+                 max_prefill_tokens: Optional[int] = None,
+                 min_prefill_bucket: int = 1):
         if not getattr(net, "_initialized", False):
             net.init()
         self.net = net
@@ -192,11 +202,29 @@ class PagedDecodeEngine:
         # dispatch (the tree itself is resolved per dispatch — see
         # the _params property)
         quant.serving_params(net, quantize)
-        budget = stream_budget(net.layers)
-        if budget is None:
+        # the per-sequence budget: the smallest length a layer of the
+        # net bounds the paged path to (a positional table's `max_len`,
+        # the `cache_len` a block's prefill carry has) and the server's
+        # own `max_positions` — which a rotary model, with no table and
+        # no length of its own, needs
+        limits = [l.paged_stream_limit for l in net.layers
+                  if getattr(l, "paged_cache", False)]
+        limits += [l.max_len for l in net.layers
+                   if isinstance(l, PositionalEncodingLayer)]
+        limits = [int(x) for x in limits if x is not None]
+        if max_positions is not None:
+            if limits and int(max_positions) > min(limits):
+                raise ValueError(
+                    f"max_positions {max_positions} exceeds what the net "
+                    f"itself can hold ({min(limits)}: KV cache_len / "
+                    f"positional max_len)")
+            limits.append(int(max_positions))
+        if not limits:
             raise ValueError(
-                "net has no bounded stream budget (no TransformerEncoder"
-                "Block / PositionalEncodingLayer) — nothing to page")
+                "net has no bounded stream budget (no positional table, "
+                "no cache length of its own): give the server "
+                "max_positions, the positions a slot may hold")
+        budget = min(limits)
         if budget % block_len != 0:
             raise ValueError(
                 f"block_len {block_len} must divide the stream budget "
@@ -224,9 +252,27 @@ class PagedDecodeEngine:
         # those programs' cache entries and is what `kv_read_pct`
         # counts from
         self._in_place = tuple(
-            net.layers[i].paged_decode_in_place(k_pool)
-            for i, (k_pool, _) in zip(self.pool.layer_indices,
-                                      self.pool.kv))
+            net.layers[i].paged_in_place(arrays)
+            for i, arrays in zip(self.pool.layer_indices, self.pool.kv))
+        # prefill: a layer that implements `forward_prefill` hands back
+        # the rows its pages are cut from, prompt-long, and the wave's
+        # last positions alone go through the layers after the last
+        # paged one; otherwise the prompt runs through the net's
+        # monolithic carries (`get_prefill_bucketed`), budget-long
+        self._paged_prefill = all(
+            hasattr(net.layers[i], "forward_prefill")
+            for i in self.pool.layer_indices)
+        # admission bounded in tokens: no prefill program is wider than
+        # `max_prefill_tokens` (wave width x prompt bucket); prompts
+        # pad to at least `min_prefill_bucket` (a grid no traffic uses
+        # is set-up time for nothing)
+        self.max_prefill_tokens = (None if max_prefill_tokens is None
+                                   else int(max_prefill_tokens))
+        self.min_prefill_bucket = max(1, int(min_prefill_bucket))
+        if self.min_prefill_bucket & (self.min_prefill_bucket - 1):
+            raise ValueError(
+                f"min_prefill_bucket must be a power of two; got "
+                f"{min_prefill_bucket}")
         # a serving "plan": how each layer participates in the paged
         # decode walk. Input preprocessors would silently change the
         # math mid-walk — reject loudly (the zoo LMs have none).
@@ -237,7 +283,7 @@ class PagedDecodeEngine:
         self._plan: List[Tuple] = []
         pool_j = 0
         for i, layer in enumerate(net.layers):
-            if isinstance(layer, TransformerEncoderBlock):
+            if getattr(layer, "paged_cache", False):
                 self._plan.append(("block", i, pool_j))
                 pool_j += 1
             elif isinstance(layer, PositionalEncodingLayer):
@@ -355,8 +401,17 @@ class PagedDecodeEngine:
         # readbacks (0.0 with monitoring off: nothing is timed then)
         self.loop_it = 0
         self.admit_bucket = 0
+        self.admit_tokens = 0     # prompt tokens of the last admit_many
         self.wait_s = 0.0
         self.kv_read_pct = 0.0
+        # positions the last decode dispatch's attention read (summed
+        # over its paged layers), and what the routed expert layers of
+        # the last dispatch (decode or admission) report: rows routed
+        # to held experts and the fullest held expert over the mean,
+        # each a mean over those layers; None where the net has none.
+        # Read back with the tokens: no transfer of their own
+        self.positions_read = 0
+        self.moe_stats: Optional[Tuple[float, float]] = None
 
     # ------------------------------------------------------------ queries
     @property
@@ -380,6 +435,12 @@ class PagedDecodeEngine:
     @property
     def free_blocks(self) -> int:
         return self.pool.free_blocks
+
+    def _bucket(self, n: int) -> int:
+        """Padded length of an `n`-token prompt: the next power of two,
+        at least `min_prefill_bucket`, at most the budget."""
+        return min(max(bucket_len(n, self.max_total_tokens),
+                       self.min_prefill_bucket), self.max_total_tokens)
 
     def _admit_blocks(self, prompt_len: int, n_tokens: int) -> int:
         """Blocks an admission grants NOW: the prompt's footprint under
@@ -507,6 +568,13 @@ class PagedDecodeEngine:
                 f"{self.max_total_tokens} (max_blocks {self.max_blocks} x "
                 f"block_len {self.block_len}); this request can never be "
                 f"admitted — rebuild the model with a larger max_len")
+        if (self.max_prefill_tokens is not None
+                and self._bucket(prompt_len) > self.max_prefill_tokens):
+            raise ValueError(
+                f"a prompt of {prompt_len} tokens pads to "
+                f"{self._bucket(prompt_len)}, over the server's "
+                f"max_prefill_tokens {self.max_prefill_tokens}; it can "
+                f"never be admitted")
         # id 0 is the garbage block; prefix-cache pins never free
         usable = self.pool.n_blocks - 1 - self.prefix_pinned_blocks
         needed = blocks_needed(total, self.block_len)
@@ -577,6 +645,7 @@ class PagedDecodeEngine:
                       live, keys, emit_idx, temp, top_p):
             h = token_ids[:, None]            # [S, 1] int ids
             kv = list(kv)
+            stats = {}
             for entry in plan:
                 kind, i = entry[0], entry[1]
                 layer = layers[i]
@@ -588,14 +657,13 @@ class PagedDecodeEngine:
                     h, _ = layer.forward_at_positions(lp, ls, h, pos)
                 else:
                     j = entry[2]
-                    k_pool, v_pool = kv[j]
-                    h, k_pool, v_pool = layer.forward_paged(
-                        lp, h, k_pool, v_pool, block_tables, pos, live)
-                    kv[j] = (k_pool, v_pool)
+                    h, kv[j] = layer.paged_step(
+                        lp, h, kv[j], block_tables, pos, live, stats=stats)
             probs = h[:, -1]                   # [S, V]
-            return tuple(kv), self._sample_ids(probs, keys, emit_idx,
-                                               temp, top_p,
-                                               greedy_only=greedy_only)
+            return (tuple(kv), self._sample_ids(probs, keys, emit_idx,
+                                                temp, top_p,
+                                                greedy_only=greedy_only),
+                    _moe_means(stats))
 
         def decode_step(params, state, kv, block_tables, token_ids,
                         pos, remaining, keys, emit_idx, temp, top_p):
@@ -614,16 +682,18 @@ class PagedDecodeEngine:
 
             def micro(carry, _):
                 kv, tok, pos, rem, emit = carry
-                kv, nxt = one_token(params, state, kv, block_tables,
-                                    tok, pos, rem > 0, keys, emit, temp,
-                                    top_p)
+                kv, nxt, moe = one_token(params, state, kv, block_tables,
+                                         tok, pos, rem > 0, keys, emit,
+                                         temp, top_p)
                 return ((kv, nxt, pos + 1, rem - 1, emit + 1),
-                        (nxt, rem > 0))
+                        (nxt, rem > 0, moe))
 
             carry = (kv, token_ids, pos, remaining, emit_idx)
-            (kv, _, _, _, _), (toks, valids) = jax.lax.scan(
+            (kv, _, _, _, _), (toks, valids, moe) = jax.lax.scan(
                 micro, carry, None, length=J)
-            return kv, toks, valids            # [J, S] each
+            # [J, S] each; `moe` is () for a net with no routed experts
+            # (the program then has no such output), else two [J] rows
+            return kv, toks, valids, moe
 
         return decode_step
 
@@ -664,6 +734,78 @@ class PagedDecodeEngine:
             "n_slots": S,
         }
 
+    def _prefill_paged_body(self):
+        """Prefill through the paged protocol: each paged layer's
+        `forward_prefill` returns the rows its pages are cut from (as
+        long as the prompt bucket, padded to whole blocks), and only
+        each prompt's LAST position goes through the layers after the
+        last paged one (the final norm, the vocabulary head: logits of
+        every position of an 8k-token prompt would be gigabytes)."""
+        net, layers, plan = self.net, self.net.layers, self._plan
+        bl = self.block_len
+        last_block = max(n for n, e in enumerate(plan) if e[0] == "block")
+
+        def prefill(params, state, x, last_idx):
+            params = net.dtype.cast_params(params)
+            h = x
+            lengths = last_idx + 1
+            rows_out = []
+            stats = {}
+            for n, entry in enumerate(plan):
+                kind, i = entry[0], entry[1]
+                layer = layers[i]
+                lp = params.get(str(i), {})
+                ls = state.get(str(i), {})
+                if kind == "plain":
+                    h, _ = layer.forward(lp, ls, h, train=False, rng=None)
+                elif kind == "pos":
+                    h, _ = layer.forward_at_positions(
+                        lp, ls, h, jnp.broadcast_to(
+                            jnp.arange(h.shape[1]), h.shape[:2]))
+                else:
+                    h, rows = layer.forward_prefill(lp, h, lengths,
+                                                    stats=stats)
+                    pad = -h.shape[1] % bl
+                    rows_out.append(tuple(
+                        jnp.pad(r, ((0, 0), (0, pad), (0, 0)))
+                        for r in rows))
+                if n == last_block:
+                    h = h[jnp.arange(h.shape[0]), last_idx][:, None]
+            return h[:, -1], tuple(rows_out), _moe_means(stats)
+
+        return prefill
+
+    def _run_prefill(self, prompts, last_idx):
+        """One prefill dispatch over right-padded `prompts` [k, Pb] ->
+        (probs [k, V] at each row's `last_idx`, per paged layer the
+        `[k, C, width]` arrays its pages are cut from, the routed
+        expert layers' device scalars for `_take_moe`)."""
+        net = self.net
+        if self._paged_prefill:
+            fn = self._shared_jit(
+                ("prefill_paged", tuple(self._plan), self.block_len),
+                lambda: jax.jit(self._prefill_paged_body()))
+            return fn(self._params, net.net_state, jnp.asarray(prompts),
+                      jnp.asarray(last_idx))
+        from deeplearning4j_tpu.zoo.transformer import get_prefill_bucketed
+        k = prompts.shape[0]
+        carries = {str(i): layer.init_carry(k, net.dtype.compute_dtype)
+                   for i, layer in enumerate(net.layers)
+                   if isinstance(layer, BaseRecurrentLayer)}
+        probs, carries = get_prefill_bucketed(net)(
+            self._params, net.net_state, jnp.asarray(prompts), carries,
+            jnp.asarray(last_idx))
+        return probs, tuple(net.layers[i].carry_pages(carries[str(i)])
+                            for i in self.pool.layer_indices), ()
+
+    def _take_moe(self, moe):
+        """Device scalars from a program's routed expert layers -> the
+        host pair `moe_stats` (the mean over a fused chunk's steps), at
+        a point where the caller has already blocked on that program's
+        tokens."""
+        self.moe_stats = (tuple(float(np.mean(np.asarray(m))) for m in moe)
+                          if moe else None)
+
     def _build_admit_finish(self, k: int, greedy_only: bool):
         """One fused dispatch completing a k-wide admission wave:
         scatter every sequence's monolithic prefill K/V into its pool
@@ -677,23 +819,21 @@ class PagedDecodeEngine:
 
         def admit_finish(kv, rows, block_carries, probs, keys, emit0,
                          temp, top_p):
-            # rows [k, max_rows]; block_carries: per layer (k_cache,
-            # v_cache) with leading dim k; probs [k, V]; emit0 [k] is
+            # rows [k, max_rows]; block_carries: per paged layer the
+            # arrays its pool's pages are cut from (`carry_pages` /
+            # `forward_prefill`), leading dim k; probs [k, V]; emit0 [k] is
             # the sampled-rng emit offset (nonzero for a requeued
             # continuation — its stream keeps the fold_in(key, t)
             # indices it would have had uninterrupted)
             out = []
-            for (k_pool, v_pool), (k_cache, v_cache) in zip(
-                    kv, block_carries):
-                C = k_cache.shape[1]       # [k, C, H, Dh] -> pages
-                shape = (k * (C // bl), bl, k_pool.shape[-1])
+            for pools, caches in zip(kv, block_carries):
+                C = caches[0].shape[1]     # [k, C, ...] -> pages
                 flat_rows = rows[:, :C // bl].reshape(-1)
-                out.append((
-                    k_pool.at[flat_rows].set(
-                        k_cache.reshape(shape).astype(k_pool.dtype)),
-                    v_pool.at[flat_rows].set(
-                        v_cache.reshape(shape).astype(v_pool.dtype)),
-                ))
+                out.append(tuple(
+                    pool.at[flat_rows].set(cache.reshape(
+                        (k * (C // bl), bl, pool.shape[-1])
+                    ).astype(pool.dtype))
+                    for pool, cache in zip(pools, caches)))
             firsts = self._sample_ids(probs, keys, emit0, temp, top_p,
                                       greedy_only=greedy_only)
             return tuple(out), firsts
@@ -784,11 +924,8 @@ class PagedDecodeEngine:
         shape-keyed executable."""
 
         def fork(kv, src, dst):
-            out = []
-            for k_pool, v_pool in kv:
-                out.append((k_pool.at[dst].set(k_pool[src]),
-                            v_pool.at[dst].set(v_pool[src])))
-            return tuple(out)
+            return tuple(tuple(pool.at[dst].set(pool[src])
+                               for pool in pools) for pools in kv)
 
         return self._shared_jit(
             ("fork",),
@@ -857,11 +994,8 @@ class PagedDecodeEngine:
                         h, _ = layer.forward_at_positions(lp, ls, h, pos)
                     else:
                         j = entry[2]
-                        k_pool, v_pool = kv[j]
-                        h, k_pool, v_pool = layer.forward_paged(
-                            lp, h, k_pool, v_pool, block_tables, pos,
-                            live)
-                        kv[j] = (k_pool, v_pool)
+                        h, kv[j] = layer.paged_step(
+                            lp, h, kv[j], block_tables, pos, live)
                 nxt = jnp.argmax(h[:, -1], axis=-1).astype(jnp.int32)
                 return (tuple(kv), nxt, pos + 1), nxt
 
@@ -938,20 +1072,11 @@ class PagedDecodeEngine:
                 f"({self.pool.free_blocks} free) — register prefixes "
                 f"before admitting traffic, or grow n_blocks")
         try:
-            from deeplearning4j_tpu.zoo.transformer import (
-                get_prefill_bucketed)
-            net = self.net
-            Pb = bucket_len(P, self.max_total_tokens)
+            Pb = self._bucket(P)
             prompts = np.zeros((1, Pb), np.int32)
             prompts[0, :P] = prompt
-            carries = {str(i): layer.init_carry(1, net.dtype.compute_dtype)
-                       for i, layer in enumerate(net.layers)
-                       if isinstance(layer, BaseRecurrentLayer)}
-            probs, carries = get_prefill_bucketed(net)(
-                self._params, net.net_state, jnp.asarray(prompts),
-                carries, jnp.asarray([P - 1], np.int32))
-            block_carries = [carries[str(i)]
-                             for i in self.pool.layer_indices]
+            probs, block_carries, _ = self._run_prefill(
+                prompts, np.asarray([P - 1], np.int32))
             max_rows = max(c[0].shape[1] // self.block_len
                            for c in block_carries)
             rows = np.full((1, max_rows), GARBAGE_BLOCK, np.int32)
@@ -966,8 +1091,7 @@ class PagedDecodeEngine:
                 fin = self._admit_finish[(1, True)] = \
                     self._build_admit_finish(1, True)
             self.pool.kv, _ = fin(
-                self.pool.kv, jnp.asarray(rows),
-                tuple((c[0], c[1]) for c in block_carries), probs,
+                self.pool.kv, jnp.asarray(rows), block_carries, probs,
                 jnp.zeros((1, 2), np.uint32), jnp.zeros(1, np.int32),
                 jnp.zeros(1, np.float32), jnp.ones(1, np.float32))
         except Exception:
@@ -1026,6 +1150,7 @@ class PagedDecodeEngine:
             return []
         self.admit_info = {}
         self.wait_s = 0.0
+        self.admit_tokens = 0
         it = self.loop_it
         wave = []
         try:
@@ -1054,6 +1179,8 @@ class PagedDecodeEngine:
                         # ALL prior admissions (prefix_cache="radix")
                         entry = self._match_radix(prompt)
                     if entry is None:
+                        if not self._wave_fits(wave, P):
+                            break
                         nb = self._admit_blocks(P, n_tokens)
                         blocks = self._alloc_admit(nb)
                         if blocks is None:
@@ -1069,6 +1196,7 @@ class PagedDecodeEngine:
                     wave.append(w)
             if not wave:
                 return []
+            self.admit_tokens = sum(int(w["prompt"].shape[0]) for w in wave)
             out = self._admit_dispatch(wave)
             if self._radix is not None:
                 # every admission's fully-written prompt blocks feed
@@ -1105,6 +1233,21 @@ class PagedDecodeEngine:
                     except ValueError:
                         pass   # already back in the pool
             raise
+
+    def _wave_fits(self, wave, prompt_len: int) -> bool:
+        """Would one more fresh prompt of `prompt_len` keep the wave's
+        prefill program (pow2 width x prompt bucket) within
+        `max_prefill_tokens`?  A wave's first prompt always fits
+        (`check_budget` refused the ones that never can)."""
+        if self.max_prefill_tokens is None:
+            return True
+        fresh = [int(w["prompt"].shape[0]) for w in wave
+                 if w["entry"] is None]
+        k2 = 1
+        while k2 < len(fresh) + 1:
+            k2 *= 2
+        return k2 * self._bucket(max(fresh + [prompt_len])) \
+            <= self.max_prefill_tokens
 
     def _cow_admit_blocks(self, entry: dict, prompt_len: int,
                           n_tokens: int) -> Optional[dict]:
@@ -1177,20 +1320,12 @@ class PagedDecodeEngine:
         # admissions under realistic traffic): right padding is sound
         # because the blocks are causal and the padding rows' K/V land
         # past each slot's position, where every later read masks them
-        Pb = bucket_len(max(int(w["prompt"].shape[0]) for w in wave),
-                        self.max_total_tokens)
+        Pb = self._bucket(max(int(w["prompt"].shape[0]) for w in wave))
         self.admit_bucket = Pb
         it = self.loop_it
 
         with monitor.span("serve/admit/dispatch", it=it, width=k2,
                           bucket=Pb):
-            net = self.net
-            from deeplearning4j_tpu.zoo.transformer import (
-                get_prefill_bucketed)
-            prefill = get_prefill_bucketed(net)
-            carries = {str(i): layer.init_carry(k2, net.dtype.compute_dtype)
-                       for i, layer in enumerate(net.layers)
-                       if isinstance(layer, BaseRecurrentLayer)}
             prompts = np.zeros((k2, Pb), np.int32)
             last_idx = np.zeros(k2, np.int32)
             for j, w in enumerate(wave):
@@ -1199,12 +1334,8 @@ class PagedDecodeEngine:
             for j in range(k, k2):            # dummy width-padding rows
                 prompts[j] = prompts[k - 1]
                 last_idx[j] = last_idx[k - 1]
-            probs, carries = prefill(self._params, net.net_state,
-                                     jnp.asarray(prompts), carries,
-                                     jnp.asarray(last_idx))
-
-            block_carries = [carries[str(i)]
-                             for i in self.pool.layer_indices]
+            probs, block_carries, moe = self._run_prefill(prompts,
+                                                          last_idx)
             max_rows = max(c[0].shape[1] // self.block_len
                            for c in block_carries)
             rows = np.full((k2, max_rows), GARBAGE_BLOCK, np.int32)
@@ -1213,7 +1344,9 @@ class PagedDecodeEngine:
             temps = np.zeros(k2, np.float32)
             top_ps = np.ones(k2, np.float32)
             for j, w in enumerate(wave):
-                rows[j, :len(w["blocks"])] = w["blocks"]
+                # an upfront grant may pass the rows the prefill wrote
+                n = min(len(w["blocks"]), max_rows)
+                rows[j, :n] = w["blocks"][:n]
                 r = w["r"]
                 if r.get("rng") is not None:
                     keys[j] = np.asarray(r["rng"], np.uint32).reshape(2)
@@ -1230,12 +1363,12 @@ class PagedDecodeEngine:
                 fin = self._admit_finish[(k2, greedy)] = \
                     self._build_admit_finish(k2, greedy)
             self.pool.kv, firsts = fin(
-                self.pool.kv, jnp.asarray(rows),
-                tuple((c[0], c[1]) for c in block_carries), probs,
+                self.pool.kv, jnp.asarray(rows), block_carries, probs,
                 jnp.asarray(keys), jnp.asarray(emit0), jnp.asarray(temps),
                 jnp.asarray(top_ps))
         with monitor.span("serve/admit/wait", it=it) as sp:
             firsts = np.asarray(firsts)
+            self._take_moe(moe)
         self.wait_s += sp.duration_s
 
         with monitor.span("serve/admit/post", it=it):
@@ -1549,6 +1682,8 @@ class PagedDecodeEngine:
             speculate = self.spec_k is not None
         self.wait_s = 0.0
         self.kv_read_pct = 100.0     # the K-wide score path gathers
+        self.positions_read = 0
+        self.moe_stats = None
         if speculate and self.spec_k:
             return self._spec_step(proposers=proposers)
         it = self.loop_it
@@ -1575,7 +1710,7 @@ class PagedDecodeEngine:
                         greedy_only=True)
                 decode = self._decode_greedy
             self.kv_read_pct = self._kv_read_pct()
-            kv, toks, valids = decode(
+            kv, toks, valids, moe = decode(
                 self._params, self.net.net_state, self.pool.kv,
                 jnp.asarray(self.block_tables),
                 jnp.asarray(self.last_token),
@@ -1586,6 +1721,7 @@ class PagedDecodeEngine:
         with monitor.span("serve/decode/wait", it=it) as sp:
             toks = np.asarray(toks)                     # [J, S]
             valids = np.asarray(valids)
+            self._take_moe(moe)
         self.wait_s = sp.duration_s
         with monitor.span("serve/decode/post", it=it):
             return self._after_decode(toks, valids)
@@ -1602,9 +1738,17 @@ class PagedDecodeEngine:
         whole = self.steps_per_dispatch * self.n_slots * self.max_blocks
         j = np.arange(self.steps_per_dispatch)[:, None]      # [J, 1]
         held = -(-(self.pos[None, :] + j + 1) // self.block_len)
-        read = int(np.where(self.remaining[None, :] > j, held, 0).sum())
+        live = self.remaining[None, :] > j
+        read = int(np.where(live, held, 0).sum())
         n_in_place = sum(self._in_place)
         n_layers = len(self._in_place)
+        # positions, not blocks: an in-place layer reads the `pos + 1`
+        # rows a live slot holds, a gathering layer the whole budget of
+        # every slot
+        self.positions_read = (
+            n_in_place * int(np.where(
+                live, self.pos[None, :] + j + 1, 0).sum())
+            + (n_layers - n_in_place) * whole * self.block_len)
         return 100.0 * (n_in_place * read
                         + (n_layers - n_in_place) * whole) / (
             n_layers * whole)
@@ -1885,6 +2029,7 @@ class PagedDecodeEngine:
         The exporting engine is left untouched — the caller releases
         the slot with `evict()` once the handoff is safely delivered
         (at-least-once: a failed send keeps the slot decodable here)."""
+        self._check_handoff_wire()
         s = self.slots[slot]
         if s is None:
             raise ValueError(f"slot {slot} is not in use")
@@ -1896,7 +2041,8 @@ class PagedDecodeEngine:
         for i, (k, v) in zip(self.pool.layer_indices, self.pool.kv):
             # the wire keeps heads and head_dim apart; the pool keeps a
             # page's heads side by side
-            page = (self.block_len, self.net.layers[i].n_heads, -1)
+            page = (self.block_len,
+                    self.net.layers[i].paged_handoff_heads, -1)
             per_layer.append(np.stack(
                 [np.asarray(k)[idx].reshape(len(idx), *page),
                  np.asarray(v)[idx].reshape(len(idx), *page)]))
@@ -1920,6 +2066,22 @@ class PagedDecodeEngine:
         }
         return header, kv
 
+    def _check_handoff_wire(self):
+        """The handoff wire (`wire.encode_handoff`) is `[n_layers, 2,
+        n_blocks, block_len, heads, head_dim]`: (K, V) pages of heads.
+        A pool whose layers declare other arrays (a latent pool: one
+        array, no head axis) has no place on it."""
+        for i, arrays in zip(self.pool.layer_indices, self.pool.kv):
+            layer = self.net.layers[i]
+            if (len(arrays) != 2
+                    or getattr(layer, "paged_handoff_heads", None) is None):
+                raise NotImplementedError(
+                    f"prefill->decode handoff carries (K, V) pages of "
+                    f"heads; layer {i} ({type(layer).__name__}) keeps "
+                    f"{len(arrays)} pool array(s) of width "
+                    f"{arrays[0].shape[-1]} with no head axis — the wire "
+                    f"format has no such payload yet")
+
     def adopt_handoff(self, header: dict, kv) -> int:
         """Adopt a handed-off slot: allocate private blocks, scatter
         the K/V payload into the pool, and rebuild the host slot state
@@ -1928,6 +2090,7 @@ class PagedDecodeEngine:
         extended across the wire). Raises ValueError on a pool-shape/
         dtype mismatch, RuntimeError when no slot or blocks are free
         (the caller's backpressure signal — nothing is mutated)."""
+        self._check_handoff_wire()
         kv = np.asarray(kv)
         L = len(self.pool.kv)
         k0 = self.pool.kv[0][0]
@@ -1939,7 +2102,8 @@ class PagedDecodeEngine:
             raise ValueError(
                 f"handoff block_len {header['block_len']} != engine "
                 f"block_len {self.block_len}")
-        n_heads = self.net.layers[self.pool.layer_indices[0]].n_heads
+        n_heads = self.net.layers[
+            self.pool.layer_indices[0]].paged_handoff_heads
         page = (self.block_len, n_heads, k0.shape[2] // n_heads)
         if tuple(kv.shape[3:]) != page:
             raise ValueError(

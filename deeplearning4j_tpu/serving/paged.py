@@ -15,9 +15,10 @@ the next admission.
 
 Split of responsibilities:
 
-- device: the block pools (one (K, V) pair per transformer block
-  layer, all dtype = the net's compute dtype) and the gather/scatter
-  attention path (`MultiHeadAttention.forward_with_paged_cache`);
+- device: the block pools (whatever arrays each paged layer declares:
+  a (K, V) pair per transformer block, one latent array per latent
+  attention block; all dtype = the net's compute dtype) and the layers'
+  own cached steps (`paged_step`: docs/SERVING.md, the paged protocol);
 - host: free/used accounting (`BlockAllocator`) and the block tables,
   which ride h2d once per scheduler step.
 
@@ -30,10 +31,6 @@ anything. The allocator never hands it out.
 from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
-
-import jax.numpy as jnp
-
-from deeplearning4j_tpu.nn.layers.transformer import TransformerEncoderBlock
 
 GARBAGE_BLOCK = 0
 
@@ -330,12 +327,15 @@ class RadixPrefixCache:
 class PagedKVPool:
     """The per-layer block pools for one model + the shared allocator.
 
-    `kv` is a flat tuple of (k_pool, v_pool) pairs — one per
-    TransformerEncoderBlock in layer order — shaped
-    `[n_blocks, block_len, n_heads * head_dim]` in the net's compute
-    dtype (the same dtype `init_carry` gives the monolithic caches, so
-    prefill copies are exact). It is a plain pytree: jitted programs
-    take it as an argument and return the updated pools."""
+    The arrays are whatever each paged layer DECLARES
+    (`layer.paged_pool_arrays(n_blocks, block_len, dtype)`, the paged
+    protocol of docs/SERVING.md): `kv` is a flat tuple with one entry a
+    paged layer, in layer order, each a tuple of
+    `[n_blocks, block_len, width]` arrays in the net's compute dtype —
+    (K, V) of `n_heads * head_dim` for a `TransformerEncoderBlock`, one
+    latent array for a `LatentAttentionBlock`. It is a plain pytree:
+    jitted programs take it as an argument and return the updated
+    pools."""
 
     def __init__(self, net, n_blocks: int, block_len: int):
         if block_len < 1:
@@ -343,18 +343,18 @@ class PagedKVPool:
         self.block_len = int(block_len)
         self.n_blocks = int(n_blocks)
         self.layer_indices = [i for i, l in enumerate(net.layers)
-                              if isinstance(l, TransformerEncoderBlock)]
+                              if getattr(l, "paged_cache", False)]
         if not self.layer_indices:
             raise ValueError(
-                "PagedKVPool needs at least one TransformerEncoderBlock "
-                f"layer; got {[type(l).__name__ for l in net.layers]}")
+                "PagedKVPool needs at least one layer that implements the "
+                "paged protocol (TransformerEncoderBlock, "
+                "LatentAttentionBlock); got "
+                f"{[type(l).__name__ for l in net.layers]}")
         dtype = net.dtype.compute_dtype
-        kv = []
-        for i in self.layer_indices:
-            layer = net.layers[i]
-            shape = (self.n_blocks, self.block_len, layer.n_in)
-            kv.append((jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)))
-        self.kv: Tuple = tuple(kv)
+        self.kv: Tuple = tuple(
+            tuple(net.layers[i].paged_pool_arrays(
+                self.n_blocks, self.block_len, dtype))
+            for i in self.layer_indices)
         self.allocator = BlockAllocator(self.n_blocks)
 
     @property
@@ -366,7 +366,5 @@ class PagedKVPool:
         return self.allocator.used_blocks
 
     def device_bytes(self) -> int:
-        total = 0
-        for k, v in self.kv:
-            total += k.size * k.dtype.itemsize + v.size * v.dtype.itemsize
-        return total
+        return sum(a.size * a.dtype.itemsize
+                   for arrays in self.kv for a in arrays)

@@ -233,6 +233,9 @@ class GenerationServer(ParallelInference):
                  spec_sampled: bool = False,
                  spec_draft_layers: Optional[int] = None,
                  prefix_cache: str = "registered",
+                 max_positions: Optional[int] = None,
+                 max_prefill_tokens: Optional[int] = None,
+                 min_prefill_bucket: int = 1,
                  name: Optional[str] = None,
                  slo: Optional[SLOObjective] = None):
         super().__init__(net)
@@ -254,7 +257,9 @@ class GenerationServer(ParallelInference):
             quantize=quantize, allocation=allocation,
             speculative=speculative, spec_sampled=spec_sampled,
             spec_draft_layers=spec_draft_layers,
-            prefix_cache=prefix_cache)
+            prefix_cache=prefix_cache, max_positions=max_positions,
+            max_prefill_tokens=max_prefill_tokens,
+            min_prefill_bucket=min_prefill_bucket)
         self._metrics_cache = None
         # speculative-decoding policy: drafting is only worth its
         # k-wide scoring dispatch while the proposer's tokens actually
@@ -458,9 +463,9 @@ class GenerationServer(ParallelInference):
             widths.append(w)
             w *= 2
         widths.append(eng.n_slots)
-        top_bucket = bucket_len(int(prompt_len), eng.max_total_tokens)
+        top_bucket = eng._bucket(int(prompt_len))
         buckets = []
-        b = 1
+        b = eng.min_prefill_bucket
         while b <= top_bucket:
             buckets.append(b)
             b *= 2
@@ -489,6 +494,11 @@ class GenerationServer(ParallelInference):
         try:
             for k in widths:
                 for pl in buckets:
+                    if (eng.max_prefill_tokens is not None
+                            and k * pl > eng.max_prefill_tokens):
+                        # admission is bounded in tokens: the engine
+                        # never builds this (width, bucket) program
+                        continue
                     # a bucket rounded past the prompt may leave less
                     # token headroom than requested — admission-only
                     # warmup (n=1) still compiles that bucket's
@@ -901,6 +911,24 @@ class GenerationServer(ParallelInference):
                 "/ (n_slots x max_blocks): 100 where it gathers every "
                 "slot's whole table",
                 buckets=(1, 2, 5, 10, 20, 35, 50, 75, 100), **lbl),
+            "moe_rows": reg.histogram(
+                "serving_moe_rows",
+                "(token, expert) rows routed to the experts this server "
+                "holds, a dispatch (decode or admission), mean over "
+                "its routed expert layers",
+                buckets=(1, 4, 16, 64, 256, 1024, 4096, 16384, 65536),
+                **lbl),
+            "moe_load": reg.histogram(
+                "serving_moe_load_max_over_mean",
+                "rows of the fullest held expert over the mean held "
+                "expert's, a dispatch, mean over its routed expert "
+                "layers",
+                buckets=(1, 1.25, 1.5, 2, 3, 4, 8, 16, 32), **lbl),
+            "positions_read": reg.counter(
+                "serving_latent_positions_read",
+                "cache positions the decode dispatches' attention read, "
+                "summed over the paged layers (a gathering layer reads "
+                "every slot's whole budget)", **lbl),
             "goodput_frac": reg.gauge(
                 GOODPUT_FRACTION_GAUGE,
                 "useful token-positions / dispatched token-positions "
@@ -1047,7 +1075,8 @@ class GenerationServer(ParallelInference):
             with monitor.span("serve/admit", it=it,
                               width=len(wave)) as sp:
                 admitted = self._admit(eng, m, it, wave, requests)
-                sp.set(admitted=admitted, bucket=eng.admit_bucket)
+                sp.set(admitted=admitted, bucket=eng.admit_bucket,
+                       tokens=eng.admit_tokens)
             if not admitted:
                 break
             self._dispatch_s += sp.duration_s
@@ -1055,6 +1084,7 @@ class GenerationServer(ParallelInference):
                 m["admit_waves"].inc()
                 m["admit_wave"].observe(sp.duration_s)
                 m["admit_wait"].observe(eng.wait_s)
+                self._observe_layer_counts(eng, m, decode=False)
             progressed = True
             with monitor.span("serve/sched/intake", it=it):
                 wave, requests, more = self._next_wave(eng, m)
@@ -1115,6 +1145,7 @@ class GenerationServer(ParallelInference):
                     m["decode_host"].observe(sp.duration_s - eng.wait_s)
                     m["batch_slots"].observe(n_active)
                     m["kv_read_pct"].observe(eng.kv_read_pct)
+                    self._observe_layer_counts(eng, m, decode=True)
                 if n_tok and dt > 0:
                     rate = n_tok / dt
                     self._ewma_tok_s = (rate if self._ewma_tok_s is None
@@ -1139,6 +1170,18 @@ class GenerationServer(ParallelInference):
             with monitor.span("serve/sched/gauges", it=it):
                 self._publish_gauges(eng, m)
         return progressed
+
+    @staticmethod
+    def _observe_layer_counts(eng, m, *, decode: bool):
+        """What the engine read back with the last dispatch's tokens:
+        the routed expert layers' rows and load (decode and admission
+        dispatches alike), the positions a decode dispatch's attention
+        read.  Families of a net with no such layer observe nothing."""
+        if eng.moe_stats is not None:
+            m["moe_rows"].observe(eng.moe_stats[0])
+            m["moe_load"].observe(eng.moe_stats[1])
+        if decode and eng.positions_read:
+            m["positions_read"].inc(eng.positions_read)
 
     def _intake(self, eng, m) -> bool:
         """Control requests, cancellations, and the submit queue drained

@@ -242,10 +242,8 @@ def paged_score_forward(net, plan, params, state, kv, block_tables,
             h, _ = layer.forward_at_positions(lp, ls, h, positions)
         else:
             j = entry[2]
-            k_pool, v_pool = kv[j]
-            h, k_pool, v_pool = layer.forward_paged_multi(
-                lp, h, k_pool, v_pool, block_tables, pos, n_valid)
-            kv[j] = (k_pool, v_pool)
+            h, kv[j] = layer.paged_step_multi(
+                lp, h, kv[j], block_tables, pos, n_valid)
     return tuple(kv), h                                  # [S, K, V]
 
 

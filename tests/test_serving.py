@@ -828,9 +828,15 @@ class TestGenerationServer:
             srv.stop()
         np.testing.assert_array_equal(got, ref_tokens[0])
         assert srv.engine.block_grants_total > 0
-        with pytest.raises(RuntimeError, match="before start"):
-            GenerationServer(net, n_slots=2, n_blocks=16,
-                             block_len=BL).start().warmup(3)
+        started = GenerationServer(net, n_slots=2, n_blocks=16,
+                                   block_len=BL).start()
+        try:
+            with pytest.raises(RuntimeError, match="before start"):
+                started.warmup(3)
+        finally:
+            # a scheduler left parked would write its `serve/*` spans
+            # into whatever tracer a later test of this worker enables
+            started.stop()
 
     def test_warmup_covers_budget_clamped_top_bucket(self, net):
         """A prompt that buckets to the FULL stream budget leaves no

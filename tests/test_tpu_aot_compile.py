@@ -112,6 +112,45 @@ print(json.dumps({"kernels": names, "in_place": list(eng._in_place),
 '''
 
 
+_MLA_DECODE = '''
+# sarvam-105b's decode attention at its published widths: 32 slots, 64
+# heads, a cache row of 576 padded to 640 lanes, bf16 pages of 64
+# positions, 8,704 positions a slot; then one latent block's whole
+# paged step round it (narrow feed-forward: the kernel is what is tried)
+from deeplearning4j_tpu.kernels.mla_paged_attention import (
+    KERNEL_NAME, mla_paged_decode_attention)
+from deeplearning4j_tpu.nn.layers.latent import LatentAttentionBlock
+bf = jnp.bfloat16
+shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=S)
+low = jax.jit(lambda q, pool, bt, ln: mla_paged_decode_attention(
+    q, pool, bt, ln, latent=512, scale=0.135)).lower(
+    shape((32, 64, 576), bf), shape((4352, 64, 640), bf),
+    shape((32, 136), jnp.int32), shape((32,), jnp.int32))
+names = sorted(set(re.findall(r'kernel_name = "([^"]+)"', low.as_text())))
+low.compile()
+blk = LatentAttentionBlock(
+    n_in=4096, n_heads=64, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, ffn="dense", ffn_hidden=256,
+    rope_scaling={"beta_fast": 32, "beta_slow": 1, "factor": 40, "mscale": 1,
+                  "mscale_all_dim": 1,
+                  "original_max_position_embeddings": 4096})
+params = jax.tree_util.tree_map(
+    lambda a: shape(a.shape, a.dtype),
+    jax.eval_shape(lambda: blk.init_params(jax.random.PRNGKey(0), bf)))
+pool = shape((4352, 64, 640), bf)
+step = jax.jit(lambda p, x, pool, bt, pos, live: blk.paged_step(
+    p, x, (pool,), bt, pos, live), donate_argnums=2)
+hlo = step.lower(params, shape((32, 1, 4096), bf), pool,
+                 shape((32, 136), jnp.int32), shape((32,), jnp.int32),
+                 shape((32,), jnp.bool_)).compile().as_text()
+print(json.dumps({"kernels": names, "name": KERNEL_NAME,
+                  "in_place": blk.paged_in_place((pool,)),
+                  "step_has_kernel": KERNEL_NAME in hlo,
+                  "pool_copies": len(re.findall(
+                      r"= bf16\\[4352,64,640\\]\\S* copy\\(", hlo))}))
+'''
+
+
 def _child(body, *, import_package=True):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("LIBTPU_INIT_ARGS", None)
@@ -160,6 +199,18 @@ def test_decode_step_attends_over_the_pool_in_place_on_v5e():
     assert out["in_place"] == [True, True]
     assert KERNEL_NAME in out["kernels"]
     assert out["pool_seen"] and out["pool_copies"] == 0
+
+
+def test_latent_decode_kernel_compiles_at_published_widths_on_v5e():
+    """`dl4tpu_mla_paged_decode` at sarvam-105b's widths (576 is not a
+    multiple of 128: the row is padded to 640 lanes) lowers through
+    Mosaic for a described v5e, alone and inside a latent block's paged
+    step, which takes the kernel and copies no pool."""
+    proc, out = _child(_MLA_DECODE)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["kernels"] == [out["name"]] == ["dl4tpu_mla_paged_decode"]
+    assert out["in_place"] is True and out["step_has_kernel"] is True
+    assert out["pool_copies"] == 0
 
 
 def test_256_row_prefill_compiles_with_the_packages_libtpu_stacks():
