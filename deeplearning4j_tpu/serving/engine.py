@@ -12,10 +12,13 @@ Per dispatch:
 - `decode_step(params, state, kv, block_tables, token_ids, slot_state)
   -> (kv', next_ids, done_flags)` — embedding -> per-slot positional
   signal -> paged transformer blocks -> per-position softmax, then
-  greedy argmax or per-slot sampled next token. Inputs ride h2d once
-  per step (they are a few `[S]` vectors + the `[S, max_blocks]`
-  tables); the pools stay device-resident (donated where the backend
-  supports it).
+  greedy argmax or per-slot sampled next token. The pools stay
+  device-resident (donated where the backend supports it), and so does
+  the step's carry (last token, position, tokens left, emit index):
+  each program hands it to the next, and the host uploads only what it
+  changed itself since the last launch (a slot admitted or released, a
+  row of the block tables after a grant), so the next step can be
+  launched before this one is read back (`step_ahead`).
 - admission prefills a WAVE of prompts — heterogeneous lengths
   bucket-padded to one shape (`zoo.transformer.get_prefill_bucketed`,
   per-slot last-position gather) — then scatters the filled monolithic
@@ -328,6 +331,26 @@ class PagedDecodeEngine:
         self.temp = np.zeros(S, np.float32)
         self.top_p = np.ones(S, np.float32)
         self.slots: List[Optional[Slot]] = [None] * S
+        # what the device holds of the above. The decode program hands
+        # `_carry` (last token, pos, remaining, emit index: [4, S]) to
+        # the next one; `_dev_ints` is the host's reckoning of its last
+        # three rows (written in place at each launch), and `_tok_host`
+        # marks the slots whose last token the host wrote (an admission,
+        # a speculative step) and the carry lacks. `_uploaded` keeps, by
+        # name, the host copy and the device copy of each input the
+        # program does not advance: a launch uploads one anew only where
+        # its mirror has changed (`_decode_args`). Device constants come
+        # from numpy: no program
+        self._carry = jnp.asarray(np.zeros((4, S), np.int32))
+        self._no_fresh = jnp.asarray(np.zeros((5, S), np.int32))
+        self._dev_ints = np.zeros((3, S), np.int32)
+        self._tok_host = np.zeros(S, bool)
+        self._uploaded: Dict[str, tuple] = {}
+        # the decode step launched and not read back (one, where the
+        # caller runs ahead; none after `step` or `drain`), and what has
+        # been read back but not yet returned to the caller
+        self._flight: Optional[dict] = None
+        self._ready: Optional[Tuple[Dict[int, List[int]], List[int]]] = None
         self._decode_full = None      # greedy + sampling chain
         self._decode_greedy = None    # argmax only (no sort/rng ops)
         self._admit_finish = {}       # k -> fused write-pages+first-token
@@ -403,7 +426,13 @@ class PagedDecodeEngine:
         self.admit_bucket = 0
         self.admit_tokens = 0     # prompt tokens of the last admit_many
         self.wait_s = 0.0
+        # of the decode step the last `step` / `step_ahead` / `drain`
+        # call read back: the share of the slots' tables its attention
+        # read, and whether an earlier step was still unread when it
+        # was launched; `launched`: did that call dispatch a step itself
         self.kv_read_pct = 0.0
+        self.overlapped = False
+        self.launched = False
         # positions the last decode dispatch's attention read (summed
         # over its paged layers), and what the routed expert layers of
         # the last dispatch (decode or admission) report: rows routed
@@ -665,35 +694,50 @@ class PagedDecodeEngine:
                                                 greedy_only=greedy_only),
                     _moe_means(stats))
 
-        def decode_step(params, state, kv, block_tables, token_ids,
-                        pos, remaining, keys, emit_idx, temp, top_p):
+        def decode_step(params, state, kv, block_tables, carry, fresh,
+                        keys, temp, top_p):
             """`steps_per_dispatch` micro-steps fused into ONE program
             via lax.scan: host round-trip and dispatch overhead
             amortize over J tokens x S slots (the continuous-batching
-            counterpart of `generate()`'s fused decode scan). A slot
-            finishing mid-chunk keeps decoding — into its own pages or
-            the garbage block, never another slot's — and the `valids`
-            mask tells the host which emissions are real. J=1 is the
+            counterpart of `generate()`'s fused decode scan). J=1 is the
             admit-every-token schedule the scheduler defaults to.
-            `remaining > 0` is also each micro-step's `live` mask: a
-            freed slot keeps a stale `pos`, and the in-place attention
-            kernel must read no page for it."""
+
+            `carry` [4, S] is what the previous decode program left on
+            the device: each slot's last token, position, tokens still
+            to emit and emit index. `fresh` [5, S] is what the host
+            changed since (`_fresh_rows`: a mask, then the four
+            values): a slot admitted, released or rewritten by another
+            program takes the host's values, every other slot goes on
+            from the carry, so the host need not have read the previous
+            step's tokens to launch this one.
+
+            `remaining > 0` is each micro-step's `live` mask, and only a
+            live slot advances: a slot that finishes mid-chunk, like a
+            freed one, keeps its `pos` (its writes land in its own
+            pages or the garbage block, never another slot's, and the
+            in-place attention kernel reads no page for it), so the
+            carry out equals the host's mirrors, which advance by the
+            same rule (`taken = min(J, remaining)`)."""
             params = net.dtype.cast_params(params)
+            tok, pos, rem, emit = jnp.where(fresh[0] > 0, fresh[1:], carry)
 
             def micro(carry, _):
                 kv, tok, pos, rem, emit = carry
+                live = rem > 0
                 kv, nxt, moe = one_token(params, state, kv, block_tables,
-                                         tok, pos, rem > 0, keys, emit,
+                                         tok, pos, live, keys, emit,
                                          temp, top_p)
-                return ((kv, nxt, pos + 1, rem - 1, emit + 1),
-                        (nxt, rem > 0, moe))
+                adv = live.astype(pos.dtype)
+                return ((kv, jnp.where(live, nxt, tok), pos + adv,
+                         rem - adv, emit + adv), (nxt, moe))
 
-            carry = (kv, token_ids, pos, remaining, emit_idx)
-            (kv, _, _, _, _), (toks, valids, moe) = jax.lax.scan(
-                micro, carry, None, length=J)
-            # [J, S] each; `moe` is () for a net with no routed experts
-            # (the program then has no such output), else two [J] rows
-            return kv, toks, valids, moe
+            (kv, tok, pos, rem, emit), (toks, moe) = jax.lax.scan(
+                micro, (kv, tok, pos, rem, emit), None, length=J)
+            # toks [J, S]: micro-step j's is real where `remaining > j`,
+            # which the host knows; `moe` is () for a net with no routed
+            # experts (the program then has no such output), else two
+            # [J] rows
+            return kv, toks, moe, jnp.stack([tok, pos, rem, emit])
 
         return decode_step
 
@@ -714,10 +758,7 @@ class PagedDecodeEngine:
 
         S = self.n_slots
         args = (self._params, self.net.net_state, self.pool.kv,
-                jnp.asarray(self.block_tables), jnp.asarray(self.last_token),
-                jnp.asarray(self.pos), jnp.asarray(self.remaining),
-                jnp.asarray(self.keys), jnp.asarray(self.emit_idx),
-                jnp.asarray(self.temp), jnp.asarray(self.top_p))
+                *self._decode_args())
         jaxpr = jax.make_jaxpr(self._decode_body(greedy_only=True))(*args)
         table = hlo_cost.per_op_table(jaxpr,
                                       fused_steps=self.steps_per_dispatch)
@@ -1408,6 +1449,7 @@ class PagedDecodeEngine:
         self.remaining[slot] = n_tokens - 1
         self.emit_idx[slot] = emit0 + 1
         self.last_token[slot] = first
+        self._tok_host[slot] = True
         self.keys[slot] = key
         self.temp[slot] = r.get("temperature") or 0.0
         p = r.get("top_p")
@@ -1566,6 +1608,9 @@ class PagedDecodeEngine:
         return best
 
     def _preempt(self, slot: int):
+        # the requeued continuation is the prompt and every token
+        # emitted, those of the step in flight too: read it first
+        self._settle()
         s = self.slots[slot]
         self._preempted.append({
             "slot": slot, "request_id": s.request_id,
@@ -1668,24 +1713,156 @@ class PagedDecodeEngine:
     def step(self, *, speculate: Optional[bool] = None,
              proposers: Optional[tuple] = None
              ) -> Tuple[Dict[int, List[int]], List[int]]:
-        """One continuous-batching dispatch: every active slot advances
-        up to `steps_per_dispatch` tokens — or, with `speculative=k`
-        configured (and `speculate` not overridden to False by the
-        scheduler's accept-rate policy), up to k tokens through ONE
-        k-position score dispatch (`_spec_step`). Returns ({slot:
-        [tokens emitted this dispatch]}, [slots that finished and were
-        released]). Under incremental allocation, slots whose next
-        writes cross a block boundary are granted blocks first — and
-        pool pressure preempts the lowest-progress slot into
-        `drain_preempted()` instead of deadlocking."""
-        if speculate is None:
-            speculate = self.spec_k is not None
+        """One continuous-batching dispatch, launched and read back:
+        every active slot advances up to `steps_per_dispatch` tokens —
+        or, with `speculative=k` configured (and `speculate` not
+        overridden to False by the scheduler's accept-rate policy), up
+        to k tokens through ONE k-position score dispatch
+        (`_spec_step`). Returns ({slot: [tokens emitted]}, [slots that
+        finished and were released]). Under incremental allocation,
+        slots whose next writes cross a block boundary are granted
+        blocks first — and pool pressure preempts the lowest-progress
+        slot into `drain_preempted()` instead of deadlocking.
+
+        This is `step_ahead` with nothing left in flight: the form for
+        callers that want a step's tokens from the call that launched
+        it (`while eng.active.any(): eng.step()` loses none)."""
+        return self._step(False, speculate, proposers)
+
+    def step_ahead(self, *, speculate: Optional[bool] = None,
+                   proposers: Optional[tuple] = None
+                   ) -> Tuple[Dict[int, List[int]], List[int]]:
+        """Launch the next decode step, THEN read the one launched by
+        the call before: the device runs step n+1 while the host reads
+        step n, does its bookkeeping and hands its tokens on. What the
+        next launch needs of the step in flight stays on the device
+        (`_carry`); the rest the host knows without its tokens, because
+        a request ends by length alone: positions, emit indices, block
+        grants, and which slots finish — a slot whose `remaining`
+        reaches 0 in the step just launched is released at the launch
+        (the device runs programs in order, so a later program that
+        writes its freed blocks cannot overtake the step that still
+        does), and named in `finished` by the call that returns its
+        last token.
+
+        Every token comes back exactly once, in order; the last step's
+        by a call made when no slot is active any more (it launches
+        nothing), or by `drain()`. Whatever needs the tokens on the host
+        or rewrites a slot reads the step in flight first, by itself
+        (`_spec_step`, `_preempt`, `evict`, `export_handoff`), and what
+        it read is returned by the next call here. A caller that maps
+        slots to requests calls `drain()` before it admits: a slot that
+        finished in the step in flight is free already, and its last
+        tokens would come back under the slot's next request."""
+        return self._step(True, speculate, proposers)
+
+    def drain(self) -> Tuple[Dict[int, List[int]], List[int]]:
+        """Read back whatever decode step is in flight -> everything
+        emitted and finished that no call has returned yet."""
+        self._begin_call()
+        self._settle()
+        return self._take_ready()
+
+    @property
+    def in_flight(self) -> bool:
+        """Is a decode step launched whose tokens no call has returned
+        (or one read back on the quiet, by `_settle`)?"""
+        return self._flight is not None or self._ready is not None
+
+    def _begin_call(self):
         self.wait_s = 0.0
         self.kv_read_pct = 100.0     # the K-wide score path gathers
         self.positions_read = 0
         self.moe_stats = None
+        self.overlapped = False
+        self.launched = False
+
+    def _step(self, ahead: bool, speculate, proposers):
+        if speculate is None:
+            speculate = self.spec_k is not None
+        self._begin_call()
         if speculate and self.spec_k:
-            return self._spec_step(proposers=proposers)
+            # the proposer reads each slot's history on the host
+            self._settle()
+            self.launched = True
+            self._hold(*self._spec_step(proposers=proposers))
+            return self._take_ready()
+        flight = self._launch()
+        self._settle()               # the step launched before this one
+        self._flight = flight
+        if not ahead:
+            self._settle()
+        return self._take_ready()
+
+    def _settle(self):
+        """Read the step in flight, if any, and hold what it emitted for
+        the next `step` / `step_ahead` / `drain` to return: afterwards
+        the host's mirrors, `last_token` and the slots' histories
+        included, are the whole truth again."""
+        if self._flight is not None:
+            self._collect()
+
+    def _hold(self, emitted, finished):
+        if self._ready is None:
+            self._ready = (emitted, finished)
+            return
+        held, done = self._ready
+        for slot, toks in emitted.items():
+            held.setdefault(slot, []).extend(toks)
+        done.extend(finished)
+
+    def _take_ready(self):
+        out, self._ready = self._ready, None
+        return out if out is not None else ({}, [])
+
+    def _device(self, name: str, host):
+        """The device copy of the host mirror `name`, uploaded anew
+        only if the mirror has changed since the copy was made. The
+        copy is of a snapshot, never of the mirror itself: the host
+        goes on writing its mirrors while the step that reads the
+        upload is in flight."""
+        held = self._uploaded.get(name)
+        if held is None or not np.array_equal(held[0], host):
+            snap = np.array(host)
+            held = self._uploaded[name] = (snap, jnp.asarray(snap))
+        return held[1]
+
+    def _fresh_rows(self):
+        """What the host changed since the last launch, for the decode
+        program's merge: [changed, last_token, pos, remaining,
+        emit_idx] as one [5, S] upload — or the all-zero constant when
+        the carry on the device already says it all, which is every
+        step between an admission, a grant of the host's own making and
+        a release. A slot is changed where the host wrote its token or
+        where a mirror differs from what the last launch left on the
+        device. Every writer in this file does both or leaves the slot
+        dead (a release: the token no longer matters); should a live
+        slot be changed with its token still the device's, the step in
+        flight is read first, so that `last_token` is the truth."""
+        pos, rem, emit = self._dev_ints
+        changed = (self._tok_host | (self.pos != pos)
+                   | (self.remaining != rem) | (self.emit_idx != emit))
+        if not changed.any():
+            return self._no_fresh
+        if (changed & ~self._tok_host & (self.remaining > 0)).any():
+            self._settle()
+        return jnp.asarray(np.stack(
+            [changed, self.last_token, self.pos, self.remaining,
+             self.emit_idx]).astype(np.int32, copy=False))
+
+    def _decode_args(self):
+        """The decode program's arguments after the pool."""
+        return (self._device("block_tables", self.block_tables),
+                self._carry, self._fresh_rows(),
+                self._device("keys", self.keys),
+                self._device("temp", self.temp),
+                self._device("top_p", self.top_p))
+
+    def _launch(self) -> Optional[dict]:
+        """Grant blocks, then dispatch one plain decode step over the
+        active slots and advance the host's mirrors by the program's
+        own rule; nothing is read back. -> the step's record for
+        `_collect` (None: no slot was active)."""
         it = self.loop_it
         with monitor.span("serve/decode/grow", it=it):
             if (self.allocation == "incremental" or self._prefixes
@@ -1694,7 +1871,7 @@ class PagedDecodeEngine:
                 # (shared write-window blocks) must still run
                 self._grow_block_tables()
         if not self.active.any():
-            return {}, []
+            return None
         with monitor.span("serve/decode/dispatch", it=it):
             # two static program variants: the greedy-only decode skips
             # the sampling chain (sort + threefry) — picked whenever no
@@ -1709,82 +1886,102 @@ class PagedDecodeEngine:
                     self._decode_greedy = self._build_decode(
                         greedy_only=True)
                 decode = self._decode_greedy
-            self.kv_read_pct = self._kv_read_pct()
-            kv, toks, valids, moe = decode(
+            kv_read_pct, positions_read = self._kv_read()
+            # was the step before this one still unread at the launch?
+            overlapped = self._flight is not None
+            kv, toks, moe, self._carry = decode(
                 self._params, self.net.net_state, self.pool.kv,
-                jnp.asarray(self.block_tables),
-                jnp.asarray(self.last_token),
-                jnp.asarray(self.pos), jnp.asarray(self.remaining),
-                jnp.asarray(self.keys), jnp.asarray(self.emit_idx),
-                jnp.asarray(self.temp), jnp.asarray(self.top_p))
+                *self._decode_args())
             self.pool.kv = kv
-        with monitor.span("serve/decode/wait", it=it) as sp:
-            toks = np.asarray(toks)                     # [J, S]
-            valids = np.asarray(valids)
-            self._take_moe(moe)
-        self.wait_s = sp.duration_s
-        with monitor.span("serve/decode/post", it=it):
-            return self._after_decode(toks, valids)
+            self.launched = True
+            for out in (toks, *moe):
+                out.copy_to_host_async()
+            # the host's half of the step, from what it knows already:
+            # an active slot emits `taken = min(J, remaining)` tokens
+            J = self.steps_per_dispatch
+            idx = np.flatnonzero(self.active).tolist()
+            slots = [self.slots[i] for i in idx]
+            taken = np.where(self.active,
+                             np.minimum(J, self.remaining), 0
+                             ).astype(np.int32)
+            self.pos = self.pos + taken
+            self.emit_idx = self.emit_idx + taken
+            self.remaining = self.remaining - taken
+            # ledger: the decode chunk touches J*S token-positions;
+            # emitted tokens on live lanes are useful, idle/finished
+            # lanes and the tail past each lane's budget are pad_waste
+            n_useful = int(taken.sum())
+            self.goodput.account(useful=n_useful,
+                                 pad_waste=J * self.n_slots - n_useful)
+            finished = []
+            for i, slot in zip(idx, slots):
+                slot.emitted += int(taken[i])
+                slot.pos = int(self.pos[i])
+                if self.remaining[i] <= 0:
+                    finished.append(i)
+                    self._release(i)
+            self._dev_ints[0] = self.pos
+            self._dev_ints[1] = self.remaining
+            self._dev_ints[2] = self.emit_idx
+            self._tok_host[:] = False
+            return dict(toks=toks, moe=moe, idx=idx, slots=slots,
+                        taken=taken, finished=finished,
+                        kv_read_pct=kv_read_pct,
+                        positions_read=positions_read,
+                        overlapped=overlapped)
 
-    def _kv_read_pct(self) -> float:
-        """100 x the pool blocks of K (and as many of V) the decode
-        dispatch about to launch reads in a layer, over the
+    def _collect(self):
+        """Read the step in flight back and do the bookkeeping that
+        needs its tokens; what it emitted is held for the caller
+        (`_take_ready`)."""
+        flight, self._flight = self._flight, None
+        it = self.loop_it
+        with monitor.span("serve/decode/wait", it=it) as sp:
+            toks = np.asarray(flight["toks"])               # [J, S]
+            self._take_moe(flight["moe"])
+        self.wait_s += sp.duration_s
+        with monitor.span("serve/decode/post", it=it):
+            self.kv_read_pct = flight["kv_read_pct"]
+            self.positions_read = flight["positions_read"]
+            self.overlapped = flight["overlapped"]
+            taken = flight["taken"]
+            emitted: Dict[int, List[int]] = {}
+            for i, slot in zip(flight["idx"], flight["slots"]):
+                out = emitted[i] = toks[:taken[i], i].tolist()
+                if self.slots[i] is slot:
+                    # not a slot released at the launch (and maybe
+                    # admitted to since, with a first token of its own)
+                    self.last_token[i] = out[-1]
+                if self.spec_k:
+                    slot.history.extend(out)
+            self._hold(emitted, flight["finished"])
+
+    def _kv_read(self) -> Tuple[float, int]:
+        """Of the decode dispatch about to launch: (100 x the pool
+        blocks of K (and as many of V) it reads in a layer, over the
         `steps_per_dispatch x n_slots x max_blocks` a gather of every
-        slot's whole table moves. An in-place layer reads
+        slot's whole table moves; the positions its attention reads,
+        summed over the paged layers). An in-place layer reads
         `ceil((pos+1)/block_len)` blocks for each slot whose
         `remaining > 0` at that micro-step (the program's own
         validity) and none for the others; a gathering layer reads
-        everything — mean over the layers."""
+        everything — the share is the mean over the layers."""
         whole = self.steps_per_dispatch * self.n_slots * self.max_blocks
         j = np.arange(self.steps_per_dispatch)[:, None]      # [J, 1]
-        held = -(-(self.pos[None, :] + j + 1) // self.block_len)
         live = self.remaining[None, :] > j
-        read = int(np.where(live, held, 0).sum())
+        # a slot's position at micro-step j: it advances while live
+        at = self.pos[None, :] + j
+        read = int(np.where(live, -(-(at + 1) // self.block_len), 0).sum())
         n_in_place = sum(self._in_place)
         n_layers = len(self._in_place)
         # positions, not blocks: an in-place layer reads the `pos + 1`
         # rows a live slot holds, a gathering layer the whole budget of
         # every slot
-        self.positions_read = (
-            n_in_place * int(np.where(
-                live, self.pos[None, :] + j + 1, 0).sum())
-            + (n_layers - n_in_place) * whole * self.block_len)
-        return 100.0 * (n_in_place * read
-                        + (n_layers - n_in_place) * whole) / (
-            n_layers * whole)
-
-    def _after_decode(self, toks, valids):
-        """Slot bookkeeping for one decode chunk's `[J, S]` tokens and
-        validity mask, read back to the host."""
-        taken = valids.sum(axis=0).astype(np.int32)  # [S] tokens emitted
-        act = self.active
-        # ledger: the decode chunk touched J*S token-positions; emitted
-        # tokens on live lanes are useful, idle/finished lanes and the
-        # tail past each lane's budget are pad_waste
-        n_useful = int(np.where(act, taken, 0).sum())
-        self.goodput.account(
-            useful=n_useful,
-            pad_waste=int(toks.shape[0]) * int(toks.shape[1]) - n_useful)
-        last_idx = np.clip(taken - 1, 0, None)
-        self.last_token = np.where(
-            act & (taken > 0), toks[last_idx, np.arange(toks.shape[1])],
-            self.last_token)
-        self.pos = self.pos + np.where(act, taken, 0)
-        self.emit_idx = self.emit_idx + np.where(act, taken, 0)
-        self.remaining = self.remaining - np.where(act, taken, 0)
-        emitted: Dict[int, List[int]] = {}
-        finished = []
-        for i in np.flatnonzero(act):
-            i = int(i)
-            emitted[i] = [int(t) for t in toks[valids[:, i], i]]
-            self.slots[i].emitted += int(taken[i])
-            self.slots[i].pos = int(self.pos[i])
-            if self.spec_k:
-                self.slots[i].history.extend(emitted[i])
-            if self.remaining[i] <= 0:
-                finished.append(i)
-                self._release(i)
-        return emitted, finished
+        positions = (n_in_place * int(np.where(live, at + 1, 0).sum())
+                     + (n_layers - n_in_place) * whole * self.block_len)
+        return (100.0 * (n_in_place * read
+                         + (n_layers - n_in_place) * whole)
+                / (n_layers * whole), positions)
 
     # ------------------------------------------------- speculative decode
     def _propose(self, s: int, max_draft: int) -> List[int]:
@@ -1931,7 +2128,7 @@ class PagedDecodeEngine:
                 chosen = np.asarray(chosen)
             self.pool.kv = kv
             greedy_mat = np.asarray(greedy_mat)
-        self.wait_s = sp.duration_s
+        self.wait_s += sp.duration_s
         with monitor.span("serve/decode/post", it=it):
             self.spec_dispatches_total += 1
             # ledger: the score program touched S*K token-positions; per
@@ -1983,6 +2180,7 @@ class PagedDecodeEngine:
                 self.emit_idx[s] += n
                 self.remaining[s] -= n
                 self.last_token[s] = toks[-1]
+                self._tok_host[s] = True
                 slot = self.slots[s]
                 slot.emitted += n
                 slot.pos = int(self.pos[s])
@@ -2002,6 +2200,7 @@ class PagedDecodeEngine:
         blocks immediately; the pool pages become garbage the moment
         the table row is retired (no device work — the next gather by
         a reusing sequence overwrites them via its own prefill)."""
+        self._settle()
         if self.slots[slot] is None:
             raise ValueError(f"slot {slot} is not in use")
         self._release(slot)
@@ -2030,6 +2229,7 @@ class PagedDecodeEngine:
         the slot with `evict()` once the handoff is safely delivered
         (at-least-once: a failed send keeps the slot decodable here)."""
         self._check_handoff_wire()
+        self._settle()      # the header carries `last_token`
         s = self.slots[slot]
         if s is None:
             raise ValueError(f"slot {slot} is not in use")
@@ -2144,6 +2344,7 @@ class PagedDecodeEngine:
         self.emit_idx[slot] = int(
             header.get("emit_idx", s.emit_base + s.emitted))
         self.last_token[slot] = int(header["last_token"])
+        self._tok_host[slot] = True
         self.keys[slot] = np.asarray(header.get("keys") or [0, 0],
                                      np.uint32)
         self.temp[slot] = float(header.get("temperature") or 0.0)

@@ -331,6 +331,9 @@ class GenerationServer(ParallelInference):
         # `serve/admit` and `serve/decode` spans that their timers
         # observe: the rest of `serve/loop` is the scheduler's own
         self._dispatch_s = 0.0
+        # seconds of `serve/decode` spans that launched a step and read
+        # none, until the step's own span is observed
+        self._launch_s = 0.0
         # lifecycle: draining refuses admissions while in-flight
         # streams finish (the hot-swap handoff); stopped is terminal
         self._draining = False
@@ -911,6 +914,12 @@ class GenerationServer(ParallelInference):
                 "/ (n_slots x max_blocks): 100 where it gathers every "
                 "slot's whole table",
                 buckets=(1, 2, 5, 10, 20, 35, 50, 75, 100), **lbl),
+            "overlap_pct": reg.histogram(
+                "serving_decode_overlap_pct",
+                "at each decode launch, 100 if an earlier decode step "
+                "was still unread (the device had work while the host "
+                "did its own), 0 if the loop had drained",
+                buckets=(0, 100), **lbl),
             "moe_rows": reg.histogram(
                 "serving_moe_rows",
                 "(token, expert) rows routed to the experts this server "
@@ -1007,8 +1016,9 @@ class GenerationServer(ParallelInference):
     # ---------------------------------------------------------- scheduler
     def _collect_loop(self):
         """The scheduler loop (replaces the coalescing collector):
-        admissions, one decode dispatch, stream fan-out, eviction,
-        gauges — then block on the queue only when fully idle. Each
+        admissions, one decode launch, the earlier step's readback and
+        stream fan-out, eviction, gauges (`_schedule_once`) — then
+        block on the queue only when fully idle. Each
         iteration is one `serve/loop` span whose number `it` every
         span and request-lane phase of the iteration carries."""
         eng = self.engine
@@ -1040,12 +1050,22 @@ class GenerationServer(ParallelInference):
                 self._queue_item_taken(item)
                 if item is not None:
                     self._pending.append(item)
+        # stopping: the step in flight has been computed — its tokens
+        # go out before `stop()` fails whatever is still open
+        if eng.in_flight:
+            try:
+                self._decode(eng, self._serving_metrics(), eng.loop_it,
+                             launch=False)
+            except Exception as e:  # noqa: BLE001 — as in the loop
+                self._fail_all(e)
 
     def _fail_all(self, exc: BaseException):
-        try:
-            self.engine.drain_preempted()   # notices die with their reqs
-        except Exception:  # noqa: BLE001 — engine state may be torn
-            pass
+        for settle in (self.engine.drain_preempted, self.engine.drain):
+            # notices, and the step in flight, die with their requests
+            try:
+                settle()
+            except Exception:  # noqa: BLE001 — engine state may be torn
+                pass
         for slot, (req, fut, _) in list(self._slot2req.items()):
             try:
                 self.engine.evict(slot)
@@ -1065,12 +1085,24 @@ class GenerationServer(ParallelInference):
         self._pending.clear()
 
     def _schedule_once(self, eng) -> bool:
+        """One iteration: intake, admission waves, one decode launch.
+        The decode step runs one ahead (`engine.step_ahead`): with a
+        step in flight the loop grants blocks for the next and launches
+        it, and only then reads the earlier one, does its bookkeeping
+        and fans its tokens out. Whatever rewrites a slot or hands one
+        to another request reads the step in flight first and sends
+        its tokens on (`_decode(launch=False)`): a cancellation, an
+        admission wave (which would otherwise hold tokens already
+        computed for as long as the prefill takes), and the engine by
+        itself where it preempts or drafts."""
         m = self._serving_metrics()
         it = eng.loop_it
         self._dispatch_s = 0.0
         with monitor.span("serve/sched/intake", it=it):
             progressed = self._intake(eng, m)
             wave, requests, shed = self._next_wave(eng, m)
+        if wave and eng.in_flight:
+            self._decode(eng, m, it, launch=False)
         while wave:
             with monitor.span("serve/admit", it=it,
                               width=len(wave)) as sp:
@@ -1090,86 +1122,119 @@ class GenerationServer(ParallelInference):
                 wave, requests, more = self._next_wave(eng, m)
                 shed = shed or more
         progressed = progressed or shed
-        # --------------------------------------------------- decode
-        if eng.active.any():
-            n_active = eng.active_slots
-            t0 = time.perf_counter()
-            with monitor.span("serve/decode", it=it,
-                              active=n_active) as sp:
-                emitted, finished = eng.step(
-                    speculate=self._spec_policy(),
-                    proposers=self._spec_proposers())
-            dt = time.perf_counter() - t0
-            if self.dispatch_floor_s is not None \
-                    and dt < self.dispatch_floor_s:
-                time.sleep(self.dispatch_floor_s - dt)
-                dt = self.dispatch_floor_s   # EWMA/trace see the
-                # emulated device rate, not the host-compute rate
-            with monitor.span("serve/sched/fanout", it=it):
-                # dispatch-level speculative deltas for trace
-                # attribution — read BEFORE _spec_update advances the
-                # *_seen cursors
-                d_spec_prop = (eng.spec_proposed_total
-                               - self._spec_proposed_seen)
-                d_spec_acc = (eng.spec_accepted_total
-                              - self._spec_accepted_seen)
-                self._spec_update(m)
-                now = time.monotonic()
-                # pool-pressure preemptions (incremental allocation):
-                # requeue each evicted request as a continuation at the
-                # HEAD of the admission queue — it predates everything
-                # queued, and its emitted tokens stand (the engine
-                # re-admits prompt+emitted at the same rng emit offset)
-                preempted = eng.drain_preempted()
-                if preempted:
-                    requeued = []
-                    for note in preempted:
-                        entry = self._slot2req.pop(note["slot"], None)
-                        if entry is not None:
-                            requeued.append(entry)
-                            tr = entry[0].stream.trace
-                            if tr is not None:
-                                tr.event("preempt_requeue",
-                                         emitted=int(
-                                             note.get("emitted", 0)))
-                    self._pending[:0] = requeued
-                n_tok = sum(len(ts) for ts in emitted.values())
-                if n_tok:
-                    self._dispatch_s += sp.duration_s
-                if m is not None and n_tok:
-                    m["step"].observe(dt)
-                    m["tokens"].inc(n_tok)
-                    # the same dispatch from inside: the engine's wait
-                    # span against the rest of `serve/decode`
-                    m["decode_wait"].observe(eng.wait_s)
-                    m["decode_host"].observe(sp.duration_s - eng.wait_s)
-                    m["batch_slots"].observe(n_active)
-                    m["kv_read_pct"].observe(eng.kv_read_pct)
-                    self._observe_layer_counts(eng, m, decode=True)
-                if n_tok and dt > 0:
-                    rate = n_tok / dt
-                    self._ewma_tok_s = (rate if self._ewma_tok_s is None
-                                        else 0.8 * self._ewma_tok_s
-                                        + 0.2 * rate)
-                t1 = t0 + dt
-                for slot, toks in emitted.items():
-                    stream = self._slot2req[slot][0].stream
-                    stream._emit_many(toks, now)
-                    tr = stream.trace
-                    if tr is not None:
-                        args = {"tokens": len(toks), "it": it}
-                        if d_spec_prop:
-                            args["spec_proposed"] = d_spec_prop
-                            args["spec_accepted"] = d_spec_acc
-                        tr.phase("decode", t0, t1, **args)
-                for slot in finished:
-                    req, fut, _ = self._slot2req.pop(slot)
-                    self._finish(req, m)
+        if eng.active.any() or eng.in_flight:
+            self._decode(eng, m, it, launch=True)
             progressed = True
         if m is not None:
             with monitor.span("serve/sched/gauges", it=it):
                 self._publish_gauges(eng, m)
         return progressed
+
+    def _decode(self, eng, m, it, *, launch: bool):
+        """One `serve/decode` span and the fan-out of what it read.
+        With `launch` the engine launches the next step and then reads
+        the one before (`step_ahead`; nothing to launch: it reads
+        alone); without, it only reads the step in flight.
+
+        The step's seconds (`serving_step_seconds`, its host and wait
+        parts, the rate the shedding policy reads) are a period of the
+        loop: a span that launched one step and read another, or, the
+        loop having drained, the span that launched a step (kept in
+        `_launch_s`) and the one that read it. A span that only reads
+        a step whose launch an earlier period holds (before a wave or a
+        cancellation, the last step of a batch) takes microseconds for
+        tokens that were ready: it counts the step's tokens and what
+        the step did, and no seconds."""
+        t0 = time.perf_counter()
+        with monitor.span("serve/decode", it=it,
+                          active=eng.active_slots) as sp:
+            if launch:
+                emitted, finished = eng.step_ahead(
+                    speculate=self._spec_policy(),
+                    proposers=self._spec_proposers())
+            else:
+                emitted, finished = eng.drain()
+        dt = time.perf_counter() - t0
+        if eng.launched and self.dispatch_floor_s is not None \
+                and dt < self.dispatch_floor_s:
+            time.sleep(self.dispatch_floor_s - dt)
+            dt = self.dispatch_floor_s   # EWMA/trace see the
+            # emulated device rate, not the host-compute rate
+        with monitor.span("serve/sched/fanout", it=it):
+            # dispatch-level speculative deltas for trace
+            # attribution — read BEFORE _spec_update advances the
+            # *_seen cursors
+            d_spec_prop = (eng.spec_proposed_total
+                           - self._spec_proposed_seen)
+            d_spec_acc = (eng.spec_accepted_total
+                          - self._spec_accepted_seen)
+            self._spec_update(m)
+            now = time.monotonic()
+            n_tok = sum(len(ts) for ts in emitted.values())
+            if not n_tok:
+                self._launch_s += sp.duration_s
+                self._dispatch_s += sp.duration_s
+            else:
+                timed = eng.launched or self._launch_s > 0
+                step_s, span_s = dt + self._launch_s, \
+                    sp.duration_s + self._launch_s
+                self._launch_s = 0.0
+                if timed:
+                    # an untimed span's microseconds stay the loop's own
+                    self._dispatch_s += sp.duration_s
+                if m is not None:
+                    m["tokens"].inc(n_tok)
+                    m["batch_slots"].observe(len(emitted))
+                    m["kv_read_pct"].observe(eng.kv_read_pct)
+                    m["overlap_pct"].observe(
+                        100.0 if eng.overlapped else 0.0)
+                    self._observe_layer_counts(eng, m, decode=True)
+                    if timed:
+                        m["step"].observe(step_s)
+                        # the same step from inside: the engine's wait
+                        # span against the rest of `serve/decode`
+                        m["decode_wait"].observe(eng.wait_s)
+                        m["decode_host"].observe(span_s - eng.wait_s)
+                if timed and step_s > 0:
+                    rate = n_tok / step_s
+                    self._ewma_tok_s = (rate if self._ewma_tok_s is None
+                                        else 0.8 * self._ewma_tok_s
+                                        + 0.2 * rate)
+            t1 = t0 + dt
+            for slot, toks in emitted.items():
+                stream = self._slot2req[slot][0].stream
+                stream._emit_many(toks, now)
+                tr = stream.trace
+                if tr is not None:
+                    args = {"tokens": len(toks), "it": it}
+                    if d_spec_prop:
+                        args["spec_proposed"] = d_spec_prop
+                        args["spec_accepted"] = d_spec_acc
+                    tr.phase("decode", t0, t1, **args)
+            for slot in finished:
+                req, fut, _ = self._slot2req.pop(slot)
+                self._finish(req, m)
+            # pool-pressure preemptions (incremental allocation):
+            # requeue each evicted request as a continuation at the
+            # HEAD of the admission queue — it predates everything
+            # queued, and its emitted tokens stand (the engine
+            # re-admits prompt+emitted at the same rng emit offset).
+            # After the fan-out: the engine read the step in flight
+            # before it preempted, and the victim's tokens of that
+            # step have just gone to its stream
+            preempted = eng.drain_preempted()
+            if preempted:
+                requeued = []
+                for note in preempted:
+                    entry = self._slot2req.pop(note["slot"], None)
+                    if entry is not None:
+                        requeued.append(entry)
+                        tr = entry[0].stream.trace
+                        if tr is not None:
+                            tr.event("preempt_requeue",
+                                     emitted=int(
+                                         note.get("emitted", 0)))
+                self._pending[:0] = requeued
 
     @staticmethod
     def _observe_layer_counts(eng, m, *, decode: bool):
@@ -1193,14 +1258,20 @@ class GenerationServer(ParallelInference):
         if self._drain_control(eng):
             progressed = True
         # -------------------------------------------- cancellations
-        for slot, (req, fut, _) in list(self._slot2req.items()):
-            if req.stream.cancelled:
-                eng.evict(slot)
-                del self._slot2req[slot]
-                if m is not None:
-                    m["evicted"].inc()
-                req.stream._finish()   # partial tokens, clean close
-                progressed = True
+        cancelled = [slot for slot, (req, _, _) in self._slot2req.items()
+                     if req.stream.cancelled]
+        if cancelled and eng.in_flight:
+            # the step in flight is computed: its tokens go out first
+            self._decode(eng, m, eng.loop_it, launch=False)
+        for slot in cancelled:
+            entry = self._slot2req.pop(slot, None)
+            if entry is None:
+                continue      # finished in the step just read
+            eng.evict(slot)
+            if m is not None:
+                m["evicted"].inc()
+            entry[0].stream._finish()   # partial tokens, clean close
+            progressed = True
         # cancelled while QUEUED: reap anywhere in line, not only at
         # the head — stranded entries otherwise keep counting toward
         # max_queue and the shed projection, shedding real requests

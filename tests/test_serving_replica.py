@@ -381,12 +381,18 @@ class TestRouterReplicated:
             w2.stop()
 
     def test_kill_drill_zero_dropped_streams(self, coord, net, prompts,
-                                             ref):
+                                             ref, monkeypatch):
         """Kill one of two replicas mid-flood: every accepted stream
         still finishes (migrated, greedy-bit-equal) and the set
         converges to the survivor."""
-        w1 = _worker(net, coord.address)
-        w2 = _worker(net, coord.address)
+        # under the sandbox's step floor the flood outlasts the 0.1 s
+        # before the kill (24 tokens take 0.5 s a stream); without it
+        # twelve warm streams can finish first, the sooner since the
+        # decode loop runs a step ahead (ISSUE 30), and nothing is left
+        # to migrate (as in `test_mid_stream_death_is_typed`)
+        monkeypatch.setenv("DL4J_SANDBOX_MODEL", "1")
+        w1 = _worker(net, coord.address, dispatch_floor_s=0.02)
+        w2 = _worker(net, coord.address, dispatch_floor_s=0.02)
         rset = ReplicaSet(coord.address, "m", refresh_s=0.05)
         router = FleetRouter()
         router.attach_replicas("m", rset)
@@ -413,15 +419,18 @@ class TestRouterReplicated:
             w2.stop()
 
     def test_directory_eviction_migrates_without_deadlock(
-            self, coord, net, prompts):
+            self, coord, net, prompts, monkeypatch):
         """A replica evicted from the serving DIRECTORY while its
         socket still works and streams are in flight: refresh() closes
         the client, whose failing streams migrate SYNCHRONOUSLY on the
         refreshing thread and re-enter refresh()/backends() on the same
         set — a regression to closing under the set lock wedges that
         thread (and every future submit) forever."""
-        w1 = _worker(net, coord.address)
-        w2 = _worker(net, coord.address)
+        # the step floor keeps streams in flight at the eviction (see
+        # `test_kill_drill_zero_dropped_streams`)
+        monkeypatch.setenv("DL4J_SANDBOX_MODEL", "1")
+        w1 = _worker(net, coord.address, dispatch_floor_s=0.02)
+        w2 = _worker(net, coord.address, dispatch_floor_s=0.02)
         rset = ReplicaSet(coord.address, "m", refresh_s=0.05)
         router = FleetRouter()
         router.attach_replicas("m", rset)
@@ -528,17 +537,35 @@ class TestQueuedMigration:
         reg.publish("m", net)
         reg.publish("m", net2)
         fleet = FleetServer(reg)
-        # a shape no other test in this process compiles: the
-        # incumbent's first admission wave stalls in jit compile for
-        # seconds, pinning the tail in the queue while swap() exports
-        # it — the migration is deterministic, not a race
         fleet.deploy("m", version=1, n_slots=2, n_blocks=36,
                      block_len=4)
         srv = fleet.server("m")
+        # the incumbent's scheduler is held inside its decode call, as
+        # a long compile or a long step holds it, until swap() has
+        # exported the tail: the migration is deterministic, not a
+        # race. (It used to lean on the first decode program's compile
+        # and on the readback after it; a warm compile cache shortens
+        # the one, and a loop that launches a step without reading it
+        # (ISSUE 30) no longer blocks in the other.)
+        exported = threading.Event()
+        real_step, real_export = srv.engine.step_ahead, srv.export_queued
+
+        def held_step(*a, **kw):
+            out = real_step(*a, **kw)
+            if len(srv._slot2req) == 2:      # both admitted, on v1
+                exported.wait(30)
+            return out
+
+        def export():
+            try:
+                return real_export()
+            finally:
+                exported.set()
+        srv.engine.step_ahead, srv.export_queued = held_step, export
         inflight = [srv.generate_async(p, N_TOK) for p in prompts[:2]]
         deadline = time.monotonic() + 30
         while srv.queue_depth() and time.monotonic() < deadline:
-            time.sleep(0.002)   # both admitted (compiling) on v1
+            time.sleep(0.002)   # both admitted on v1
         queued = [srv.generate_async(p, N_TOK) for p in prompts[2:5]]
         fleet.swap("m", version=2, drain_timeout=120)
         try:

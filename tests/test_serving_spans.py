@@ -4,7 +4,10 @@ Contracts (ISSUE 27, tracing):
 
 - every `serve/decode` and `serve/admit` span of the scheduler thread
   has its children, in order, not overlapping, covering it, all inside
-  one `serve/loop` with the same `it`;
+  one `serve/loop` with the same `it`; since ISSUE 30 the decode loop
+  runs one step ahead, so a `serve/decode` is the launch of a step
+  (`grow`, `dispatch`), the readback of the one before (`wait`,
+  `post`), or both in that order;
 - the timer families observed at the same boundaries partition the
   loop, and their counts are the engine's dispatches and waves;
 - under `jax.profiler.start_trace` the same spans lie on one line of
@@ -17,6 +20,7 @@ Contracts (ISSUE 27, tracing):
 """
 
 import glob
+import time
 
 import numpy as np
 import pytest
@@ -34,6 +38,12 @@ BL = 4
 N_TOK = 6
 
 DECODE_KIDS = ["grow", "dispatch", "wait", "post"]
+# the decode loop runs one step ahead: a span may launch without a step
+# to read (the loop had drained), or read without launching (before a
+# wave or a cancellation, and the last step; with `grow` where the
+# engine looked for slots to launch and found none)
+PLAIN_KIDS = (DECODE_KIDS, ["grow", "dispatch"], ["wait", "post"],
+              ["grow", "wait", "post"])
 SPEC_KIDS = ["propose", "grow", "dispatch", "wait", "post"]
 FAMILIES = ("serving_sched_host_seconds", "serving_admit_wave_seconds",
             "serving_decode_host_seconds", "serving_decode_wait_seconds")
@@ -69,21 +79,40 @@ def _monitor_off():
     monitor._STATE.tracer = monitor.GLOBAL_TRACER
 
 
-def _serve(net, prompts, *, one_by_one=False, count=None, **server_kw):
+def _serve(net, prompts, *, one_by_one=False, count=None, floor_s=None,
+           **server_kw):
     """Six requests through a two-slot server -> (streams, tokens).
-    `count`: a dict that receives how many `step` and `admit_many`
-    calls of the engine dispatched something."""
+    `floor_s`: seconds that each launch of a decode step and each
+    readback of one takes at the least, inside the engine's own
+    `serve/decode/dispatch` and `serve/decode/wait` spans.
+    `count`: a dict that receives how many decode steps the scheduler
+    read back (`step`), how many of those reads closed a period of the
+    loop (`period`: the call launched the next step too, or the call
+    before launched this one and read none; the others read a step
+    whose launch an earlier period holds, and are given no seconds),
+    and how many `admit_many` calls of the engine admitted something."""
     server_kw.setdefault("n_slots", 2)
     srv = GenerationServer(net, n_blocks=16, block_len=BL, **server_kw)
+    eng = srv.engine
+    if floor_s is not None:
+        # `_kv_read` runs inside the dispatch span, `_take_moe` inside
+        # the wait span, once a step each
+        for name in ("_kv_read", "_take_moe"):
+            def floored(*a, _real=getattr(eng, name), **kw):
+                time.sleep(floor_s)
+                return _real(*a, **kw)
+            setattr(eng, name, floored)
     if count is not None:
-        eng = srv.engine
-        for name in ("step", "admit_many"):
-            def counting(*a, _real=getattr(eng, name), _name=name, **kw):
-                out = _real(*a, **kw)
-                count[_name] = count.get(_name, 0) + bool(
-                    out[0] if _name == "step" else out)
-                return out
-            setattr(eng, name, counting)
+        _on_decode_reads(eng, lambda: count.update(
+            step=count.get("step", 0) + 1,
+            period=count.get("period", 0)
+            + bool(eng.launched or srv._launch_s > 0)))
+
+        def counting(*a, _real=eng.admit_many, **kw):
+            out = _real(*a, **kw)
+            count["admit_many"] = count.get("admit_many", 0) + bool(out)
+            return out
+        eng.admit_many = counting
     srv.start()
     try:
         if one_by_one:
@@ -97,6 +126,19 @@ def _serve(net, prompts, *, one_by_one=False, count=None, **server_kw):
     finally:
         srv.stop()
     return streams, toks
+
+
+def _on_decode_reads(eng, seen):
+    """Call `seen()` after each of the scheduler's calls into the
+    engine that came back with a decode step's tokens: `step_ahead`
+    (launch the next, read the one before) and `drain` (read alone)."""
+    for name in ("step_ahead", "drain"):
+        def reading(*a, _real=getattr(eng, name), **kw):
+            out = _real(*a, **kw)
+            if out[0]:
+                seen()
+            return out
+        setattr(eng, name, reading)
 
 
 def _spans(tracer, prefix="serve/"):
@@ -136,6 +178,12 @@ def _check_children(spans, parent, expected):
         # span and 90% over all of them; the microseconds are the same
         # where a step takes 75 ms (on the chip the span round a decode
         # dispatch and the clock round it differ by 0.01 ms: PERF.md, PR 27).
+        # Since the loop runs a step ahead (ISSUE 30) a decode span of
+        # this tiny model is a launch that returns at once and a
+        # readback of tokens that are ready, 20-500 us in all between
+        # the same microseconds: the decode cases give the launch and
+        # the readback a floor (`_serve(floor_s=)`), as a device that
+        # takes milliseconds a step gives them (3.5 ms on the chip).
         assert sum(k[2] - k[1] for k in kids) >= 0.8 * (e - s), (a, kids)
     assert covered >= 0.9 * total
     return parents
@@ -144,13 +192,21 @@ def _check_children(spans, parent, expected):
 class TestSpanTree:
     def test_decode_children(self, mon, net, prompts, ref_tokens):
         _, tracer = mon
-        _, toks = _serve(net, prompts)
+        _, toks = _serve(net, prompts, floor_s=4e-3)
         np.testing.assert_array_equal(toks, ref_tokens)
 
+        seen = []
+
         def expected(names):
-            assert names == DECODE_KIDS
+            assert names in PLAIN_KIDS
+            seen.append(names)
         parents = _check_children(_spans(tracer), "serve/decode", expected)
-        assert all(a["active"] >= 1 for _, _, _, a in parents)
+        # some spans launch one step and read the one before (how many:
+        # the waves of six requests through two slots decide, each
+        # drains the loop)
+        assert DECODE_KIDS in seen
+        assert all(a["active"] >= 1 for n, (_, _, _, a) in zip(seen, parents)
+                   if "dispatch" in n)
 
     def test_admit_children(self, mon, net, prompts):
         _, tracer = mon
@@ -183,13 +239,13 @@ class TestSpanTree:
     def test_spec_step_children(self, mon, net):
         _, tracer = mon
         prompt = np.asarray([1, 2, 3, 1, 2, 3], np.int64)
-        _serve(net, [prompt], n_slots=1, speculative=4)
+        _serve(net, [prompt], n_slots=1, speculative=4, floor_s=4e-3)
         seen = []
 
         def expected(names):
             # the scheduler's acceptance policy may turn drafting off
             # for a dispatch: that one is a plain decode step
-            assert names in (SPEC_KIDS, DECODE_KIDS)
+            assert names == SPEC_KIDS or names in PLAIN_KIDS
             seen.append(names)
         _check_children(_spans(tracer), "serve/decode", expected)
         assert SPEC_KIDS in seen
@@ -237,12 +293,15 @@ class TestTimers:
 
         def n_obs(family):
             return sum(v["count"] for v in snap[family]["values"])
-        assert count["step"] > 0 and count["admit_many"] > 0
+        assert count["admit_many"] > 0
+        # six requests through two slots: some reads launch nothing
+        # (before a wave, the last step) and are given no seconds
+        assert 0 < count["period"] < count["step"]
         for family in ("serving_decode_host_seconds",
                        "serving_decode_wait_seconds",
-                       "serving_decode_batch_slots",
                        "serving_step_seconds"):
-            assert n_obs(family) == count["step"], family
+            assert n_obs(family) == count["period"], family
+        assert n_obs("serving_decode_batch_slots") == count["step"]
         for family in ("serving_admit_wave_seconds",
                        "serving_admit_wait_seconds"):
             assert n_obs(family) == count["admit_many"], family
@@ -453,13 +512,7 @@ class TestDecodeReadsInPlace:
         srv = GenerationServer(net, n_slots=2, n_blocks=16,
                                block_len=self.BL)
         eng = srv.engine
-
-        def stepping(*a, _real=eng.step, **kw):
-            out = _real(*a, **kw)
-            if out[0]:
-                seen.append(eng.kv_read_pct)
-            return out
-        eng.step = stepping
+        _on_decode_reads(eng, lambda: seen.append(eng.kv_read_pct))
         srv.start()
         try:
             for s in [srv.generate_async(p, N_TOK) for p in prompts]:

@@ -97,9 +97,7 @@ from deeplearning4j_tpu.serving import PagedDecodeEngine
 net = lm(vocab_size=128, d_model=128, n_layers=2, n_heads=2, max_len=64)
 eng = PagedDecodeEngine(net, n_slots=4, n_blocks=16, block_len=16)
 args = (tree(eng._params), tree(net.net_state), tree(eng.pool.kv)) + tuple(
-    sds(a) for a in (eng.block_tables, eng.last_token, eng.pos,
-                     eng.remaining, eng.keys, eng.emit_idx, eng.temp,
-                     eng.top_p))
+    sds(a) for a in eng._decode_args())
 low = jax.jit(eng._decode_body(greedy_only=True),
               donate_argnums=2).lower(*args)
 names = sorted(set(re.findall(r'kernel_name = "([^"]+)"', low.as_text())))
