@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-
 def check_gradients_fn(
     loss_fn: Callable[[Dict], jnp.ndarray],
     params: Dict,
@@ -96,22 +95,25 @@ def check_model_gradients(
     labels_mask=None,
     seed: int = 0,
 ):
-    """Gradient-check a MultiLayerNetwork on one minibatch (reference
-    `GradientCheckUtil.checkGradients(mln, ...)`).
+    """Gradient-check a container on one minibatch (reference
+    `GradientCheckUtil.checkGradients(mln, ...)` and its graph
+    overload): features / labels / masks are what its `_loss_fn` takes
+    — one array each, or one sequence entry per graph input / output.
 
     Dropout must be disabled in the config (the reference asserts this
     too — stochastic forward breaks finite differences)."""
-    for layer in model.layers:
+    for _, layer in model._keyed_layers():
         d = layer.dropout
         if d is not None and (not isinstance(d, (int, float)) or d < 1.0):
             raise ValueError("Gradient checks require dropout disabled "
                              "(reference GradientCheckUtil precondition)")
     if not model._initialized:
         model.init()
-    x = np.asarray(features, dtype=np.float64)
-    y = np.asarray(labels, dtype=np.float64)
-    fm = None if features_mask is None else jnp.asarray(np.asarray(features_mask))
-    lm = None if labels_mask is None else jnp.asarray(np.asarray(labels_mask))
+    tmap = jax.tree_util.tree_map
+    x, y = tmap(lambda a: np.asarray(a, dtype=np.float64),
+                (model._as_io(features), model._as_io(labels)))
+    fm, lm = tmap(lambda m: jnp.asarray(np.asarray(m)),
+                  (features_mask, labels_mask))
 
     from deeplearning4j_tpu.nd.dtype import DataTypePolicy
 
@@ -119,11 +121,11 @@ def check_model_gradients(
     model.dtype = DataTypePolicy(param_dtype=jnp.float64, compute_dtype=jnp.float64,
                                  output_dtype=jnp.float64)
     saved_state = model.net_state
-    model.net_state = jax.tree_util.tree_map(
+    model.net_state = tmap(
         lambda a: np.asarray(a, dtype=np.float64), model.net_state)
 
     def loss_fn(p):
-        loss, _ = model._loss_fn(p, model.net_state, jnp.asarray(x), jnp.asarray(y),
+        loss, _ = model._loss_fn(p, model.net_state, *tmap(jnp.asarray, (x, y)),
                                  None, fm, lm, train=False)
         return loss
 
@@ -145,46 +147,8 @@ def check_graph_gradients(
     max_params_per_array: int = 32,
     seed: int = 0,
 ):
-    """Gradient-check a ComputationGraph on one minibatch (reference
-    `GradientCheckUtil.checkGradients(graph, ...)` overload)."""
-    if not isinstance(inputs, (list, tuple)):
-        inputs = [inputs]
-    if not isinstance(labels, (list, tuple)):
-        labels = [labels]
-    for name, node in model.conf.nodes.items():
-        layer = getattr(node, "layer", None)
-        if layer is None:
-            continue
-        d = layer.dropout
-        if d is not None and (not isinstance(d, (int, float)) or d < 1.0):
-            raise ValueError("Gradient checks require dropout disabled")
-    if not model._initialized:
-        model.init()
-    xs = [np.asarray(x, dtype=np.float64) for x in inputs]
-    ys = [np.asarray(y, dtype=np.float64) for y in labels]
-
-    from deeplearning4j_tpu.nd.dtype import DataTypePolicy
-
-    saved_policy = model.dtype
-    model.dtype = DataTypePolicy(param_dtype=jnp.float64,
-                                 compute_dtype=jnp.float64,
-                                 output_dtype=jnp.float64)
-    saved_state = model.net_state
-    model.net_state = jax.tree_util.tree_map(
-        lambda a: np.asarray(a, dtype=np.float64), model.net_state)
-
-    def loss_fn(p):
-        loss, _ = model._loss_fn(p, model.net_state,
-                                 [jnp.asarray(x) for x in xs],
-                                 [jnp.asarray(y) for y in ys],
-                                 None, None, None, train=False)
-        return loss
-
-    try:
-        return check_gradients_fn(loss_fn, model.params, epsilon=epsilon,
-                                  max_rel_error=max_rel_error,
-                                  max_params_per_array=max_params_per_array,
-                                  seed=seed)
-    finally:
-        model.dtype = saved_policy
-        model.net_state = saved_state
+    """`check_model_gradients` under the name and argument order of the
+    reference's `checkGradients(graph, ...)` overload."""
+    return check_model_gradients(model, inputs, labels, epsilon,
+                                 max_rel_error, max_params_per_array,
+                                 seed=seed)

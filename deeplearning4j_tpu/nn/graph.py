@@ -15,15 +15,13 @@ from __future__ import annotations
 
 import dataclasses
 import json
-import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from deeplearning4j_tpu.common.updaters import Sgd
-from deeplearning4j_tpu.nd.dtype import DataTypePolicy, resolve_policy
+from deeplearning4j_tpu.nd.dtype import DataTypePolicy
 from deeplearning4j_tpu.nn.conf.builder import (
     CONFIG_FORMAT_VERSION,
     check_format_version,
@@ -38,15 +36,11 @@ from deeplearning4j_tpu.nn import scan_stack
 from deeplearning4j_tpu.nn.layers.base import Layer, layer_from_dict
 from deeplearning4j_tpu.nn.layers.feedforward import BaseOutputLayerMixin
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer
-from deeplearning4j_tpu.optimize.gradients import (
-    apply_gradient_normalization,
-    apply_max_norm_constraint,
-)
-from deeplearning4j_tpu.optimize.listeners import ComposedListeners
+from deeplearning4j_tpu.nn.trainable import TrainableNetwork, _convert_features
+from deeplearning4j_tpu.datasets.iterator import ListDataSetIterator, as_iterator
+from deeplearning4j_tpu.datasets.multidataset import MultiDataSet
 from deeplearning4j_tpu import monitor
 
-
-from deeplearning4j_tpu.nd.donation import donate_argnums as _donate
 
 
 @dataclasses.dataclass
@@ -321,71 +315,23 @@ class GraphBuilder:
         return conf
 
 
-class ComputationGraph:
+class ComputationGraph(TrainableNetwork):
     def __init__(self, conf: ComputationGraphConfiguration,
                  dtype_policy: DataTypePolicy = None, diagnostics=None):
-        self.conf = conf
-        # DL4J_DTYPE_POLICY env > explicit arg > conf.dtype_policy >
-        # process default (nd/dtype.py)
-        self.dtype = resolve_policy(dtype_policy, conf)
-        # in-graph model-internals diagnostics (monitor/diagnostics.py):
-        # DL4J_DIAGNOSTICS env > explicit arg > conf.diagnostics > off
-        self.diagnostics = monitor.resolve_diagnostics(diagnostics, conf)
-        self._diag = (monitor.Diagnostics(self.diagnostics)
-                      if self.diagnostics is not None else None)
-        self._last_diagnostics = None
-        self._last_group_dv = None
-        self.params: Dict[str, Dict[str, jnp.ndarray]] = {}
-        self.net_state: Dict[str, Dict[str, jnp.ndarray]] = {}
-        self.updater_state: Dict[str, Dict[str, Any]] = {}
-        self.iteration_count = 0
-        self.epoch_count = 0
-        self.listeners: List = []
-        self.score_value = float("nan")
-        self._initialized = False
-        self._jit_train_step = None
-        self._jit_tbptt_step = None
-        self._jit_multi_step = None
-        self._jit_output = None
-        self._jit_rnn_step = None
-        self._solver = None
-        self._ambient_seq_ctx = None
-        self._uses_seq_parallel = any(
-            getattr(n.layer, "sequence_parallel", None)
-            for n in conf.nodes.values() if n.layer is not None)
+        super().__init__(conf, dtype_policy, diagnostics)
         # scan-over-layers chain plan (nn/scan_stack.py), built lazily
         # from traced shapes: {head: [members]}, skip set, fold indices
         self._chain_plan = None
-        self._packed_runs_cache = None
-        self._rnn_carries: Dict[str, Any] = {}
-        self._rnn_stream_pos = 0  # host-side stream-budget tracker
         self.output_layer_names = [
             n for n in conf.network_outputs
             if conf.nodes[n].kind == "layer"
             and isinstance(conf.nodes[n].layer, BaseOutputLayerMixin)
         ]
 
-    def _sync_ambient_context(self):
-        """See `MultiLayerNetwork._sync_ambient_context` — drop cached
-        jitted programs when the ambient sequence-parallel (mesh, axis)
-        changes, so trace-time schedule selection stays current."""
-        if not self._uses_seq_parallel:
-            return
-        from deeplearning4j_tpu.parallel.context import current_sequence_mesh
-        ctx = current_sequence_mesh()
-        if ctx == self._ambient_seq_ctx:
-            return
-        self._ambient_seq_ctx = ctx
-        self._jit_train_step = None
-        self._jit_tbptt_step = None
-        self._jit_multi_step = None
-        self._jit_output = None
-        self._jit_rnn_step = None
-        self._solver = None
-
     # ------------------------------------------------------------------ init
     def _init_trees(self, seed: int):
-        """Pure init (see MultiLayerNetwork._init_trees)."""
+        """Pure init: build (params, net_state, updater_state) without
+        touching self — also usable under `jax.eval_shape`."""
         root = jax.random.PRNGKey(seed)
         pdt = self.dtype.param_dtype
         params, state, upd = {}, {}, {}
@@ -404,22 +350,42 @@ class ComputationGraph:
                 state[name] = s
         return params, state, upd
 
-    def init(self, seed: Optional[int] = None) -> "ComputationGraph":
-        seed = self.conf.seed if seed is None else seed
-        (self.params, self.net_state, self.updater_state) = \
-            self._init_trees(seed)
-        from deeplearning4j_tpu.nn.multilayer import validate_param_widths
-        validate_param_widths(self.params)
-        self._initialized = True
-        return self
+    # ------------------------------------------------ the shared code's answers
+    def _layer(self, lk: str):
+        return self.conf.nodes[lk].layer
 
-    def set_listeners(self, *listeners):
-        self.listeners = list(listeners)
-        return self
+    def _keyed_layers(self):
+        return [(n, node.layer) for n, node in self.conf.nodes.items()
+                if node.kind == "layer"]
 
-    def add_listener(self, listener):
-        self.listeners.append(listener)
-        return self
+    def _scan_runs(self, params):
+        return list(self._chains(params)[0].values())
+
+    def _as_io(self, v):
+        return tuple(v) if isinstance(v, (list, tuple)) else (v,)
+
+    def _step_batch(self, ds, data_format=None):
+        """One tuple entry per graph input / output."""
+        def arrays(vals, n=None):
+            vals = vals if vals is not None else [None] * n
+            return tuple(None if v is None else jnp.asarray(v) for v in vals)
+
+        if isinstance(ds, MultiDataSet):
+            xs, ys = arrays(ds.features), arrays(ds.labels)
+            fmasks = arrays(ds.features_masks, len(xs))
+            lmasks = arrays(ds.labels_masks, len(ys))
+        else:
+            xs, ys = arrays([ds.features]), arrays([ds.labels])
+            fmasks = arrays([ds.features_mask])
+            lmasks = arrays([ds.labels_mask])
+        xs = tuple(_convert_features(x, data_format) for x in xs)
+        return xs, ys, fmasks, lmasks, ds.num_examples()
+
+    def _predict(self, ds, data_format=None):
+        masks = (None if ds.features_mask is None
+                 else [jnp.asarray(ds.features_mask)])
+        return self.output(_convert_features(ds.features, data_format),
+                           masks=masks)
 
     # --------------------------------------------------------------- forward
     def _input_feeds_ids(self, input_name: str) -> bool:
@@ -584,7 +550,7 @@ class ComputationGraph:
             h, mask, lrng = preouts[name]
             # losses / softmax statistics stay fp32 under a mixed
             # policy (activations, labels and output-layer params all
-            # upcast to output_dtype; see MultiLayerNetwork._loss_fn)
+            # upcast to output_dtype)
             h = self.dtype.cast_output(h)
             y = self.dtype.cast_output(jnp.asarray(labels[oi]))
             lparams = self.dtype.cast_output_params(
@@ -614,483 +580,17 @@ class ComputationGraph:
             return total, (new_state, out_carries, stats_out)
         return total, (new_state, out_carries)
 
-    # ------------------------------------------------------------ train step
-    def _packed_runs(self, params):
-        """Chains packed at the train-step boundary — see
-        `MultiLayerNetwork._packed_runs` (nn/scan_stack.py)."""
-        runs = self._packed_runs_cache
-        if runs is None:
-            chains, _, _ = self._chains(params)
-            rwt = [(members, self.conf.nodes[members[0]].layer)
-                   for members in chains.values()]
-            runs = scan_stack.packable_runs(self.conf, rwt)
-            self._packed_runs_cache = runs
-        return runs
-
-    def _fused_state_runs(self, runs):
-        """Fused-Adam packed chains whose m/v ride the step programs
-        pre-flattened — see MultiLayerNetwork._fused_state_runs."""
-        from deeplearning4j_tpu.kernels import fused_adam as fa
-        return [scan_stack.run_key(keys) for keys in runs
-                if fa.fused_adam_eligible(
-                    self.conf.nodes[keys[0]].layer.updater or Sgd(1e-3))]
-
-    def _apply_updates(self, params, grads, upd_state, step):
-        from deeplearning4j_tpu.kernels import fused_adam as fa
-        new_params, new_upd = {}, {}
-        for lk, lgrads in grads.items():
-            if scan_stack.is_run_key(lk):
-                # stacked run entry — elementwise updater covers the
-                # whole run (packable_runs guarantees no constraints)
-                layer = self.conf.nodes[scan_stack.run_members(lk)[0]].layer
-            else:
-                layer = self.conf.nodes[lk].layer
-            updater = layer.updater or Sgd(1e-3)
-            if (scan_stack.is_run_key(lk)
-                    and fa.fused_adam_eligible(updater)):
-                # Pallas fast path — one kernel per packed run (see
-                # MultiLayerNetwork._apply_updates)
-                lp, lu = fa.adam_update_packed(
-                    updater, params[lk], lgrads, upd_state[lk], step)
-                new_params[lk] = lp
-                new_upd[lk] = lu
-                continue
-            lp, lu = {}, {}
-            for pk, g in lgrads.items():
-                # bf16 grads (mixed policy) meet the fp32 master here
-                g = g.astype(params[lk][pk].dtype)
-                delta, new_s = updater.apply(g, upd_state[lk][pk], step)
-                lp[pk] = params[lk][pk] - delta.astype(params[lk][pk].dtype)
-                lu[pk] = new_s
-            new_params[lk] = (lp if scan_stack.is_run_key(lk)
-                              else layer.apply_constraints(lp))
-            new_upd[lk] = lu
-        if self.conf.max_norm is not None:
-            new_params = apply_max_norm_constraint(new_params, self.conf.max_norm)
-        return new_params, new_upd
-
-    def _make_train_step(self, tbptt: bool = False):
-        gn = self.conf.gradient_normalization
-        gn_t = self.conf.gradient_normalization_threshold
-        diag = self._diag
-        want_acts = diag is not None and diag.config.activation_stats
-
-        def step_fn(params, upd_state, state, it, xs, ys, rng, fmasks, lmasks,
-                    carries=None):
-            # boundary packing — see MultiLayerNetwork._make_train_step
-            runs = ([] if tbptt or not scan_stack.scan_enabled(self.conf)
-                    else self._packed_runs(params))
-            fused_runs = []
-            if runs:
-                from deeplearning4j_tpu.kernels import fused_adam as fa
-                fused_runs = self._fused_state_runs(runs)
-                params, upd_state = fa.pack_run_trees(
-                    params, upd_state, runs, fused_runs)
-
-            def lf(p):
-                if tbptt and carries is not None:
-                    stopped = jax.tree_util.tree_map(jax.lax.stop_gradient, carries)
-                else:
-                    stopped = carries
-                return self._loss_fn(p, state, xs, ys, rng, fmasks, lmasks,
-                                     train=True, carries=stopped,
-                                     act_stats=want_acts)
-
-            # cast outside value_and_grad: bf16 grads under mixed_bf16,
-            # fp32 master update below (see MultiLayerNetwork)
-            (loss, aux), grads = jax.value_and_grad(
-                lf, has_aux=True)(self.dtype.cast_params(params))
-            if want_acts:
-                new_state, new_carries, acts = aux
-            else:
-                (new_state, new_carries), acts = aux, None
-            grads = apply_gradient_normalization(grads, gn, gn_t)
-            new_params, new_upd = self._apply_updates(params, grads, upd_state, it)
-            new_params, new_upd, new_state, dv = \
-                monitor.diagnostics.collect_and_gate(
-                    diag, "fit", params_old=params, params_new=new_params,
-                    upd_old=upd_state, upd_new=new_upd, state_old=state,
-                    state_new=new_state, grads=grads, loss=loss, acts=acts)
-            if runs:
-                from deeplearning4j_tpu.kernels import fused_adam as fa
-                new_params, new_upd = fa.unpack_run_trees(
-                    new_params, new_upd, runs, fused_runs)
-            return new_params, new_upd, new_state, loss, new_carries, dv
-
-        return jax.jit(step_fn, donate_argnums=_donate(0, 1, 2))
-
-    def _multi_step_fn(self):
-        """Unjitted k-fused-steps function — see
-        `MultiLayerNetwork._multi_step_fn` (same carry-structure rule:
-        only state keys present at init are carried across steps)."""
-        gn = self.conf.gradient_normalization
-        gn_t = self.conf.gradient_normalization_threshold
-        diag = self._diag
-        want_acts = diag is not None and diag.config.activation_stats
-
-        def one(carry, inp):
-            params, upd, state, it = carry
-            xs, ys, rng = inp
-
-            def lf(p):
-                return self._loss_fn(p, state, xs, ys, rng, None, None,
-                                     train=True, act_stats=want_acts)
-
-            (loss, aux), grads = jax.value_and_grad(
-                lf, has_aux=True)(self.dtype.cast_params(params))
-            if want_acts:
-                new_state, _, acts = aux
-            else:
-                (new_state, _), acts = aux, None
-            grads = apply_gradient_normalization(grads, gn, gn_t)
-            new_params, new_upd = self._apply_updates(params, grads, upd, it)
-            new_params, new_upd, new_state, dv = \
-                monitor.diagnostics.collect_and_gate(
-                    diag, "fit", params_old=params, params_new=new_params,
-                    upd_old=upd, upd_new=new_upd, state_old=state,
-                    state_new=new_state, grads=grads, loss=loss, acts=acts)
-            state = {k: new_state.get(k, v) for k, v in state.items()}
-            return (new_params, new_upd, state, it + 1), (loss, dv)
-
-        def multi(params, upd, state, it0, xs_stack, ys_stack, rngs):
-            # homogeneous chains ride the k-step scan carry stacked —
-            # packed/unpacked once per PROGRAM (see scan_stack); fused-
-            # Adam chains carry m/v pre-flattened (kernels/fused_adam)
-            runs = (self._packed_runs(params)
-                    if scan_stack.scan_enabled(self.conf) else [])
-            fused_runs = []
-            if runs:
-                from deeplearning4j_tpu.kernels import fused_adam as fa
-                fused_runs = self._fused_state_runs(runs)
-                params, upd = fa.pack_run_trees(params, upd, runs,
-                                                fused_runs)
-            (params, upd, state, _), (losses, dvs) = jax.lax.scan(
-                one, (params, upd, state, jnp.asarray(it0, jnp.int32)),
-                (xs_stack, ys_stack, rngs))
-            if runs:
-                from deeplearning4j_tpu.kernels import fused_adam as fa
-                params, upd = fa.unpack_run_trees(params, upd, runs,
-                                                  fused_runs)
-            return params, upd, state, losses, dvs
-
-        return multi
-
-    def _make_multi_step(self):
-        """k fused train steps in one `lax.scan` dispatch — same design
-        (and numerics contract) as MultiLayerNetwork._make_multi_step;
-        the DAG container shares the dispatch-amortization lever."""
-        return jax.jit(self._multi_step_fn(), donate_argnums=_donate(0, 1, 2))
-
-    def _run_multi_step(self, xs_stack, ys_stack, it0):
-        """xs_stack/ys_stack: tuples of [k, B, ...] arrays (one per
-        graph input/output). Returns per-step losses."""
-        if self._jit_multi_step is None:
-            self._jit_multi_step = self._make_multi_step()
-        rng_root = jax.random.PRNGKey(self.conf.seed + 1)
-        k = xs_stack[0].shape[0]
-        its = jnp.arange(it0, it0 + k)
-        rngs = jax.vmap(lambda i: jax.random.fold_in(rng_root, i))(its)
-        (self.params, self.updater_state, self.net_state, losses, dvs) = \
-            self._jit_multi_step(self.params, self.updater_state,
-                                 self.net_state, it0, xs_stack, ys_stack,
-                                 rngs)
-        # stacked per-step diag vectors ({} with diagnostics off) — read
-        # by the fit loop at listener cadence, NOT here (no sync)
-        self._last_group_dv = dvs
-        return losses
-
-    # ------------------------------------------------- AOT observability
-    def _train_step_avals(self, xs, ys, steps: int):
-        """Stacked input avals (tuples — one entry per graph input /
-        output). Accepts single arrays, sequences of arrays, or
-        ShapeDtypeStructs; only shapes/dtypes are read."""
-        def tup(v):
-            return tuple(v) if isinstance(v, (list, tuple)) else (v,)
-
-        def sds(a):
-            return jax.ShapeDtypeStruct((steps,) + tuple(a.shape),
-                                        jnp.dtype(a.dtype))
-        key = jax.random.PRNGKey(0)
-        rngs = jax.ShapeDtypeStruct((steps,) + tuple(key.shape), key.dtype)
-        return (tuple(sds(a) for a in tup(xs)),
-                tuple(sds(a) for a in tup(ys)), rngs)
-
-    def lower_train_step(self, xs, ys, *, steps: int = 1, it0: int = 0):
-        """AOT-lower the exact fused train-step — same contract as
-        `MultiLayerNetwork.lower_train_step` (device-free
-        `.cost_analysis()`; `.compile()` is the fit-loop executable;
-        pass a plain Python int for `it0` when calling it)."""
-        if not self._initialized:
-            self.init()
-        if self._jit_multi_step is None:
-            self._jit_multi_step = self._make_multi_step()
-        xs_a, ys_a, rngs = self._train_step_avals(xs, ys, steps)
-        return self._jit_multi_step.lower(
-            self.params, self.updater_state, self.net_state, it0,
-            xs_a, ys_a, rngs)
-
-    def train_step_jaxpr(self, xs, ys, *, steps: int = 1):
-        """ClosedJaxpr of the same fused train-step (per-op cost
-        tables — `benchtools/hlo_cost.py`)."""
-        if not self._initialized:
-            self.init()
-        xs_a, ys_a, rngs = self._train_step_avals(xs, ys, steps)
-        return jax.make_jaxpr(self._multi_step_fn())(
-            self.params, self.updater_state, self.net_state, 0,
-            xs_a, ys_a, rngs)
-
     # ------------------------------------------------------------------- fit
     def fit(self, data, labels=None, *, epochs: int = 1, batch_size: int = 32,
             steps_per_execution: int = 1):
         """Train. `data`: DataSetIterator / DataSet / MultiDataSet /
-        (features, labels) arrays. `steps_per_execution > 1` fuses that
-        many unmasked minibatch steps into one scan dispatch (see
-        MultiLayerNetwork.fit)."""
-        from deeplearning4j_tpu.datasets.iterator import as_iterator
-        from deeplearning4j_tpu.datasets.multidataset import MultiDataSet
-
-        if not self._initialized:
-            self.init()
-        self._sync_ambient_context()
-        if isinstance(data, MultiDataSet):
-            batches = [data]
-        else:
-            batches = None
-        tbptt = self.conf.backprop_type == BackpropType.TRUNCATED_BPTT
-        if self._jit_train_step is None:
-            self._jit_train_step = self._make_train_step()
-        if tbptt and self._jit_tbptt_step is None:
-            self._jit_tbptt_step = self._make_train_step(tbptt=True)
-        solver = None
-        if getattr(self.conf, "optimization_algo", "sgd") != "sgd":
-            if tbptt:
-                raise ValueError(
-                    "optimization_algo=%r cannot be combined with truncated "
-                    "BPTT: the line-search solvers optimize the full-sequence "
-                    "loss and would ignore tbptt_fwd_length. Use SGD, or "
-                    "standard backprop_type." % self.conf.optimization_algo)
-            if self._solver is None:
-                from deeplearning4j_tpu.optimize.solvers import Solver
-                self._solver = Solver(self, self.conf.optimization_algo,
-                                      max_iterations=self.conf.max_iterations)
-            solver = self._solver
-        listeners = ComposedListeners(self.listeners
-                                      + monitor.extra_listeners())
-        rng_root = jax.random.PRNGKey(self.conf.seed + 1)
-        if batches is not None:
-            iterator = batches
-            timed_it = None
-        else:
-            from deeplearning4j_tpu.datasets.iterator import (
-                TimedDataSetIterator)
-            iterator = timed_it = TimedDataSetIterator(
-                as_iterator(data, labels, batch_size=batch_size))
-        spe = max(1, int(steps_per_execution))
-        fused_ok = spe > 1 and solver is None and not tbptt
-
-        def flush(pending, etl_ms=0.0):
-            if not pending:
-                return
-            if len(pending) == 1:
-                xs, ys, n_examples = pending[0]
-                run_one(xs, ys, (None,) * len(xs), (None,) * len(ys),
-                        n_examples, etl_ms)
-                return
-            with monitor.span("fit/forward_backward",
-                              iteration=self.iteration_count,
-                              fused_steps=len(pending)):
-                xs_stack = tuple(jnp.stack([p[0][i] for p in pending])
-                                 for i in range(len(pending[0][0])))
-                ys_stack = tuple(jnp.stack([p[1][i] for p in pending])
-                                 for i in range(len(pending[0][1])))
-                losses = np.asarray(self._run_multi_step(xs_stack, ys_stack,
-                                                         self.iteration_count))
-            with monitor.span("fit/update", fused_steps=len(pending)):
-                group_stats = None
-                dvs = self._last_group_dv
-                if (self._diag is not None and dvs
-                        and any(self._diag.due(self.iteration_count + j)
-                                for j in range(len(pending)))):
-                    # ONE batched transfer for the whole fused group
-                    group_stats = self._diag.process(
-                        self, dvs, "fit", self.iteration_count)
-                for j, (_, _, n_examples) in enumerate(pending):
-                    self.score_value = float(losses[j])
-                    dstats = (group_stats[j] if group_stats is not None
-                              and self._diag.due(self.iteration_count)
-                              else None)
-                    listeners.iteration_done(self, self.iteration_count,
-                                             self.epoch_count, self.score_value,
-                                             batch_size=n_examples,
-                                             # ETL attribution matches the
-                                             # MultiLayerNetwork fused path:
-                                             # flush-time ETL charged to the
-                                             # first fused iteration
-                                             etl_ms=etl_ms if j == 0 else 0.0,
-                                             # only the group's LAST callback
-                                             # sees params consistent with the
-                                             # iteration count (checkpointable)
-                                             step_boundary=(
-                                                 j == len(pending) - 1),
-                                             diagnostics=dstats)
-                    self.iteration_count += 1
-
-        def run_one(xs, ys, fmasks, lmasks, n_examples, etl_ms=0.0):
-            rng = jax.random.fold_in(rng_root, self.iteration_count)
-            dv = None
-            with monitor.span("fit/forward_backward",
-                              iteration=self.iteration_count):
-                if solver is not None:
-                    loss = solver.optimize(list(xs), list(ys), list(fmasks),
-                                           list(lmasks))
-                elif tbptt and any(x.ndim == 3 for x in xs):
-                    loss, dv = self._fit_tbptt(xs, ys, fmasks, lmasks, rng)
-                else:
-                    (self.params, self.updater_state, new_state, loss, _,
-                     dv) = \
-                        self._jit_train_step(
-                            self.params, self.updater_state, self.net_state,
-                            self.iteration_count, xs, ys, rng, fmasks, lmasks)
-                    self.net_state = {**self.net_state, **new_state}
-            with monitor.span("fit/update", iteration=self.iteration_count):
-                self.score_value = float(loss)
-                dstats = None
-                if (self._diag is not None and dv
-                        and self._diag.due(self.iteration_count)):
-                    dstats = self._diag.process(
-                        self, dv, "fit", self.iteration_count)[-1]
-                listeners.iteration_done(self, self.iteration_count,
-                                         self.epoch_count, self.score_value,
-                                         batch_size=n_examples, etl_ms=etl_ms,
-                                         diagnostics=dstats)
-            self.iteration_count += 1
-
-        mon_on = monitor.is_enabled()
-        listeners.on_fit_start(self)
-        for _ in range(epochs):
-            listeners.on_epoch_start(self, self.epoch_count)
-            if hasattr(iterator, "reset"):
-                iterator.reset()
-            pending = []
-            for ds in iterator:
-                etl_ms = timed_it.last_etl_ms if timed_it is not None else 0.0
-                if mon_on and timed_it is not None:
-                    t1 = time.perf_counter()
-                    monitor.tracer().complete_between(
-                        "fit/etl", t1 - etl_ms / 1e3, t1,
-                        iteration=self.iteration_count)
-                if isinstance(ds, MultiDataSet):
-                    xs = tuple(jnp.asarray(f) for f in ds.features)
-                    ys = tuple(jnp.asarray(l) for l in ds.labels)
-                    fmasks = tuple(None if m is None else jnp.asarray(m)
-                                   for m in (ds.features_masks or [None] * len(xs)))
-                    lmasks = tuple(None if m is None else jnp.asarray(m)
-                                   for m in (ds.labels_masks or [None] * len(ys)))
-                    n_examples = int(np.shape(ds.features[0])[0])
-                else:
-                    xs = (jnp.asarray(ds.features),)
-                    ys = (jnp.asarray(ds.labels),)
-                    fmasks = (None if ds.features_mask is None else jnp.asarray(ds.features_mask),)
-                    lmasks = (None if ds.labels_mask is None else jnp.asarray(ds.labels_mask),)
-                    n_examples = ds.num_examples()
-                masked = (any(m is not None for m in fmasks)
-                          or any(m is not None for m in lmasks))
-                if not fused_ok or masked:
-                    flush(pending)
-                    pending = []
-                    run_one(xs, ys, fmasks, lmasks, n_examples, etl_ms)
-                else:
-                    if pending and any(
-                            a.shape != b.shape
-                            for a, b in zip(pending[0][0] + pending[0][1],
-                                            xs + ys)):
-                        flush(pending)
-                        pending = []
-                    pending.append((xs, ys, n_examples))
-                    if len(pending) == spe:
-                        flush(pending, etl_ms)
-                        pending = []
-            flush(pending)
-            listeners.on_epoch_end(self, self.epoch_count)
-            self.epoch_count += 1
-        listeners.on_fit_end(self)
-        return self
-
-    def _recurrent_nodes(self):
-        return [(n, node.layer) for n, node in self.conf.nodes.items()
-                if node.kind == "layer"
-                and isinstance(node.layer, BaseRecurrentLayer)]
-
-    def _fit_tbptt(self, xs, ys, fmasks, lmasks, rng):
-        """Truncated BPTT over the DAG: chunk every time axis, carry RNN
-        state across chunks with stop_gradient (reference
-        `ComputationGraph.doTruncatedBPTT`)."""
-        T = max(x.shape[1] for x in xs if x.ndim == 3)
-        L = self.conf.tbptt_fwd_length
-        batch = xs[0].shape[0]
-        budget = self._stream_budget()
-        if budget is not None and T > budget:
-            raise ValueError(
-                f"TBPTT over a {T}-step sequence exceeds the bounded "
-                f"carry budget {budget} (min over transformer cache_len "
-                f"/ positional max_len): chunks past the budget would "
-                f"silently clamp into the KV cache. Shorten the "
-                f"sequences or rebuild with cache_len/max_len >= {T}.")
-        carries = {n: layer.init_carry(batch, self.dtype.compute_dtype)
-                   for n, layer in self._recurrent_nodes()}
-
-        def chunk(a, s):
-            # only rank-3 [B, T, F] time series are chunked (a 4D conv
-            # input in a multi-input graph must pass through untouched)
-            return a if (a is None or a.ndim != 3) else a[:, s:s + L]
-
-        total_loss, nchunks = 0.0, 0
-        dv = None
-        for s in range(0, T, L):
-            xc = tuple(chunk(x, s) for x in xs)
-            yc = tuple(y[:, s:s + L] if y.ndim == 3 else y for y in ys)
-            fm = tuple(None if m is None else m[:, s:s + L] for m in fmasks)
-            lm = tuple(None if m is None else
-                       (m[:, s:s + L] if m.ndim >= 2 else m) for m in lmasks)
-            crng = jax.random.fold_in(rng, s)
-            (self.params, self.updater_state, new_state, loss, carries,
-             dv) = \
-                self._jit_tbptt_step(self.params, self.updater_state,
-                                     self.net_state, self.iteration_count,
-                                     xc, yc, crng, fm, lm, carries)
-            self.net_state = {**self.net_state, **new_state}
-            total_loss += float(loss)
-            nchunks += 1
-        # diagnostics reflect the LAST chunk (see MultiLayerNetwork)
-        return total_loss / max(nchunks, 1), dv
+        (features, labels) arrays; the loop is `TrainableNetwork._fit`."""
+        iterator = (ListDataSetIterator([data]) if isinstance(data, MultiDataSet)
+                    else as_iterator(data, labels, batch_size=batch_size))
+        return self._fit(iterator, epochs=epochs,
+                         steps_per_execution=steps_per_execution)
 
     # ------------------------------------------------------ rnn streaming
-    def rnn_clear_previous_state(self):
-        self._rnn_carries = {}
-        self._rnn_stream_pos = 0
-
-    def _stream_budget(self):
-        if getattr(self, "_stream_budget_cache", None) is None:
-            from deeplearning4j_tpu.nn.layers.transformer import (
-                stream_budget)
-            self._stream_budget_cache = (stream_budget(
-                [n.layer for n in self.conf.nodes.values()
-                 if n.layer is not None]),)
-        return self._stream_budget_cache[0]
-
-    def _check_stream_budget(self, new_tokens: int):
-        """Bounded-carry guard — see
-        `MultiLayerNetwork._check_stream_budget`."""
-        budget = self._stream_budget()
-        pos = getattr(self, "_rnn_stream_pos", 0)
-        if budget is not None and pos + new_tokens > budget:
-            raise ValueError(
-                f"rnn_time_step has streamed {pos} positions and this call "
-                f"adds {new_tokens}, exceeding the stream budget {budget} "
-                f"(min over transformer cache_len / positional max_len). "
-                f"Call rnn_clear_previous_state() to start a new sequence, "
-                f"or rebuild with a larger cache_len/max_len.")
-
     def rnn_time_step(self, *inputs, masks=None):
         """Streaming inference carrying RNN state across calls
         (reference `ComputationGraph.rnnTimeStep`). Each input may be
@@ -1127,7 +627,7 @@ class ComputationGraph:
         self._check_stream_budget(t_new)
         carries = dict(self._rnn_carries)
         batch = xs[0].shape[0]
-        for n, layer in self._recurrent_nodes():
+        for n, layer in self._recurrent_layers():
             if n not in carries:
                 carries[n] = layer.init_carry(batch, self.dtype.compute_dtype)
         if self._jit_rnn_step is None:
@@ -1141,34 +641,17 @@ class ComputationGraph:
         acts, carries = self._jit_rnn_step(self.params, self.net_state,
                                            tuple(xs), masks, carries)
         self._rnn_carries.update(carries)
-        self._rnn_stream_pos = getattr(self, "_rnn_stream_pos", 0) + t_new
+        self._rnn_stream_pos += t_new
         outs = []
         for n in self.conf.network_outputs:
             h = acts[n]
             outs.append(h[:, -1, :] if squeeze and h.ndim == 3 else h)
         return outs[0] if len(outs) == 1 else tuple(outs)
 
-    # ------------------------------------------------------------- resume
-    @staticmethod
-    def resume(directory) -> "ComputationGraph":
-        """Rebuild from the newest VALID full-state checkpoint under
-        `directory` (fault/ runtime) — exact-restart counterpart of
-        `MultiLayerNetwork.resume`; corrupt newest checkpoints fall
-        back to older ones with a logged warning."""
-        from deeplearning4j_tpu import fault
-        model, _ = fault.resume(directory)
-        if not isinstance(model, ComputationGraph):
-            raise TypeError(
-                f"checkpoint under {directory} holds a "
-                f"{type(model).__name__}; use that container's resume()")
-        return model
-
     # ------------------------------------------------------------ pretrain
     def pretrain(self, data, *, epochs: int = 1, batch_size: int = 32):
         """Greedy layerwise pretraining of AutoEncoder-style layer nodes
         in topological order (reference `ComputationGraph.pretrain`)."""
-        from deeplearning4j_tpu.datasets.iterator import as_iterator
-
         if not self._initialized:
             self.init()
         iterator = as_iterator(data, batch_size=batch_size)
@@ -1251,6 +734,17 @@ class ComputationGraph:
         return self
 
     # ------------------------------------------------------------- inference
+    @property
+    def single_io(self) -> bool:
+        return (len(self.conf.network_inputs) == 1
+                and len(self.conf.network_outputs) == 1)
+
+    def _forward_output(self, params, state, x):
+        """Eval-mode forward of a single-io graph's one features array
+        to its one output, pure (what the mesh trainers re-jit)."""
+        acts = self._forward_all(params, state, [x], train=False, rng=None)[0]
+        return acts[self.conf.network_outputs[0]]
+
     def output(self, *inputs, train: bool = False, masks=None):
         if not self._initialized:
             self.init()
@@ -1275,54 +769,7 @@ class ComputationGraph:
                                           unrolled=True)
         return acts
 
-    def score(self, dataset=None, training: bool = False):
-        if dataset is None:
-            return self.score_value
-        loss, _ = self._loss_fn(self.params, self.net_state,
-                                [jnp.asarray(dataset.features)],
-                                [jnp.asarray(dataset.labels)],
-                                None, None, None, train=training)
-        return float(loss)
-
-    def _evaluate_with(self, evaluator, iterator):
-        from deeplearning4j_tpu.datasets.iterator import as_iterator
-        from deeplearning4j_tpu.eval.evaluation import Evaluation
-        it = as_iterator(iterator, batch_size=128)
-        it.reset()
-        for ds in it:
-            masks = (None if ds.features_mask is None
-                     else [jnp.asarray(ds.features_mask)])
-            out = self.output(ds.features, masks=masks)
-            kw = {}
-            meta = getattr(ds, "example_metadata", None)
-            if meta is not None and isinstance(evaluator, Evaluation):
-                kw["record_metadata"] = meta
-            evaluator.eval(ds.labels, np.asarray(out),
-                           mask=ds.labels_mask, **kw)
-        return evaluator
-
     def evaluate(self, iterator, labels_list=None, top_n: int = 1):
-        from deeplearning4j_tpu.eval.evaluation import Evaluation
-        return self._evaluate_with(
-            Evaluation(labels_names=labels_list, top_n=top_n), iterator)
-
-    def evaluate_roc(self, iterator, threshold_steps: int = 0):
-        from deeplearning4j_tpu.eval.roc import ROC
-        return self._evaluate_with(ROC(threshold_steps=threshold_steps),
-                                   iterator)
-
-    def evaluate_roc_multi_class(self, iterator, threshold_steps: int = 0):
-        from deeplearning4j_tpu.eval.roc import ROCMultiClass
-        return self._evaluate_with(ROCMultiClass(threshold_steps=threshold_steps),
-                                   iterator)
-
-    # -------------------------------------------------------- param access
-    def param_table(self) -> Dict[str, jnp.ndarray]:
-        out = {}
-        for lk, lp in self.params.items():
-            for pk, arr in lp.items():
-                out[f"{lk}_{pk}"] = arr
-        return out
-
-    def num_params(self) -> int:
-        return sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(self.params))
+        # the reference's graph overload: no data_format before the labels
+        return super().evaluate(iterator, labels_list=labels_list,
+                                top_n=top_n)

@@ -24,138 +24,37 @@ feedForward(), rnnTimeStep(), evaluate() surfaces are all here.
 
 from __future__ import annotations
 
-import time
-from functools import partial
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from deeplearning4j_tpu.common.updaters import Sgd, Updater
-from deeplearning4j_tpu.nd.dtype import DataTypePolicy, resolve_policy
-from deeplearning4j_tpu.nn.conf.builder import (
-    BackpropType,
-    GradientNormalization,
-    MultiLayerConfiguration,
-)
+from deeplearning4j_tpu.common.updaters import Sgd
+from deeplearning4j_tpu.nd.dtype import DataTypePolicy
+from deeplearning4j_tpu.nn.conf.builder import MultiLayerConfiguration
 from deeplearning4j_tpu.nn.layers.base import Layer
 from deeplearning4j_tpu.nn.layers.feedforward import BaseOutputLayerMixin
 from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer
 from deeplearning4j_tpu.nn import scan_stack
-from deeplearning4j_tpu.optimize.gradients import (
-    apply_gradient_normalization,
-    apply_max_norm_constraint,
+from deeplearning4j_tpu.nn.trainable import (
+    TrainableNetwork,
+    _convert_features,
+    _convert_labels,
 )
-from deeplearning4j_tpu.optimize.listeners import ComposedListeners, TrainingListener
-from deeplearning4j_tpu.datasets.iterator import (
-    DataSetIterator,
-    TimedDataSetIterator,
-    as_iterator,
-)
-from deeplearning4j_tpu import monitor
+from deeplearning4j_tpu.datasets.iterator import as_iterator
 
 
-from deeplearning4j_tpu.nd.donation import donate_argnums as _donate
-
-
-def _convert_features(x, data_format):
-    if data_format in (None, "native"):
-        return x
-    if data_format.upper() == "NCHW":
-        return jnp.transpose(jnp.asarray(x), (0, 2, 3, 1))
-    if data_format.upper() in ("NCW", "NFT"):  # [B, F, T] → [B, T, F]
-        return jnp.transpose(jnp.asarray(x), (0, 2, 1))
-    raise ValueError(f"Unknown data_format {data_format}")
-
-
-def _convert_labels(y, data_format):
-    if y is None or data_format in (None, "native"):
-        return y
-    y = jnp.asarray(y)
-    if data_format.upper() in ("NCW", "NFT") and y.ndim == 3:
-        return jnp.transpose(y, (0, 2, 1))
-    return y
-
-
-def validate_param_widths(params):
-    """Unresolved n_in produces zero-width weights that only explode at
-    first forward — fail at init instead (reference LayerValidation
-    role). Shared by MultiLayerNetwork and ComputationGraph."""
-    for key, ps in params.items():
-        for pn, arr in ps.items():
-            if 0 in np.shape(arr):
-                raise ValueError(
-                    f"layer {key} param {pn} has shape {np.shape(arr)} — "
-                    f"input width unresolved; set n_in on the layer or "
-                    f"set_input_type() on the builder")
-
-
-class MultiLayerNetwork:
+class MultiLayerNetwork(TrainableNetwork):
     def __init__(self, conf: MultiLayerConfiguration, dtype_policy: DataTypePolicy = None,
                  diagnostics=None):
-        self.conf = conf
         self.layers: List[Layer] = conf.layers
-        # DL4J_DTYPE_POLICY env > explicit arg > conf.dtype_policy >
-        # process default (nd/dtype.py)
-        self.dtype = resolve_policy(dtype_policy, conf)
-        # in-graph model-internals diagnostics (monitor/diagnostics.py):
-        # DL4J_DIAGNOSTICS env > explicit arg > conf.diagnostics > off
-        self.diagnostics = monitor.resolve_diagnostics(diagnostics, conf)
-        self._diag = (monitor.Diagnostics(self.diagnostics)
-                      if self.diagnostics is not None else None)
-        self._last_diagnostics = None
-        self._last_group_dv = None
-        self.params: Dict[str, Dict[str, jnp.ndarray]] = {}
-        self.net_state: Dict[str, Dict[str, jnp.ndarray]] = {}
-        self.updater_state: Dict[str, Dict[str, Any]] = {}
-        self.iteration_count = 0
-        self.epoch_count = 0
-        self.listeners: List[TrainingListener] = []
-        self.score_value: float = float("nan")
-        self._rnn_carries: Dict[str, Any] = {}  # rnnTimeStep streaming state
-        self._rnn_stream_pos = 0  # host-side stream-budget tracker
-        self._jit_train_step = None
-        self._jit_tbptt_step = None
-        self._jit_multi_step = None
-        self._jit_output = None
-        self._jit_rnn_step = None
-        self._solver = None
-        self._ambient_seq_ctx = None
-        self._uses_seq_parallel = any(
-            getattr(l, "sequence_parallel", None) for l in self.layers)
+        super().__init__(conf, dtype_policy, diagnostics)
         # scan-over-layers segment plans (nn/scan_stack.py), keyed by
         # the forward's layer count; built lazily from traced shapes
         self._scan_plans: Dict[int, list] = {}
-        self._packed_runs_cache = None
-        self._initialized = False
         out = self.layers[-1] if self.layers else None
-        if out is not None and not isinstance(out, BaseOutputLayerMixin):
-            self._has_loss = False
-        else:
-            self._has_loss = True
-
-    def _sync_ambient_context(self):
-        """Cached jitted steps bake in trace-time decisions — including
-        which attention schedule the ambient `sequence_sharding` context
-        selected. If the active (mesh, axis) differs from the one the
-        cached programs were traced under, drop them so the next call
-        re-traces; otherwise a step compiled outside the context would
-        silently keep running local attention inside it (and vice
-        versa). No-op for models with no sequence-parallel layers."""
-        if not self._uses_seq_parallel:
-            return
-        from deeplearning4j_tpu.parallel.context import current_sequence_mesh
-        ctx = current_sequence_mesh()
-        if ctx == self._ambient_seq_ctx:
-            return
-        self._ambient_seq_ctx = ctx
-        self._jit_train_step = None
-        self._jit_tbptt_step = None
-        self._jit_multi_step = None
-        self._jit_output = None
-        self._jit_rnn_step = None
-        self._solver = None
+        self._has_loss = out is None or isinstance(out, BaseOutputLayerMixin)
 
     # ------------------------------------------------------------------ init
     def _init_trees(self, seed: int):
@@ -177,21 +76,30 @@ class MultiLayerNetwork:
                 state[str(i)] = s
         return params, state, upd
 
-    def init(self, seed: Optional[int] = None) -> "MultiLayerNetwork":
-        seed = self.conf.seed if seed is None else seed
-        (self.params, self.net_state, self.updater_state) = \
-            self._init_trees(seed)
-        validate_param_widths(self.params)
-        self._initialized = True
-        return self
+    # ------------------------------------------------ the shared code's answers
+    def _layer(self, lk: str):
+        return self.layers[int(lk)]
 
-    def set_listeners(self, *listeners):
-        self.listeners = list(listeners)
-        return self
+    def _keyed_layers(self):
+        return [(str(i), layer) for i, layer in enumerate(self.layers)]
 
-    def add_listener(self, listener):
-        self.listeners.append(listener)
-        return self
+    def _scan_runs(self, params):
+        # plan over n-1: the output layer never packs
+        plan = self._forward_plan(params, max(len(self.layers) - 1, 0))
+        return [[str(i) for i in range(seg[1], seg[2])]
+                for seg in plan if seg[0] == "scan"]
+
+    def _step_batch(self, ds, data_format=None):
+        x = _convert_features(ds.features, data_format)
+        y = _convert_labels(ds.labels, data_format)
+        fmask = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
+        lmask = None if ds.labels_mask is None else _convert_labels(ds.labels_mask, data_format)
+        return x, y, fmask, lmask, int(np.shape(x)[0])
+
+    def _predict(self, ds, data_format=None):
+        return self.output(ds.features, data_format=data_format,
+                           mask=None if ds.features_mask is None
+                           else jnp.asarray(ds.features_mask))
 
     # --------------------------------------------------------------- forward
     def _forward_plan(self, params, n):
@@ -332,6 +240,18 @@ class MultiLayerNetwork:
         h, new_state, new_carries, _, mask = self._forward_core(
             params, state, x, train=train, rng=rng, mask=fmask,
             carries=carries, upto=n - 1, stats_out=stats_out)
+        return self._output_loss(params, state, h, mask, y, rng, lmask,
+                                 new_state, new_carries, stats_out,
+                                 train=train)
+
+    def _output_loss(self, params, state, h, mask, y, rng, lmask, new_state,
+                     new_carries=None, stats_out=None, *, train):
+        """The loss's tail from the last hidden activation `h`: the
+        output layer's preprocessor and loss, regularization and the
+        auxiliary losses threaded through `new_state`. Shared with the
+        pipeline trainer (parallel/pipeline_container.py), whose
+        schedule produces `h` its own way."""
+        n = len(self.layers)
         if (n - 1) in self.conf.input_preprocessors:
             pp = self.conf.input_preprocessors[n - 1]
             h = pp.pre_process(h, mask)
@@ -370,511 +290,29 @@ class MultiLayerNetwork:
             if "aux_loss" in st:
                 reg = reg + st.pop("aux_loss")
         total = self.dtype.cast_output(loss) + reg
-        if act_stats:
+        if stats_out is not None:
             return total, (new_state, new_carries, stats_out)
         return total, (new_state, new_carries)
-
-    # ---------------------------------------------------------- train step
-    def _packed_runs(self, params):
-        """Runs packed at the train-step boundary (nn/scan_stack.py):
-        the loss-path scan runs (plan over n-1 — the output layer never
-        packs) filtered to configs whose gradient-normalization /
-        constraint semantics survive a stacked leading axis."""
-        runs = self._packed_runs_cache
-        if runs is None:
-            n = len(self.layers)
-            plan = self._forward_plan(params, max(n - 1, 0))
-            rwt = [([str(i) for i in range(seg[1], seg[2])],
-                    self.layers[seg[1]])
-                   for seg in plan if seg[0] == "scan"]
-            runs = scan_stack.packable_runs(self.conf, rwt)
-            self._packed_runs_cache = runs
-        return runs
-
-    def _fused_state_runs(self, runs, params=None):
-        """Packed runs whose updater takes the fused-Adam kernel —
-        their m/v ride the step programs in the kernel's pre-flattened
-        [rows, 128] layout (kernels/fused_adam.py: the relayout that
-        used to happen around the kernel every micro-step now happens
-        once per program, at the pack/unpack boundary). Runs carrying
-        LoRA adapter nodes (tenancy/lora.py) stay on the per-leaf path
-        — the kernel's flat layout has no notion of a wrapped weight."""
-        from deeplearning4j_tpu.kernels import fused_adam as fa
-        from deeplearning4j_tpu.tenancy import lora
-        return [scan_stack.run_key(keys) for keys in runs
-                if fa.fused_adam_eligible(
-                    self.layers[int(keys[0])].updater or Sgd(1e-3))
-                and not (params is not None and any(
-                    lora.contains_lora(params.get(k, {})) for k in keys))]
-
-    def _apply_updates(self, params, grads, upd_state, step):
-        from deeplearning4j_tpu.kernels import fused_adam as fa
-        from deeplearning4j_tpu.tenancy import lora
-        # a FROZEN attached adapter freezes the WHOLE base, not just
-        # the wrapped matmul weights: biases, norms and embeddings hold
-        # still too, so the published delta fully describes the tenant
-        # and N tenants fine-tuned off one base stay composable. The
-        # flag is derived from leaf types/aux (static under trace —
-        # part of the treedef, so no stale-compile hazard).
-        frozen_base = any(
-            w.frozen for lv in params.values() for w in lv.values()
-            if type(w).__name__ == "LoRAWeight")
-        new_params, new_upd = {}, {}
-        for lk, lgrads in grads.items():
-            if scan_stack.is_run_key(lk):
-                # stacked run entry: the shared updater is elementwise,
-                # so one application covers the whole run (packable_runs
-                # guarantees no per-layer constraints on these layers)
-                layer = self.layers[int(scan_stack.run_members(lk)[0])]
-            else:
-                layer = self.layers[int(lk)]
-            updater = layer.updater or Sgd(1e-3)
-            if frozen_base and not lora.contains_lora(params[lk]):
-                # frozen-base training, no adapter in this entry
-                # (packed runs included): nothing here may move
-                new_params[lk] = params[lk]
-                new_upd[lk] = upd_state[lk]
-                continue
-            if (scan_stack.is_run_key(lk)
-                    and fa.fused_adam_eligible(updater)):
-                # Pallas fast path: ONE kernel read-modify-writes the
-                # whole packed run's param/m/v stack in a single pass
-                # (bit-comparable to the per-leaf jnp path below;
-                # DL4J_PALLAS_KERNELS=0 opts out)
-                lp, lu = fa.adam_update_packed(
-                    updater, params[lk], lgrads, upd_state[lk], step)
-                new_params[lk] = lp
-                new_upd[lk] = lu
-                continue
-            lp, lu = {}, {}
-            for pk, g in lgrads.items():
-                p = params[lk][pk]
-                if type(p).__name__ == "LoRAWeight":
-                    # adapter leaf (tenancy/lora.py): B/A move through
-                    # the updater; a frozen base keeps its object
-                    # identity — zero copies, bit-identical base
-                    from deeplearning4j_tpu.tenancy import lora
-                    lp[pk], lu[pk] = lora.apply_adapter_update(
-                        updater, p, g, upd_state[lk][pk], step)
-                    continue
-                if frozen_base:
-                    # plain leaf beside an adapted one (a Dense bias
-                    # next to its wrapped W): frozen too
-                    lp[pk] = p
-                    lu[pk] = upd_state[lk][pk]
-                    continue
-                # bf16 grads (mixed policy) meet the fp32 master here:
-                # upcast BEFORE the updater so m/v/param stay fp32
-                g = g.astype(p.dtype)
-                delta, new_s = updater.apply(g, upd_state[lk][pk], step)
-                lp[pk] = p - delta.astype(p.dtype)
-                lu[pk] = new_s
-            new_params[lk] = (lp if scan_stack.is_run_key(lk)
-                              else layer.apply_constraints(lp))
-            new_upd[lk] = lu
-        if self.conf.max_norm is not None:
-            new_params = apply_max_norm_constraint(new_params, self.conf.max_norm)
-        return new_params, new_upd
-
-    def _make_train_step(self, tbptt: bool):
-        gn = self.conf.gradient_normalization
-        gn_t = self.conf.gradient_normalization_threshold
-        diag = self._diag
-        want_acts = diag is not None and diag.config.activation_stats
-
-        def step_fn(params, upd_state, state, it, x, y, rng, fmask, lmask, carries=None):
-            # boundary packing (nn/scan_stack.py): homogeneous runs ride
-            # the whole step as ONE stacked entry — forward scan,
-            # backward, and updater all stay depth-independent. The
-            # TBPTT step threads carries through the unrolled path and
-            # keeps the per-layer tree.
-            runs = ([] if tbptt or not scan_stack.scan_enabled(self.conf)
-                    else self._packed_runs(params))
-            fused_runs = []
-            if runs:
-                from deeplearning4j_tpu.kernels import fused_adam as fa
-                fused_runs = self._fused_state_runs(runs, params)
-                params, upd_state = fa.pack_run_trees(
-                    params, upd_state, runs, fused_runs)
-
-            def lf(p):
-                if tbptt and carries is not None:
-                    stopped = jax.tree_util.tree_map(jax.lax.stop_gradient, carries)
-                else:
-                    stopped = carries
-                return self._loss_fn(p, state, x, y, rng, fmask, lmask,
-                                     train=True, carries=stopped,
-                                     act_stats=want_acts)
-
-            # differentiate wrt the COMPUTE-dtype tree (cast outside
-            # value_and_grad): under mixed_bf16 the gradients — and any
-            # data-parallel all-reduce of them — are bf16; the updater
-            # below upcasts onto the fp32 master params/state
-            (loss, aux), grads = jax.value_and_grad(
-                lf, has_aux=True)(self.dtype.cast_params(params))
-            if want_acts:
-                new_state, new_carries, acts = aux
-            else:
-                (new_state, new_carries), acts = aux, None
-            grads = apply_gradient_normalization(grads, gn, gn_t)
-            new_params, new_upd = self._apply_updates(params, grads, upd_state, it)
-            # aux outputs only: the update/param math above is
-            # untouched, so the trajectory stays bit-identical to
-            # diagnostics-off (except an explicit skip firing)
-            new_params, new_upd, new_state, dv = \
-                monitor.diagnostics.collect_and_gate(
-                    diag, "fit", params_old=params, params_new=new_params,
-                    upd_old=upd_state, upd_new=new_upd, state_old=state,
-                    state_new=new_state, grads=grads, loss=loss, acts=acts)
-            if runs:
-                from deeplearning4j_tpu.kernels import fused_adam as fa
-                new_params, new_upd = fa.unpack_run_trees(
-                    new_params, new_upd, runs, fused_runs)
-            return new_params, new_upd, new_state, loss, new_carries, dv
-
-        return jax.jit(step_fn, donate_argnums=_donate(0, 1, 2))
-
-    def _multi_step_fn(self):
-        """Unjitted k-fused-steps function (`lax.scan` over the step
-        body). Exposed separately so `ParallelTrainer` can re-jit the
-        SAME body with mesh shardings — one copy of the fused numerics.
-
-        The scan carry must keep a constant pytree structure, so state
-        keys a train-mode forward emits that were absent from
-        `init_state` (e.g. a MoE layer's popped-empty aux slot) are NOT
-        carried across fused steps; the per-step path merges them into
-        `net_state` outside jit, where growth is legal. Keys present at
-        init (batchnorm running stats, ...) update normally."""
-        gn = self.conf.gradient_normalization
-        gn_t = self.conf.gradient_normalization_threshold
-        diag = self._diag
-        want_acts = diag is not None and diag.config.activation_stats
-
-        def one(carry, inp):
-            params, upd, state, it = carry
-            x, y, rng = inp
-
-            def lf(p):
-                return self._loss_fn(p, state, x, y, rng, None, None,
-                                     train=True, act_stats=want_acts)
-
-            (loss, aux), grads = jax.value_and_grad(
-                lf, has_aux=True)(self.dtype.cast_params(params))
-            if want_acts:
-                new_state, _, acts = aux
-            else:
-                (new_state, _), acts = aux, None
-            grads = apply_gradient_normalization(grads, gn, gn_t)
-            new_params, new_upd = self._apply_updates(params, grads, upd, it)
-            # per-step stats ride the fused scan's ys — stacked [k, K]
-            # at program exit, ONE batched transfer per listener
-            # cadence (the fused-dispatch contract)
-            new_params, new_upd, new_state, dv = \
-                monitor.diagnostics.collect_and_gate(
-                    diag, "fit", params_old=params, params_new=new_params,
-                    upd_old=upd, upd_new=new_upd, state_old=state,
-                    state_new=new_state, grads=grads, loss=loss, acts=acts)
-            state = {k: new_state.get(k, v) for k, v in state.items()}
-            return (new_params, new_upd, state, it + 1), (loss, dv)
-
-        def multi(params, upd, state, it0, xs, ys, rngs):
-            # homogeneous runs ride the k-step scan carry as stacked
-            # entries — packed/unpacked once per PROGRAM, not per step.
-            # Fused-Adam runs additionally carry m/v in the kernel's
-            # pre-flattened [rows, 128] layout, so the per-micro-step
-            # optimizer-state relayout disappears from the scan body.
-            runs = (self._packed_runs(params)
-                    if scan_stack.scan_enabled(self.conf) else [])
-            fused_runs = []
-            if runs:
-                from deeplearning4j_tpu.kernels import fused_adam as fa
-                fused_runs = self._fused_state_runs(runs, params)
-                params, upd = fa.pack_run_trees(params, upd, runs,
-                                                fused_runs)
-            (params, upd, state, _), (losses, dvs) = jax.lax.scan(
-                one, (params, upd, state, jnp.asarray(it0, jnp.int32)),
-                (xs, ys, rngs))
-            if runs:
-                from deeplearning4j_tpu.kernels import fused_adam as fa
-                params, upd = fa.unpack_run_trees(params, upd, runs,
-                                                  fused_runs)
-            return params, upd, state, losses, dvs
-
-        return multi
-
-    def _make_multi_step(self):
-        """k fused train steps in ONE device dispatch via `lax.scan`.
-
-        Small models (LeNet-class) are dispatch-bound: a ~1ms TPU step
-        costs ~10ms of Python/runtime per call. Scanning the step body
-        over stacked minibatches amortizes that to one dispatch per k
-        steps — the reference has no analogue because its loop overhead
-        is native (`MultiLayerNetwork.java:1156` fit loop); ours is the
-        idiomatic XLA fix. Numerics are identical to k single steps:
-        same per-iteration RNG fold, same updater step counter.
-        """
-        return jax.jit(self._multi_step_fn(), donate_argnums=_donate(0, 1, 2))
-
-    def _run_multi_step(self, xs, ys, it0):
-        """Run len(xs) fused steps on stacked batches. Returns per-step
-        losses (device array)."""
-        if self._jit_multi_step is None:
-            self._jit_multi_step = self._make_multi_step()
-        rng_root = jax.random.PRNGKey(self.conf.seed + 1)
-        its = jnp.arange(it0, it0 + xs.shape[0])
-        rngs = jax.vmap(lambda i: jax.random.fold_in(rng_root, i))(its)
-        (self.params, self.updater_state, self.net_state, losses, dvs) = \
-            self._jit_multi_step(self.params, self.updater_state,
-                                 self.net_state, it0, xs, ys, rngs)
-        # stacked per-step diag vectors ({} with diagnostics off) — read
-        # by the fit loop at listener cadence, NOT here (no sync)
-        self._last_group_dv = dvs
-        return losses
-
-    # ------------------------------------------------- AOT observability
-    def _train_step_avals(self, x, y, steps: int):
-        """Stacked input avals for the fused train-step: only shapes and
-        dtypes are read, so callers can pass arrays OR ShapeDtypeStructs
-        and no host memory is spent on the stacks."""
-        def sds(a):
-            return jax.ShapeDtypeStruct((steps,) + tuple(a.shape),
-                                        jnp.dtype(a.dtype))
-        key = jax.random.PRNGKey(0)
-        rngs = jax.ShapeDtypeStruct((steps,) + tuple(key.shape), key.dtype)
-        return sds(x), sds(y), rngs
-
-    def lower_train_step(self, x, y, *, steps: int = 1, it0: int = 0):
-        """AOT-lower the exact fused train-step that
-        `fit(steps_per_execution=steps)` dispatches. Returns a
-        `jax.stages.Lowered`: `.cost_analysis()` (per-program FLOPs /
-        bytes accessed) runs on any host with no accelerator attached —
-        the device-free seam `benchtools/hlo_cost.py` builds on — and
-        `.compile()` yields the same executable the fit loop would
-        build (bench.py compiles it once for cost analysis AND the
-        timed windows, so the minutes-long ResNet program is never
-        compiled twice). Call the compiled executable with a plain
-        Python int for `it0`, matching this lowering's aval."""
-        if not self._initialized:
-            self.init()
-        if self._jit_multi_step is None:
-            self._jit_multi_step = self._make_multi_step()
-        xs, ys, rngs = self._train_step_avals(x, y, steps)
-        return self._jit_multi_step.lower(
-            self.params, self.updater_state, self.net_state, it0,
-            xs, ys, rngs)
-
-    def train_step_jaxpr(self, x, y, *, steps: int = 1):
-        """ClosedJaxpr of the same fused train-step (the per-op cost
-        tables in `benchtools/hlo_cost.py` walk it primitive by
-        primitive)."""
-        if not self._initialized:
-            self.init()
-        xs, ys, rngs = self._train_step_avals(x, y, steps)
-        return jax.make_jaxpr(self._multi_step_fn())(
-            self.params, self.updater_state, self.net_state, 0,
-            xs, ys, rngs)
 
     # ----------------------------------------------------------------- fit
     def fit(self, data, labels=None, *, epochs: int = 1, batch_size: int = 32,
             data_format=None, shuffle: bool = True,
             steps_per_execution: int = 1):
         """Train. `data` may be a DataSetIterator, DataSet, list of
-        DataSets, or a feature array (+ labels).
-
-        `steps_per_execution > 1` fuses that many minibatch steps into a
-        single device dispatch (`lax.scan` over stacked batches) —
-        numerics identical, Python overhead paid once per group. Falls
-        back to per-step dispatch for TBPTT, line-search solvers, masked
-        batches, and ragged tails."""
-        if not self._initialized:
-            self.init()
-        self._sync_ambient_context()
-        # iterator-side ETL attribution (feeds the etl_ms info key and,
-        # when monitoring is on, fit/etl spans + the ETL histogram)
-        iterator = TimedDataSetIterator(
-            as_iterator(data, labels, batch_size=batch_size, shuffle=shuffle))
-        listeners = ComposedListeners(self.listeners
-                                      + monitor.extra_listeners())
-        rng_root = jax.random.PRNGKey(self.conf.seed + 1)
-        tbptt = self.conf.backprop_type == BackpropType.TRUNCATED_BPTT
-        solver = None
-        if getattr(self.conf, "optimization_algo", "sgd") != "sgd":
-            if tbptt:
-                raise ValueError(
-                    "optimization_algo=%r cannot be combined with truncated "
-                    "BPTT: the line-search solvers optimize the full-sequence "
-                    "loss and would ignore tbptt_fwd_length. Use SGD, or "
-                    "standard backprop_type." % self.conf.optimization_algo)
-            # line-search family (reference OptimizationAlgorithm enum):
-            # each minibatch is optimized for max_iterations by the solver.
-            # Cached on self so repeated fit() calls reuse the jitted loss.
-            if self._solver is None:
-                from deeplearning4j_tpu.optimize.solvers import Solver
-                self._solver = Solver(self, self.conf.optimization_algo,
-                                      max_iterations=self.conf.max_iterations)
-            solver = self._solver
-        if self._jit_train_step is None:
-            self._jit_train_step = self._make_train_step(tbptt=False)
-        if tbptt and self._jit_tbptt_step is None:
-            self._jit_tbptt_step = self._make_train_step(tbptt=True)
-        spe = max(1, int(steps_per_execution))
-        fused_ok = spe > 1 and solver is None and not tbptt
-
-        def fit_one(x, y, fmask, lmask, etl_ms):
-            rng = jax.random.fold_in(rng_root, self.iteration_count)
-            dv = None
-            # forward_backward covers the step's device dispatch (the
-            # fused fwd+bwd+update program); the score readback + host
-            # state merge + listener fan-out is the update span. With
-            # monitoring off both spans are the shared no-op.
-            with monitor.span("fit/forward_backward",
-                              iteration=self.iteration_count):
-                if solver is not None:
-                    loss = solver.optimize(x, y, fmask, lmask)
-                elif tbptt and x.ndim == 3:
-                    loss, dv = self._fit_tbptt(x, y, fmask, lmask, rng)
-                else:
-                    (self.params, self.updater_state, new_state, loss, _,
-                     dv) = \
-                        self._jit_train_step(self.params, self.updater_state,
-                                             self.net_state, self.iteration_count,
-                                             x, y, rng, fmask, lmask, None)
-                    self.net_state = {**self.net_state, **new_state}
-            with monitor.span("fit/update", iteration=self.iteration_count):
-                self.score_value = float(loss)
-                dstats = None
-                if (self._diag is not None and dv
-                        and self._diag.due(self.iteration_count)):
-                    # ONE batched device→host transfer at cadence; the
-                    # watchdog's warn/halt/count actions live here
-                    dstats = self._diag.process(
-                        self, dv, "fit", self.iteration_count)[-1]
-                listeners.iteration_done(self, self.iteration_count, self.epoch_count,
-                                         self.score_value,
-                                         batch_size=int(np.shape(x)[0]),
-                                         etl_ms=etl_ms,
-                                         batch=(x, y, fmask, lmask),
-                                         diagnostics=dstats)
-            self.iteration_count += 1
-
-        def flush(pending, etl_ms):
-            if not pending:
-                return
-            if len(pending) == 1:
-                fit_one(pending[0][0], pending[0][1], None, None, etl_ms)
-                return
-            with monitor.span("fit/forward_backward",
-                              iteration=self.iteration_count,
-                              fused_steps=len(pending)):
-                xs = jnp.stack([p[0] for p in pending])
-                ys = jnp.stack([p[1] for p in pending])
-                losses = np.asarray(self._run_multi_step(xs, ys,
-                                                         self.iteration_count))
-            with monitor.span("fit/update", fused_steps=len(pending)):
-                group_stats = None
-                dvs = self._last_group_dv
-                if (self._diag is not None and dvs
-                        and any(self._diag.due(self.iteration_count + j)
-                                for j in range(len(pending)))):
-                    # the fused group's stacked stats arrive in ONE
-                    # batched transfer when any step in it is on-cadence
-                    group_stats = self._diag.process(
-                        self, dvs, "fit", self.iteration_count)
-                for j, (x, y) in enumerate(pending):
-                    self.score_value = float(losses[j])
-                    dstats = (group_stats[j] if group_stats is not None
-                              and self._diag.due(self.iteration_count)
-                              else None)
-                    # mid-group callbacks see POST-group params with a
-                    # mid-group iteration count; only the last callback
-                    # is a state-consistent step boundary (checkpoint
-                    # listeners key off this)
-                    listeners.iteration_done(self, self.iteration_count,
-                                             self.epoch_count, self.score_value,
-                                             batch_size=int(np.shape(x)[0]),
-                                             etl_ms=etl_ms if j == 0 else 0.0,
-                                             batch=(x, y, None, None),
-                                             step_boundary=(
-                                                 j == len(pending) - 1),
-                                             diagnostics=dstats)
-                    self.iteration_count += 1
-
-        mon_on = monitor.is_enabled()
-        listeners.on_fit_start(self)
-        for _ in range(epochs):
-            listeners.on_epoch_start(self, self.epoch_count)
-            iterator.reset()
-            pending = []
-            for ds in iterator:
-                etl_ms = iterator.last_etl_ms
-                if mon_on:
-                    t1 = time.perf_counter()
-                    monitor.tracer().complete_between(
-                        "fit/etl", t1 - etl_ms / 1e3, t1,
-                        iteration=self.iteration_count)
-                x = _convert_features(ds.features, data_format)
-                y = _convert_labels(ds.labels, data_format)
-                fmask = None if ds.features_mask is None else jnp.asarray(ds.features_mask)
-                lmask = None if ds.labels_mask is None else _convert_labels(ds.labels_mask, data_format)
-                if not fused_ok or fmask is not None or lmask is not None:
-                    flush(pending, 0.0)
-                    pending = []
-                    fit_one(x, y, fmask, lmask, etl_ms)
-                else:
-                    if pending and (x.shape != pending[0][0].shape
-                                    or np.shape(y) != np.shape(pending[0][1])):
-                        flush(pending, 0.0)
-                        pending = []
-                    pending.append((x, y))
-                    if len(pending) == spe:
-                        flush(pending, etl_ms)
-                        pending = []
-            flush(pending, 0.0)
-            listeners.on_epoch_end(self, self.epoch_count)
-            self.epoch_count += 1
-        listeners.on_fit_end(self)
-        return self
-
-    def _fit_tbptt(self, x, y, fmask, lmask, rng):
-        """Truncated BPTT: chunk the time axis, carry RNN state across
-        chunks with stop_gradient (reference `doTruncatedBPTT`
-        MultiLayerNetwork.java:1393)."""
-        T = x.shape[1]
-        L = self.conf.tbptt_fwd_length
-        from deeplearning4j_tpu.nn.layers.transformer import stream_budget
-        budget = stream_budget(self.layers)
-        if budget is not None and T > budget:
-            raise ValueError(
-                f"TBPTT over a {T}-step sequence exceeds the bounded "
-                f"carry budget {budget} (min over transformer cache_len "
-                f"/ positional max_len): chunks past the budget would "
-                f"silently clamp into the KV cache. Shorten the "
-                f"sequences or rebuild with cache_len/max_len >= {T}.")
-        carries = {}
-        for i, layer in enumerate(self.layers):
-            if isinstance(layer, BaseRecurrentLayer):
-                carries[str(i)] = layer.init_carry(x.shape[0], self.dtype.compute_dtype)
-        total_loss = 0.0
-        nchunks = 0
-        dv = None
-        for s in range(0, T, L):
-            xc = x[:, s:s + L]
-            yc = y[:, s:s + L] if y.ndim == 3 else y
-            fm = None if fmask is None else fmask[:, s:s + L]
-            lm = None if lmask is None else (lmask[:, s:s + L] if lmask.ndim >= 2 else lmask)
-            crng = jax.random.fold_in(rng, s)
-            (self.params, self.updater_state, new_state, loss, carries,
-             dv) = \
-                self._jit_tbptt_step(self.params, self.updater_state, self.net_state,
-                                     self.iteration_count, xc, yc, crng, fm, lm, carries)
-            self.net_state = {**self.net_state, **new_state}
-            total_loss += float(loss)
-            nchunks += 1
-        # diagnostics reflect the LAST chunk (one iteration spans many
-        # chunks under TBPTT; the skip gate still fires per chunk)
-        return total_loss / max(nchunks, 1), dv
+        DataSets, or a feature array (+ labels); the loop is
+        `TrainableNetwork._fit`."""
+        return self._fit(
+            as_iterator(data, labels, batch_size=batch_size, shuffle=shuffle),
+            epochs=epochs, steps_per_execution=steps_per_execution,
+            data_format=data_format)
 
     # ------------------------------------------------------------- inference
+    def _forward_output(self, params, state, x, mask=None):
+        """Eval-mode forward of one features array to the final
+        activation, pure (what the mesh trainers re-jit)."""
+        return self._forward_core(params, state, x, train=False, rng=None,
+                                  mask=mask)[0]
+
     def output(self, x, train: bool = False, data_format=None, mask=None):
         """Forward pass to the final activation (reference
         `MultiLayerNetwork.output` :1866)."""
@@ -884,10 +322,9 @@ class MultiLayerNetwork:
         x = _convert_features(x, data_format)
         if self._jit_output is None:
             def fwd(params, state, x, mask):
-                h, _, _, _, _ = self._forward_core(params, state, x, train=False,
-                                                   rng=None, mask=mask)
                 # eval numerics stay fp32 under a mixed policy
-                return self.dtype.cast_output(h)
+                return self.dtype.cast_output(
+                    self._forward_output(params, state, x, mask))
             self._jit_output = jax.jit(fwd)
         return self._jit_output(self.params, self.net_state, x, mask)
 
@@ -899,93 +336,7 @@ class MultiLayerNetwork:
                                               collect=True)
         return acts
 
-    def score(self, dataset=None, training: bool = False):
-        """Loss on a DataSet (or the last fit minibatch's score if None) —
-        reference `score()` semantics."""
-        if dataset is None:
-            return self.score_value
-        loss, _ = self._loss_fn(self.params, self.net_state,
-                                jnp.asarray(dataset.features), jnp.asarray(dataset.labels),
-                                None,
-                                None if dataset.features_mask is None else jnp.asarray(dataset.features_mask),
-                                None if dataset.labels_mask is None else jnp.asarray(dataset.labels_mask),
-                                train=training)
-        return float(loss)
-
-    def _evaluate_with(self, evaluator, iterator, data_format=None):
-        """Shared evaluation loop — any evaluator type with
-        .eval(labels, out, mask=) accumulates over the iterator
-        (reference evaluate/evaluateROC/evaluateRegression overloads)."""
-        iterator = as_iterator(iterator, batch_size=128)
-        iterator.reset()
-        for ds in iterator:
-            out = self.output(ds.features, data_format=data_format,
-                              mask=None if ds.features_mask is None
-                              else jnp.asarray(ds.features_mask))
-            from deeplearning4j_tpu.eval.evaluation import Evaluation
-            kw = {}
-            meta = getattr(ds, "example_metadata", None)
-            if meta is not None and isinstance(evaluator, Evaluation):
-                kw["record_metadata"] = meta
-            evaluator.eval(ds.labels, np.asarray(out),
-                           mask=ds.labels_mask, **kw)
-        return evaluator
-
-    def evaluate(self, iterator, data_format=None, labels_list=None,
-                 top_n: int = 1):
-        """Reference `evaluate(iterator[, labelsList[, topN]])`
-        :2794,:2892,:2944."""
-        from deeplearning4j_tpu.eval.evaluation import Evaluation
-        return self._evaluate_with(
-            Evaluation(labels_names=labels_list, top_n=top_n),
-            iterator, data_format)
-
-    def evaluate_roc(self, iterator, threshold_steps: int = 0,
-                     data_format=None):
-        """Binary ROC over the iterator (reference `evaluateROC` :2814)."""
-        from deeplearning4j_tpu.eval.roc import ROC
-        return self._evaluate_with(ROC(threshold_steps=threshold_steps),
-                                   iterator, data_format)
-
-    def evaluate_roc_multi_class(self, iterator, threshold_steps: int = 0,
-                                 data_format=None):
-        """One-vs-all ROC per class (reference `evaluateROCMultiClass`
-        :2825)."""
-        from deeplearning4j_tpu.eval.roc import ROCMultiClass
-        return self._evaluate_with(
-            ROCMultiClass(threshold_steps=threshold_steps), iterator,
-            data_format)
-
-    def evaluate_regression(self, iterator, data_format=None):
-        from deeplearning4j_tpu.eval.regression import RegressionEvaluation
-        return self._evaluate_with(RegressionEvaluation(), iterator,
-                                   data_format)
-
     # ------------------------------------------------------ rnn streaming
-    def rnn_clear_previous_state(self):
-        self._rnn_carries = {}
-        self._rnn_stream_pos = 0
-
-    def _check_stream_budget(self, new_tokens: int):
-        """Bounded-carry guard: KV caches / positional tables clamp
-        writes past their length, so streaming beyond the budget would
-        silently corrupt outputs. Tracked host-side because the carry's
-        device-side position cannot raise (same rule the zoo generate /
-        beam_search paths enforce via `_check_cache_budget`)."""
-        if getattr(self, "_stream_budget_cache", None) is None:
-            from deeplearning4j_tpu.nn.layers.transformer import (
-                stream_budget)
-            self._stream_budget_cache = (stream_budget(self.layers),)
-        budget = self._stream_budget_cache[0]
-        pos = getattr(self, "_rnn_stream_pos", 0)
-        if budget is not None and pos + new_tokens > budget:
-            raise ValueError(
-                f"rnn_time_step has streamed {pos} positions and this call "
-                f"adds {new_tokens}, exceeding the stream budget {budget} "
-                f"(min over transformer cache_len / positional max_len). "
-                f"Call rnn_clear_previous_state() to start a new sequence, "
-                f"or rebuild with a larger cache_len/max_len.")
-
     def rnn_time_step(self, x, data_format=None):
         """Streaming inference carrying RNN state across calls (reference
         `rnnTimeStep` :2605-2673). Accepts [B, F] (single step) or
@@ -1007,9 +358,9 @@ class MultiLayerNetwork:
         t_new = int(x.shape[1]) if x.ndim in (2, 3) else 1
         self._check_stream_budget(t_new)
         carries = dict(self._rnn_carries)
-        for i, layer in enumerate(self.layers):
-            if isinstance(layer, BaseRecurrentLayer) and str(i) not in carries:
-                carries[str(i)] = layer.init_carry(x.shape[0], self.dtype.compute_dtype)
+        for k, layer in self._recurrent_layers():
+            if k not in carries:
+                carries[k] = layer.init_carry(x.shape[0], self.dtype.compute_dtype)
         if self._jit_rnn_step is None:
             def rnn_fwd(params, state, x, carries):
                 h, _, new_carries, _, _ = self._forward_core(
@@ -1019,26 +370,14 @@ class MultiLayerNetwork:
         h, new_carries = self._jit_rnn_step(self.params, self.net_state, x,
                                             carries)
         self._rnn_carries.update(new_carries)
-        self._rnn_stream_pos = getattr(self, "_rnn_stream_pos", 0) + t_new
+        self._rnn_stream_pos += t_new
         return h[:, -1, :] if squeeze and h.ndim == 3 else h
 
     # -------------------------------------------------------- param access
-    def param_table(self) -> Dict[str, jnp.ndarray]:
-        """Flat {"0_W": array} view (reference `Model.paramTable`
-        "0_W"-style keys)."""
-        out = {}
-        for lk, lp in self.params.items():
-            for pk, arr in lp.items():
-                out[f"{lk}_{pk}"] = arr
-        return out
-
     def set_param_table(self, table: Dict[str, Any]):
         for key, arr in table.items():
             lk, pk = key.split("_", 1)
             self.params[lk][pk] = jnp.asarray(arr)
-
-    def num_params(self) -> int:
-        return sum(int(np.prod(a.shape)) for a in jax.tree_util.tree_leaves(self.params))
 
     def copy(self) -> "MultiLayerNetwork":
         clone = MultiLayerNetwork(MultiLayerConfiguration.from_dict(self.conf.to_dict()),
@@ -1053,23 +392,6 @@ class MultiLayerNetwork:
                 jnp.array, self.updater_state)
             clone._initialized = True
         return clone
-
-    # ------------------------------------------------------------- resume
-    @staticmethod
-    def resume(directory) -> "MultiLayerNetwork":
-        """Rebuild from the newest VALID full-state checkpoint under
-        `directory` (fault/ runtime): params, updater state, running
-        stats and counters all restored, so a follow-up `fit()`
-        continues the interrupted run bit-exactly (the per-step rng key
-        is derived from the restored iteration count). Corrupt newest
-        checkpoints fall back to older ones with a logged warning."""
-        from deeplearning4j_tpu import fault
-        model, _ = fault.resume(directory)
-        if not isinstance(model, MultiLayerNetwork):
-            raise TypeError(
-                f"checkpoint under {directory} holds a "
-                f"{type(model).__name__}; use that container's resume()")
-        return model
 
     # ------------------------------------------------------------ pretrain
     def pretrain(self, data, *, epochs: int = 1, batch_size: int = 32):

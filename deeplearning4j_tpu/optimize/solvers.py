@@ -302,41 +302,25 @@ class Solver:
         `model.net_state` is a jit *argument*, never a baked-in
         constant, so interleaving with SGD fit() stays consistent.
 
-        For ComputationGraph models, x/y/fmask/lmask may be lists (one
-        per network input/output). The loss closure is built once and
+        x/y/fmask/lmask are whatever the container's `_loss_fn` takes
+        (one array each, or one sequence entry per graph input/output;
+        an omitted mask is None). The loss closure is built once and
         jitted with the batch as an argument, so repeated calls (one per
         fit() minibatch) reuse the compiled step."""
         model = self.model
-        is_graph = hasattr(model, "conf") and hasattr(model.conf, "topo_order")
-
-        def as_list(v):
-            return [None if a is None else jnp.asarray(a) for a in v] \
-                if isinstance(v, (list, tuple)) else \
-                [None if v is None else jnp.asarray(v)]
-
         if self._loss_fn is None:
             _, unravel = ravel_pytree(model.params)
             self._unravel = unravel
-            if is_graph:
-                def loss_full(flat, state, xs, ys, fms, lms):
-                    loss, aux = model._loss_fn(unravel(flat), state, xs, ys,
-                                               None, fms, lms, train=True)
-                    return loss, aux[0]  # (new_state, carries) → state
-            else:
-                def loss_full(flat, state, xs, ys, fms, lms):
-                    loss, aux = model._loss_fn(unravel(flat), state, xs[0],
-                                               ys[0], None, fms[0], lms[0],
-                                               train=True)
-                    return loss, aux[0]
+
+            def loss_full(flat, state, x, y, fm, lm):
+                loss, aux = model._loss_fn(unravel(flat), state, x, y,
+                                           None, fm, lm, train=True)
+                return loss, aux[0]  # (new_state, carries) → state
             self._loss_full = jax.jit(loss_full)
             self._loss_fn = lambda flat, *a: loss_full(flat, *a)[0]
 
-        xs, ys = as_list(x), as_list(y)
-        # omitted masks expand to one None per input/output head (a bare
-        # [None] would be mis-indexed by multi-output graph losses)
-        fms = [None] * len(xs) if fmask is None else as_list(fmask)
-        lms = [None] * len(ys) if lmask is None else as_list(lmask)
-        args = (model.net_state, xs, ys, fms, lms)
+        args = (model.net_state,) + jax.tree_util.tree_map(
+            jnp.asarray, (x, y, fmask, lmask))
         flat0, _ = ravel_pytree(model.params)
         flat = self.optimizer.optimize(self._loss_fn, flat0, *args)
         model.params = jax.tree_util.tree_map(

@@ -308,18 +308,7 @@ def zeros_residual(params):
 
 
 # --------------------------------------------------------------- step bodies
-def _layer_for_key(model, is_graph: bool, lk: str):
-    """The layer owning a grads/params entry — ``stacked::`` run entries
-    resolve to their first member (the run template), mirroring the
-    containers' `_apply_updates`."""
-    if scan_stack.is_run_key(lk):
-        lk = scan_stack.run_members(lk)[0]
-    return (model.conf.nodes[lk].layer if is_graph
-            else model.layers[int(lk)])
-
-
-def compute_updater_deltas(model, is_graph: bool, params, grads,
-                           upd_state, step):
+def compute_updater_deltas(model, params, grads, upd_state, step):
     """Run every layer's OWN updater on its local gradients, returning
     the update tree (what the reference threshold-encodes —
     `SharedTrainingMaster` workers encode post-updater UPDATES, not raw
@@ -330,7 +319,7 @@ def compute_updater_deltas(model, is_graph: bool, params, grads,
 
     deltas, new_upd = {}, {}
     for lk, lgrads in grads.items():
-        layer = _layer_for_key(model, is_graph, lk)
+        layer = model.layer_for_key(lk)
         updater = layer.updater or Sgd(1e-3)
         ld, lu = {}, {}
         for pk, g in lgrads.items():
@@ -346,13 +335,13 @@ def compute_updater_deltas(model, is_graph: bool, params, grads,
     return deltas, new_upd
 
 
-def apply_decoded_updates(model, is_graph: bool, params, dhat):
+def apply_decoded_updates(model, params, dhat):
     """params minus the decoded shared update, then the shared
     post-update constraint pipeline (`_apply_constraints_tree` — one
     copy for the threshold and bucketed dense/rs paths)."""
     new_params = {lk: {pk: params[lk][pk] - d for pk, d in ld.items()}
                   for lk, ld in dhat.items()}
-    return _apply_constraints_tree(model, is_graph, new_params)
+    return _apply_constraints_tree(model, new_params)
 
 
 def _pmean_state(state, axis):
@@ -365,18 +354,6 @@ def _pmean_state(state, axis):
             return jax.lax.pmean(a, axis)
         return a
     return jax.tree_util.tree_map(avg, state)
-
-
-def _local_loss_fn(model, is_graph: bool):
-    if is_graph:
-        def lf(params, state, x, y, rng):
-            return model._loss_fn(params, state, (x,), (y,), rng,
-                                  (None,), (None,), train=True)
-    else:
-        def lf(params, state, x, y, rng):
-            return model._loss_fn(params, state, x, y, rng, None, None,
-                                  train=True)
-    return lf
 
 
 def _exchange_diag(model, diag, axis, *, params_old, upd_old, res_old,
@@ -418,8 +395,7 @@ def _exchange_diag(model, diag, axis, *, params_old, upd_old, res_old,
 
 
 def make_threshold_core(model, axis: str, cfg: ThresholdConfig, *,
-                        n_workers: int, is_graph: bool = False,
-                        diag=None):
+                        n_workers: int, diag=None):
     """Per-replica threshold sync-step body on ALREADY-PACKED trees
     (params/updater-state/residual may contain ``stacked::`` run
     entries — the encoder is elementwise, so a stacked leading axis
@@ -444,7 +420,7 @@ def make_threshold_core(model, axis: str, cfg: ThresholdConfig, *,
 
     gn = model.conf.gradient_normalization
     gn_t = model.conf.gradient_normalization_threshold
-    local_loss = _local_loss_fn(model, is_graph)
+    local_loss = model.local_loss
 
     def core(params, upd, state, it, residual, tau, x, y, rng):
         rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
@@ -454,11 +430,11 @@ def make_threshold_core(model, axis: str, cfg: ThresholdConfig, *,
             lambda p: local_loss(p, state, x, y, rng),
             has_aux=True)(model.dtype.cast_params(params))
         grads = apply_gradient_normalization(grads, gn, gn_t)
-        deltas, new_upd = compute_updater_deltas(
-            model, is_graph, params, grads, upd, it)
+        deltas, new_upd = compute_updater_deltas(model, params, grads,
+                                                 upd, it)
         dhat, new_residual, new_tau, sparsity = threshold_exchange(
             deltas, residual, tau, axis, cfg, n_workers=n_workers)
-        new_params = apply_decoded_updates(model, is_graph, params, dhat)
+        new_params = apply_decoded_updates(model, params, dhat)
         pstate = _pmean_state(new_state, axis)
         ploss = jax.lax.pmean(loss, axis)
         (new_params, new_upd, new_residual, new_tau, pstate, dv) = \
@@ -475,14 +451,13 @@ def make_threshold_core(model, axis: str, cfg: ThresholdConfig, *,
 
 
 def make_threshold_step(model, axis: str, cfg: ThresholdConfig, *,
-                        n_workers: int, is_graph: bool = False,
-                        diag=None):
+                        n_workers: int, diag=None):
     """One threshold sync step on per-layer (boundary) trees: packs
     ``stacked::`` runs for params, updater state AND residual at entry,
     unpacks at exit — the residual follows updater state through the
     pack boundary exactly (nn/scan_stack.py contract)."""
     core = make_threshold_core(model, axis, cfg, n_workers=n_workers,
-                               is_graph=is_graph, diag=diag)
+                               diag=diag)
 
     def step(params, upd, state, it, residual, tau, x, y, rng):
         runs = (model._packed_runs(params)
@@ -503,8 +478,7 @@ def make_threshold_step(model, axis: str, cfg: ThresholdConfig, *,
 
 
 def make_threshold_multi(model, axis: str, cfg: ThresholdConfig, *,
-                         n_workers: int, is_graph: bool = False,
-                         diag=None):
+                         n_workers: int, diag=None):
     """k fused threshold sync steps: ONE `lax.scan` whose carry is
     (params, updater state, layer state, iteration, residual, τ) — the
     residual and τ ride the carry next to the updater state, and the
@@ -516,7 +490,7 @@ def make_threshold_multi(model, axis: str, cfg: ThresholdConfig, *,
     `_multi_step_fn`): only state keys present at entry survive across
     fused steps."""
     core = make_threshold_core(model, axis, cfg, n_workers=n_workers,
-                               is_graph=is_graph, diag=diag)
+                               diag=diag)
 
     def multi(params, upd, state, it0, residual, tau, xs, ys, rngs):
         runs = (model._packed_runs(params)
@@ -627,7 +601,7 @@ def _plan_for(rs_plan: dict, lk: str) -> dict:
 
 
 
-def _threshold_bucket_hook(model, is_graph: bool, lk: str, axis: str,
+def _threshold_bucket_hook(model, lk: str, axis: str,
                            cfg: ThresholdConfig, n_workers: int,
                            gn, gn_t):
     """Threshold exchange for ONE bucket, emitted inside the backward
@@ -642,7 +616,7 @@ def _threshold_bucket_hook(model, is_graph: bool, lk: str, axis: str,
         apply_gradient_normalization,
     )
 
-    layer = _layer_for_key(model, is_graph, lk)
+    layer = model.layer_for_key(lk)
     updater = layer.updater or Sgd(1e-3)
     policy = model.dtype
 
@@ -677,7 +651,7 @@ def _threshold_bucket_hook(model, is_graph: bool, lk: str, axis: str,
     return hook
 
 
-def _dense_bucket_hook(model, is_graph: bool, lk: str, axis: str,
+def _dense_bucket_hook(model, lk: str, axis: str,
                        n_workers: int, gn, gn_t, plan_b: dict, *,
                        full_gn: bool):
     """Dense / ZeRO exchange for ONE bucket, emitted inside the
@@ -703,7 +677,7 @@ def _dense_bucket_hook(model, is_graph: bool, lk: str, axis: str,
         apply_gradient_normalization,
     )
 
-    layer = _layer_for_key(model, is_graph, lk)
+    layer = model.layer_for_key(lk)
     updater = layer.updater or Sgd(1e-3)
     n = n_workers
     policy = model.dtype
@@ -766,7 +740,7 @@ def _dense_bucket_hook(model, is_graph: bool, lk: str, axis: str,
     return hook
 
 
-def _threshold_rs_bucket_hook(model, is_graph: bool, lk: str, axis: str,
+def _threshold_rs_bucket_hook(model, lk: str, axis: str,
                               cfg: ThresholdConfig, n_workers: int,
                               gn, gn_t, plan_b: dict, elems: float):
     """Compressed ZeRO exchange for ONE bucket: threshold-encode the
@@ -779,7 +753,7 @@ def _threshold_rs_bucket_hook(model, is_graph: bool, lk: str, axis: str,
     update) mass."""
     from deeplearning4j_tpu.common.updaters import Sgd
 
-    layer = _layer_for_key(model, is_graph, lk)
+    layer = model.layer_for_key(lk)
     updater = layer.updater or Sgd(1e-3)
     n = n_workers
     wdtype = wire_dtype(n)
@@ -839,7 +813,7 @@ def _threshold_rs_bucket_hook(model, is_graph: bool, lk: str, axis: str,
     return hook
 
 
-def _apply_constraints_tree(model, is_graph: bool, new_params):
+def _apply_constraints_tree(model, new_params):
     """The post-update constraint pipeline `_apply_updates` runs, for
     params the rs hooks already updated: per-layer constraints (never
     on packed runs — `packable_runs` guarantees it), then the global
@@ -850,7 +824,7 @@ def _apply_constraints_tree(model, is_graph: bool, new_params):
 
     out = {}
     for lk, lp in new_params.items():
-        layer = _layer_for_key(model, is_graph, lk)
+        layer = model.layer_for_key(lk)
         out[lk] = (lp if scan_stack.is_run_key(lk)
                    else layer.apply_constraints(lp))
     if model.conf.max_norm is not None:
@@ -859,7 +833,7 @@ def _apply_constraints_tree(model, is_graph: bool, new_params):
 
 
 def make_bucketed_core(model, axis: str, cfg: ThresholdConfig, *,
-                       n_workers: int, mode: str, is_graph: bool = False,
+                       n_workers: int, mode: str,
                        rs_plan: Optional[dict] = None, diag=None):
     """Per-replica bucketed sync-step body on ALREADY-PACKED trees.
     Uniform signature across the four modes:
@@ -885,7 +859,7 @@ def make_bucketed_core(model, axis: str, cfg: ThresholdConfig, *,
 
     gn = model.conf.gradient_normalization
     gn_t = model.conf.gradient_normalization_threshold
-    local_loss = _local_loss_fn(model, is_graph)
+    local_loss = model.local_loss
 
     def core(params, upd, state, it, residual, tau, x, y, rng):
         rng = jax.random.fold_in(rng, jax.lax.axis_index(axis))
@@ -894,7 +868,7 @@ def make_bucketed_core(model, axis: str, cfg: ThresholdConfig, *,
         if mode in ("dense", "dense_rs"):
             no_shard: dict = {}
             hooks = {lk: _dense_bucket_hook(
-                model, is_graph, lk, axis, n_workers, gn, gn_t,
+                model, lk, axis, n_workers, gn, gn_t,
                 no_shard if mode == "dense" else _plan_for(rs_plan, lk),
                 full_gn=mode == "dense") for lk in params}
 
@@ -904,7 +878,7 @@ def make_bucketed_core(model, axis: str, cfg: ThresholdConfig, *,
 
             (loss, (new_state, _)), (upd_p, new_upd) = jax.value_and_grad(
                 lf, argnums=(0, 1), has_aux=True)(params, upd)
-            new_params = _apply_constraints_tree(model, is_graph, upd_p)
+            new_params = _apply_constraints_tree(model, upd_p)
             pstate = _pmean_state(new_state, axis)
             ploss = jax.lax.pmean(loss, axis)
             (new_params, new_upd, _, _, pstate, dv) = _exchange_diag(
@@ -917,7 +891,7 @@ def make_bucketed_core(model, axis: str, cfg: ThresholdConfig, *,
 
         if mode == "threshold":
             hooks = {lk: _threshold_bucket_hook(
-                model, is_graph, lk, axis, cfg, n_workers, gn, gn_t)
+                model, lk, axis, cfg, n_workers, gn, gn_t)
                 for lk in params}
             ctrl = {lk: _ctrl(tau[lk]) for lk in params}
 
@@ -930,12 +904,11 @@ def make_bucketed_core(model, axis: str, cfg: ThresholdConfig, *,
                 jax.value_and_grad(lf, argnums=(0, 1, 2, 3),
                                    has_aux=True)(params, upd, residual,
                                                  ctrl)
-            new_params = apply_decoded_updates(model, is_graph, params,
-                                               dhat)
+            new_params = apply_decoded_updates(model, params, dhat)
 
         elif mode == "threshold_rs":
             hooks = {lk: _threshold_rs_bucket_hook(
-                model, is_graph, lk, axis, cfg, n_workers, gn, gn_t,
+                model, lk, axis, cfg, n_workers, gn, gn_t,
                 _plan_for(rs_plan, lk), tree_elements(params[lk]))
                 for lk in params}
             ctrl = {lk: _ctrl(tau[lk]) for lk in params}
@@ -949,7 +922,7 @@ def make_bucketed_core(model, axis: str, cfg: ThresholdConfig, *,
                 jax.value_and_grad(lf, argnums=(0, 1, 2, 3),
                                    has_aux=True)(params, upd, residual,
                                                  ctrl)
-            new_params = _apply_constraints_tree(model, is_graph, upd_p)
+            new_params = _apply_constraints_tree(model, upd_p)
 
         else:
             raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
@@ -1060,7 +1033,7 @@ def tau_scalar(tau) -> float:
 
 
 def make_bucketed_step(model, axis: str, cfg: ThresholdConfig, *,
-                       n_workers: int, mode: str, is_graph: bool = False,
+                       n_workers: int, mode: str,
                        rs_plan: Optional[dict] = None, diag=None):
     """One bucketed sync step on per-layer (boundary) trees: packs
     ``stacked::`` runs for params, updater state, residual AND the
@@ -1068,8 +1041,7 @@ def make_bucketed_step(model, axis: str, cfg: ThresholdConfig, *,
     `make_threshold_step` with τ as a per-layer scalar tree (empty
     dicts for residual/τ in the dense modes)."""
     core = make_bucketed_core(model, axis, cfg, n_workers=n_workers,
-                              mode=mode, is_graph=is_graph,
-                              rs_plan=rs_plan, diag=diag)
+                              mode=mode, rs_plan=rs_plan, diag=diag)
     threshold_state = mode in ("threshold", "threshold_rs")
 
     def step(params, upd, state, it, residual, tau, x, y, rng):
@@ -1095,7 +1067,7 @@ def make_bucketed_step(model, axis: str, cfg: ThresholdConfig, *,
 
 
 def make_bucketed_multi(model, axis: str, cfg: ThresholdConfig, *,
-                        n_workers: int, mode: str, is_graph: bool = False,
+                        n_workers: int, mode: str,
                         rs_plan: Optional[dict] = None, diag=None):
     """k fused bucketed sync steps: ONE `lax.scan` whose carry is
     (params, updater state, layer state, iteration, residual, τ-tree)
@@ -1104,8 +1076,7 @@ def make_bucketed_multi(model, axis: str, cfg: ThresholdConfig, *,
     Per-step diag vectors ride the scan ys. Bit-identical to k per-step
     calls (same rng folds, same counters)."""
     core = make_bucketed_core(model, axis, cfg, n_workers=n_workers,
-                              mode=mode, is_graph=is_graph,
-                              rs_plan=rs_plan, diag=diag)
+                              mode=mode, rs_plan=rs_plan, diag=diag)
     threshold_state = mode in ("threshold", "threshold_rs")
 
     def multi(params, upd, state, it0, residual, tau, xs, ys, rngs):

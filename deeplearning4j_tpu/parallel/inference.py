@@ -54,11 +54,8 @@ class ParallelInference:
         repl = NamedSharding(mesh, P())
         sharded = NamedSharding(mesh, P(self.data_axis))
 
-        def fwd(params, state, x):
-            h, _, _, _, _ = model._forward_core(params, state, x, train=False, rng=None)
-            return h
-
-        self._fwd = jax.jit(fwd, in_shardings=(repl, repl, sharded),
+        self._fwd = jax.jit(model._forward_output,
+                            in_shardings=(repl, repl, sharded),
                             out_shardings=sharded)
 
     def _ensure_built(self):

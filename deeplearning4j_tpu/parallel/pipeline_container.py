@@ -40,6 +40,7 @@ import numpy as np
 from jax.sharding import Mesh
 
 from deeplearning4j_tpu.datasets.iterator import as_iterator
+from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.optimize.listeners import ComposedListeners
 from deeplearning4j_tpu.parallel.pipeline import pipeline_forward
 
@@ -101,7 +102,7 @@ class PipelineParallelTrainer:
         self.stats = stats
         if not model._initialized:
             model.init()
-        if not hasattr(model, "_forward_core"):
+        if not isinstance(model, MultiLayerNetwork):
             raise NotImplementedError(
                 "PipelineParallelTrainer stages MultiLayerNetwork stacks; "
                 "for a ComputationGraph, pipeline its repeated-block "
@@ -193,8 +194,9 @@ class PipelineParallelTrainer:
 
     # ------------------------------------------------------------ loss
     def _pp_loss(self, params, state, x, y, rng):
-        """Mirrors `MultiLayerNetwork._loss_fn` with the homogeneous
-        run executed by the GPipe schedule. Returns (loss, new_state)."""
+        """The container's loss with the homogeneous run executed by the
+        GPipe schedule: its forward core before the run, its output
+        loss after. Returns (loss, new_state)."""
         model = self.model
         r0, r1 = self.run
         S, per = self.n_stages, (r1 - r0) // self.n_stages
@@ -236,11 +238,10 @@ class PipelineParallelTrainer:
                              microbatches=self.microbatches,
                              data_axis=self.data_axis)
 
-        # epilog [r1, n): remaining hidden layers + output loss — the
-        # same tail structure as `MultiLayerNetwork._loss_fn`, incl.
-        # weight noise (the prolog gets it via `_forward_core`; without
-        # it here an epilog DropConnect layer would silently train
-        # different math than `model.fit`)
+        # epilog [r1, n): remaining hidden layers, incl. weight noise
+        # (the prolog gets it via `_forward_core`; without it here an
+        # epilog DropConnect layer would silently train different math
+        # than `model.fit`), then the container's own output loss
         from deeplearning4j_tpu.nn import scan_stack
         for i in range(r1, n - 1):
             layer = model.layers[i]
@@ -257,30 +258,9 @@ class PipelineParallelTrainer:
                 rng=lrng)
             if st:
                 new_state[str(i)] = st
-        if (n - 1) in model.conf.input_preprocessors:
-            h = model.conf.input_preprocessors[n - 1].pre_process(h, None)
-        out_layer = model.layers[-1]
-        si = str(n - 1)
-        lrng = None if rng is None else jax.random.fold_in(rng, n - 1)
-        # losses stay in output dtype (fp32 under a mixed policy) —
-        # same rule as the containers' _loss_fn
-        h = model.dtype.cast_output(h)
-        y = model.dtype.cast_output(jnp.asarray(y))
-        out_params = out_layer.apply_weight_noise(
-            model.dtype.cast_output_params(
-                model.dtype.cast_params(params.get(si, {}))), True,
-            None if lrng is None else jax.random.fold_in(lrng, 0x5EED))
-        loss = out_layer.compute_loss(out_params, state.get(si, {}),
-                                      h, y, train=True, rng=lrng)
-        reg = 0.0
-        for i, layer in enumerate(model.layers):
-            p = params.get(str(i))
-            if p:
-                reg = reg + layer.regularization_score(p)
-        for st in new_state.values():
-            if "aux_loss" in st:
-                reg = reg + st.pop("aux_loss")
-        return model.dtype.cast_output(loss) + reg, new_state
+        loss, _ = model._output_loss(params, state, h, None, y, rng, None,
+                                     new_state, train=True)
+        return loss, new_state
 
     # ------------------------------------------------------------ step
     def _build(self):

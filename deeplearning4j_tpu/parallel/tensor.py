@@ -216,10 +216,7 @@ class ShardedParallelTrainer:
         # _restore_fault_state (fault/), consumed by the next fit()
         self._resume_upd_r = None
         self._step = None
-        # ComputationGraph models pack features/labels as tuples
-        self._is_graph = not hasattr(model, "_forward_core")
-        if self._is_graph and (len(model.conf.network_inputs) != 1
-                               or len(model.conf.network_outputs) != 1):
+        if not model.single_io:
             raise NotImplementedError(
                 "ShardedParallelTrainer supports single-input single-"
                 "output graphs; train multi-io graphs via "
@@ -250,14 +247,9 @@ class ShardedParallelTrainer:
         model = self.model
         raw_step = model._make_train_step(tbptt=False)
 
-        if self._is_graph:
-            def step(params, upd, state, it, x, y, rng):
-                return raw_step(params, upd, state, it, (x,), (y,), rng,
-                                (None,), (None,), None)
-        else:
-            def step(params, upd, state, it, x, y, rng):
-                return raw_step(params, upd, state, it, x, y, rng,
-                                None, None, None)
+        def step(params, upd, state, it, x, y, rng):
+            return raw_step(params, upd, state, it, x, y, rng,
+                            None, None, None)
 
         self._build_shardings()
         self._step = jax.jit(
@@ -387,7 +379,6 @@ class ShardedParallelTrainer:
                  else gs.make_threshold_step)
         step = maker(
             self.model, axis, self.threshold_config, n_workers=n,
-            is_graph=self._is_graph,
             diag=self.model._diag,
             **({"mode": "threshold"} if self.bucketed else {}))
         self._build_shardings()
@@ -439,29 +430,10 @@ class ShardedParallelTrainer:
         _require_single_process("ShardedParallelTrainer.evaluate()")
         model = self.model
         self._build_shardings()
-        if not hasattr(model, "_forward_core"):
-            # ComputationGraph support here would need multi-input
-            # feature packing and per-output evaluators — score those
-            # per-output on the host or extend this when needed
-            if (len(model.conf.network_inputs) != 1
-                    or len(model.conf.network_outputs) != 1):
-                raise NotImplementedError(
-                    "ShardedParallelTrainer.evaluate supports single-"
-                    "input single-output graphs; evaluate multi-io "
-                    "graphs on the host via model.evaluate()")
         if getattr(self, "_eval_forward", None) is None:
-            if hasattr(model, "_forward_core"):  # MultiLayerNetwork
-                def fwd(params, state, x):
-                    h, _, _, _, _ = model._forward_core(
-                        params, state, x, train=False, rng=None)
-                    return h
-            else:  # single-in/out ComputationGraph
-                def fwd(params, state, x):
-                    acts, _, _, _ = model._forward_all(
-                        params, state, [x], train=False, rng=None)
-                    return acts[model.conf.network_outputs[0]]
             self._eval_forward = jax.jit(
-                fwd, in_shardings=(self._psh, self._repl, self._bsh),
+                model._forward_output,
+                in_shardings=(self._psh, self._repl, self._bsh),
                 out_shardings=self._bsh)
         params = gput_tree(model.params, self._psh)
         state = gput_tree(model.net_state, self._repl)

@@ -177,14 +177,10 @@ class ParallelTrainer:
         self._local_step = None
         self._local_multi = None
         self._average_fn = None
-        # ComputationGraph models: the bucketed engine supports
-        # single-input/single-output graphs (gradient_sharing's
-        # _local_loss_fn packs the tuples); multi-io graphs keep the
-        # GSPMD single-barrier dense program
-        self._is_graph = not hasattr(model, "_forward_core")
-        self._multi_io_graph = self._is_graph and (
-            len(model.conf.network_inputs) != 1
-            or len(model.conf.network_outputs) != 1)
+        # the bucketed engine differentiates `model.local_loss`, one
+        # features/labels pair; multi-io graphs keep the GSPMD
+        # single-barrier dense program
+        self._multi_io_graph = not model.single_io
 
     # ------------------------------------------------------------- sync mode
     def _build_sync_step(self):
@@ -235,7 +231,7 @@ class ParallelTrainer:
         mesh, axis = self.mesh, self.data_axis
         step = gs.make_threshold_step(
             self.model, axis, self.threshold_config,
-            n_workers=self.n_workers, is_graph=self._is_graph,
+            n_workers=self.n_workers,
             diag=self.model._diag)
         rep = P(axis)
         strip = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)
@@ -265,7 +261,7 @@ class ParallelTrainer:
         mesh, axis = self.mesh, self.data_axis
         multi = gs.make_threshold_multi(
             self.model, axis, self.threshold_config,
-            n_workers=self.n_workers, is_graph=self._is_graph,
+            n_workers=self.n_workers,
             diag=self.model._diag)
         rep = P(axis)
         strip = lambda t: jax.tree_util.tree_map(lambda a: a[0], t)
@@ -385,7 +381,7 @@ class ParallelTrainer:
         maker = gs.make_bucketed_multi if multi else gs.make_bucketed_step
         fn = maker(self.model, axis, self.threshold_config,
                    n_workers=self.n_workers, mode=mode,
-                   is_graph=self._is_graph, rs_plan=rs_plan,
+                   rs_plan=rs_plan,
                    diag=self.model._diag)
         per_replica_upd = mode != "dense"
         has_thr = mode in ("threshold", "threshold_rs")
@@ -690,12 +686,8 @@ class ParallelTrainer:
         state = _gput_tree(model.net_state, repl)
 
         if getattr(self, "_eval_forward", None) is None:
-            def fwd(params, state, x):
-                h, _, _, _, _ = model._forward_core(params, state, x,
-                                                    train=False, rng=None)
-                return h
             self._eval_forward = jax.jit(
-                fwd, in_shardings=(repl, repl, batch_sh),
+                model._forward_output, in_shardings=(repl, repl, batch_sh),
                 out_shardings=batch_sh)
 
         merged = evaluation if evaluation is not None else Evaluation()

@@ -119,11 +119,11 @@ def adapter_weight_keys(net) -> Dict[str, list]:
     """{layer_key: [param_key, ...]} of every weight the net's layers
     declare adapter-eligible (`Layer.adapter_weights()`)."""
     out = {}
-    for i, layer in enumerate(net.layers):
+    for lk, layer in net._keyed_layers():
         keys = [k for k in layer.adapter_weights()
-                if k in net.params.get(str(i), {})]
+                if k in net.params.get(lk, {})]
         if keys:
-            out[str(i)] = keys
+            out[lk] = keys
     return out
 
 
@@ -151,6 +151,7 @@ def init_adapter(net, *, rank: int, seed: int = 0) -> dict:
     if rank < 1:
         raise ValueError(f"adapter rank must be >= 1; got {rank}")
     plan = adapter_weight_keys(net)
+    index = {lk: i for i, (lk, _) in enumerate(net._keyed_layers())}
     root = jax.random.PRNGKey(seed)
     out: dict = {}
     for lk, keys in plan.items():
@@ -158,7 +159,7 @@ def init_adapter(net, *, rank: int, seed: int = 0) -> dict:
         for j, pk in enumerate(sorted(keys)):
             w = net.params[lk][pk]
             n_in, n_out = _leaf_shape(w)[-2], _leaf_shape(w)[-1]
-            key = jax.random.fold_in(jax.random.fold_in(root, int(lk)), j)
+            key = jax.random.fold_in(jax.random.fold_in(root, index[lk]), j)
             lp[pk] = {
                 "B": jnp.zeros((n_in, rank), jnp.float32),
                 "A": (jax.random.normal(key, (rank, n_out), jnp.float32)
@@ -197,7 +198,7 @@ def attach_adapter(net, adapter: dict, *, rank: int, alpha: float,
     new_upd = {lk: dict(lv) for lk, lv in net.updater_state.items()}
     from deeplearning4j_tpu.common.updaters import Sgd
     for lk, lv in adapter.items():
-        layer = net.layers[int(lk)]
+        layer = net.layer_for_key(lk)
         _check_layer_adaptable(layer, lk)
         updater = layer.updater or Sgd(1e-3)
         for pk, ba in lv.items():
@@ -248,7 +249,7 @@ def strip_adapter(net) -> dict:
             if isinstance(w, LoRAWeight):
                 adapter.setdefault(lk, {})[pk] = {"B": w.B, "A": w.A}
                 lv[pk] = w.base
-                layer = net.layers[int(lk)]
+                layer = net.layer_for_key(lk)
                 updater = layer.updater or Sgd(1e-3)
                 new_upd[lk][pk] = updater.init_state(w.base) \
                     if not isinstance(w.base, quant.QuantizedTensor) \

@@ -112,3 +112,102 @@ class TestFusedStateParity:
                     f"dynamic state {lk}/{sk} holds a {v.shape} array — "
                     f"too big to be disposable scratch; the fused path "
                     f"would silently drop it (see _multi_step_fn)")
+
+
+# ===================================================== one step, two containers
+# The fused step, the updater walk and the fit loop live once in
+# nn/trainable.py; a list container and the single-chain graph of the same
+# layers differ only in how they find a layer from a key and in whether a
+# batch is an array or a tuple — so from the same weights they must walk the
+# same trajectory, bit for bit.
+import jax
+import jax.numpy as jnp
+
+from deeplearning4j_tpu.nn.graph import (
+    ComputationGraph,
+    ComputationGraphConfiguration,
+)
+from deeplearning4j_tpu.nn.layers import DenseLayer
+from deeplearning4j_tpu.tenancy import lora
+
+
+def _chain_layers():
+    dense = [DenseLayer(n_in=6 if i == 0 else 16, n_out=16,
+                        activation="relu", updater=Adam(1e-2))
+             for i in range(4)]
+    return dense + [OutputLayer(n_in=16, n_out=3, activation="softmax",
+                                loss="mcxent", updater=Adam(1e-2))]
+
+
+def _list_chain():
+    b = NeuralNetConfiguration.builder().seed(7).list()
+    for layer in _chain_layers():
+        b = b.layer(layer)
+    return MultiLayerNetwork(b.build()).init()
+
+
+def _graph_chain():
+    g = ComputationGraphConfiguration.graph_builder().add_inputs("in")
+    names = ["d0", "d1", "d2", "d3", "out"]
+    for name, layer, src in zip(names, _chain_layers(), ["in"] + names):
+        g.add_layer(name, layer, src)
+    return ComputationGraph(g.set_outputs("out").build()).init(7), names
+
+
+def _like(net, names, tree):
+    """The list container's tree under the graph's node names, in fresh
+    buffers (fit donates its arguments)."""
+    return {names[int(k)]: jax.tree_util.tree_map(jnp.array, v)
+            for k, v in tree.items()}
+
+
+@pytest.mark.parametrize("fused_adam", [False, True])
+@pytest.mark.parametrize("spe", [1, 4])
+def test_list_and_graph_walk_one_trajectory(spe, fused_adam, monkeypatch):
+    monkeypatch.setenv("DL4J_PALLAS_KERNELS", "1" if fused_adam else "0")
+    x, y = _data(24)
+    net = _list_chain()
+    graph, names = _graph_chain()
+    graph.params = _like(net, names, net.params)
+    graph.updater_state = _like(net, names, net.updater_state)
+    # a packed run rides both steps, on the fused-Adam path when asked
+    assert net._packed_runs(net.params) == [["1", "2", "3"]]
+    assert graph._packed_runs(graph.params) == [["d1", "d2", "d3"]]
+    for model, run in ((net, ["1", "2", "3"]), (graph, ["d1", "d2", "d3"])):
+        assert bool(model._fused_state_runs([run], model.params)) == fused_adam
+    net.fit(x, y, epochs=1, batch_size=8, shuffle=False,
+            steps_per_execution=spe)
+    graph.fit(x, y, epochs=1, batch_size=8, steps_per_execution=spe)
+    assert net.iteration_count == graph.iteration_count == 3
+    assert net.score_value == graph.score_value
+    for ours, theirs in ((net.params, graph.params),
+                         (net.updater_state, graph.updater_state)):
+        theirs = {str(names.index(k)): v for k, v in theirs.items()}
+        assert (jax.tree_util.tree_structure(ours)
+                == jax.tree_util.tree_structure(theirs))
+        for a, b in zip(jax.tree_util.tree_leaves(ours),
+                        jax.tree_util.tree_leaves(theirs)):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def test_graph_frozen_adapter_holds_every_base_leaf():
+    """The frozen-base branch of `_apply_updates` serves the graph too
+    (it lived in the list container's copy only): with a frozen
+    `LoRAWeight` attached, one step moves the adapter and nothing else —
+    wrapped weights, biases, the packed run's other members, the output
+    layer."""
+    graph, _ = _graph_chain()
+    x, y = _data(8)
+    before = {lk: {pk: np.asarray(v).tobytes() for pk, v in lv.items()}
+              for lk, lv in graph.params.items()}
+    adapter = lora.init_adapter(graph, rank=2, seed=5)
+    assert set(adapter) == set(graph.params)      # every node's W
+    lora.attach_adapter(graph, adapter, rank=2, alpha=4.0, frozen=True)
+    graph.fit(x, y, epochs=1, batch_size=8)
+    w = graph.params["d1"]["W"]                   # a packed run's member
+    assert type(w).__name__ == "LoRAWeight"
+    assert np.asarray(w.B).any(), "the adapter never moved"
+    after = {lk: {pk: np.asarray(getattr(v, "base", v)).tobytes()
+                  for pk, v in lv.items()}
+             for lk, lv in graph.params.items()}
+    assert after == before

@@ -566,7 +566,7 @@ class TestBucketedGraphContainer:
             net = self._graph()
             t = ParallelTrainer(net, device_mesh(), mode="sync",
                                 bucketed=bucketed)
-            assert t._is_graph and not t._multi_io_graph
+            assert net.single_io and not t._multi_io_graph
             t.fit(x, y, epochs=4, batch_size=32)
             return float(net.score(ds))
 

@@ -373,7 +373,7 @@ class TestTransformerStreamingDepth:
         full = np.asarray(net.output(ids))
 
         carries = {n: layer.init_carry(2, jnp.float32)
-                   for n, layer in net._recurrent_nodes()}
+                   for n, layer in net._recurrent_layers()}
         for t in range(T):
             acts, _, _, _ = net._forward_all(
                 net.params, net.net_state, [ids[:, t:t + 1]],
