@@ -84,11 +84,14 @@ class DataTypePolicy:
         """Cast one array to the compute dtype. Non-floating inputs
         (int token ids for embeddings, bool masks) pass through
         UNCHANGED — a bf16 cast would corrupt ids above 256."""
-        if (hasattr(x, "dtype")
+        return x.astype(self.compute_dtype) if self.casts(x) else x
+
+    def casts(self, x) -> bool:
+        """Would `cast_compute` change `x`: a floating array in another
+        dtype than the compute dtype."""
+        return (hasattr(x, "dtype")
                 and jnp.issubdtype(x.dtype, jnp.floating)
-                and x.dtype != self.compute_dtype):
-            return x.astype(self.compute_dtype)
-        return x
+                and x.dtype != self.compute_dtype)
 
     def cast_output(self, x):
         if (hasattr(x, "dtype")

@@ -43,6 +43,7 @@ the weight-HBM-byte reduction on the real decode program.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -187,25 +188,62 @@ def quantize_net_params(net, mode: str = "int8"):
     return out
 
 
-def serving_params(net, quantize_mode: Optional[str]):
-    """Resolve the params tree a serving/generation program should
-    read: `net.params` when `quantize_mode` is None, else the cached
-    quantized copy (one quantization pass per net per mode — re-used
-    by prefill, decode, and admission programs alike). The cache is
-    keyed on the IDENTITY of `net.params`: every fit()/restore
-    reassigns that tree, which invalidates the quantized copy — a
-    fine-tuned net must never silently serve pre-training int8
-    weights while its fp path serves the fresh ones."""
-    if quantize_mode is None:
-        return net.params
-    cache = net.__dict__.get("_quantized_params_cache")
+# one program for all the leaves of a tree, cached by the policy and
+# the leaves' shapes: a tree reassigned with the same shapes is cast by
+# the program the first one compiled
+@functools.partial(jax.jit, static_argnums=0)
+def _cast_leaves(policy, leaves):
+    return policy.cast_params(leaves)
+
+
+def compute_copy(policy, tree):
+    """`tree` as a serving program computes on it. Under a mixed policy
+    (float32 masters, bfloat16 compute) a copy whose floating leaves
+    are in the compute dtype, made by ONE jitted call over the leaves
+    that differ; a leaf already there (an int8 `q`, a tenant's shared
+    base) is the same object in the copy, so nothing is held twice.
+    Not mixed (`float32`, `bf16_params`): `tree` itself. The value a
+    program reads is the one its own `cast_params` would have made of
+    the master, in every step; on the copy that call traces nothing."""
+    if not policy.is_mixed:
+        return tree
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    at = [i for i, x in enumerate(leaves) if policy.casts(x)]
+    if not at:
+        return tree
+    for i, x in zip(at, _cast_leaves(policy, [leaves[i] for i in at])):
+        leaves[i] = x
+    return jax.tree_util.tree_unflatten(treedef, leaves)
+
+
+def serving_tree(net, quantize_mode: Optional[str]):
+    """-> (the params tree a serving/generation program should read,
+    its bytes on the device). The tree is `net.params` in the net's
+    compute dtype (`compute_copy`: the masters themselves unless the
+    policy is mixed), with the declared matmul weights quantized first
+    under `quantize_mode`. One pass per net per mode, re-used by
+    prefill, decode, and admission programs alike. The cache is keyed
+    on the IDENTITY of `net.params`: every fit()/restore/hot swap
+    reassigns that tree, which drops the copies made of the old one —
+    a fine-tuned net must never silently serve pre-training weights —
+    and the next call makes them anew. `net.params` is never touched."""
+    cache = net.__dict__.get("_serving_params_cache")
     if cache is None or cache["source"] is not net.params:
-        cache = net.__dict__["_quantized_params_cache"] = {
+        cache = net.__dict__["_serving_params_cache"] = {
             "source": net.params, "trees": {}}
     trees = cache["trees"]
     if quantize_mode not in trees:
-        trees[quantize_mode] = quantize_net_params(net, quantize_mode)
+        tree = (net.params if quantize_mode is None
+                else quantize_net_params(net, quantize_mode))
+        tree = compute_copy(net.dtype, tree)
+        trees[quantize_mode] = (tree, weight_bytes(tree))
     return trees[quantize_mode]
+
+
+def serving_params(net, quantize_mode: Optional[str]):
+    """The tree of `serving_tree`: what every dispatch resolves (a dict
+    lookup once made)."""
+    return serving_tree(net, quantize_mode)[0]
 
 
 def weight_bytes(params_tree) -> int:

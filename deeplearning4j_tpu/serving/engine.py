@@ -40,7 +40,12 @@ Weights (`quantize="int8"`): the decode/prefill/admission programs
 read per-output-channel int8 matmul weights (nd/quant.py) from HBM and
 compute in the policy's compute dtype — autoregressive decode is
 bandwidth-bound, so the ~4x weight-byte cut is the serving throughput
-lever. `net.params` (the training master) is untouched.
+lever. `net.params` (the training master) is untouched. A net whose
+policy is mixed (float32 masters, bfloat16 compute) is served from ONE
+copy of its weights in the compute dtype, cast when the engine is
+built and again only when `net.params` is reassigned
+(`quant.serving_tree`): a decode step reads every weight, and reads it
+at the width it computes on. A net that is not mixed is read in place.
 
 Decode-parity contract (docs/SERVING.md): for the same prompt and
 sampling config, the token stream is identical to whole-batch
@@ -201,10 +206,11 @@ class PagedDecodeEngine:
                 f"prefix_cache must be 'registered' or 'radix'; "
                 f"got {prefix_cache!r}")
         self.prefix_cache_mode = prefix_cache
-        # pay the quantization pass NOW, not inside the first live
-        # dispatch (the tree itself is resolved per dispatch — see
+        # pay the pass that makes the serving tree (quantization, a
+        # mixed net's compute-dtype copy) NOW, not inside the first
+        # live dispatch (the tree itself is resolved per dispatch — see
         # the _params property)
-        quant.serving_params(net, quantize)
+        quant.serving_tree(net, quantize)
         # the per-sequence budget: the smallest length a layer of the
         # net bounds the paged path to (a positional table's `max_len`,
         # the `cache_len` a block's prefill carry has) and the server's
@@ -429,8 +435,11 @@ class PagedDecodeEngine:
         # of the decode step the last `step` / `step_ahead` / `drain`
         # call read back: the share of the slots' tables its attention
         # read, and whether an earlier step was still unread when it
-        # was launched; `launched`: did that call dispatch a step itself
+        # was launched; `launched`: did that call dispatch a step itself;
+        # `weight_gb`: the bytes / 1e9 of the params tree its program
+        # was given (reckoned when the tree was made, not a step)
         self.kv_read_pct = 0.0
+        self.weight_gb = 0.0
         self.overlapped = False
         self.launched = False
         # positions the last decode dispatch's attention read (summed
@@ -445,12 +454,13 @@ class PagedDecodeEngine:
     # ------------------------------------------------------------ queries
     @property
     def _params(self):
-        """The params tree every serving program reads: int8-quantized
-        matmul weights under quantize="int8" (nd/quant.py), the net's
-        own tree otherwise — resolved PER DISPATCH, so a fit()/restore
-        between dispatches serves the fresh weights (serving_params'
-        identity-keyed cache makes this a dict lookup; quantization
-        re-runs only when net.params was reassigned)."""
+        """The params tree every serving program reads
+        (`quant.serving_tree`): the net's own tree, or under a mixed
+        policy its one copy in the compute dtype, with int8-quantized
+        matmul weights under quantize="int8" — resolved PER DISPATCH,
+        so a fit()/restore between dispatches serves the fresh weights
+        (the identity-keyed cache makes this a dict lookup; the copy is
+        made again only when net.params was reassigned)."""
         return quant.serving_params(self.net, self.quantize)
 
     @property
@@ -1889,8 +1899,10 @@ class PagedDecodeEngine:
             kv_read_pct, positions_read = self._kv_read()
             # was the step before this one still unread at the launch?
             overlapped = self._flight is not None
+            params, weight_bytes = quant.serving_tree(self.net,
+                                                      self.quantize)
             kv, toks, moe, self._carry = decode(
-                self._params, self.net.net_state, self.pool.kv,
+                params, self.net.net_state, self.pool.kv,
                 *self._decode_args())
             self.pool.kv = kv
             self.launched = True
@@ -1928,6 +1940,7 @@ class PagedDecodeEngine:
                         taken=taken, finished=finished,
                         kv_read_pct=kv_read_pct,
                         positions_read=positions_read,
+                        weight_gb=weight_bytes / 1e9,
                         overlapped=overlapped)
 
     def _collect(self):
@@ -1943,6 +1956,7 @@ class PagedDecodeEngine:
         with monitor.span("serve/decode/post", it=it):
             self.kv_read_pct = flight["kv_read_pct"]
             self.positions_read = flight["positions_read"]
+            self.weight_gb = flight["weight_gb"]
             self.overlapped = flight["overlapped"]
             taken = flight["taken"]
             emitted: Dict[int, List[int]] = {}
@@ -2112,8 +2126,11 @@ class PagedDecodeEngine:
             greedy_only = not bool((self.temp[self.active] > 0).any())
             use_rs = self.spec_sampled and not greedy_only
             score = self._get_score(K, "rs" if use_rs else greedy_only)
+            params, weight_bytes = quant.serving_tree(self.net,
+                                                      self.quantize)
+            self.weight_gb = weight_bytes / 1e9
             out = score(
-                self._params, self.net.net_state, self.pool.kv,
+                params, self.net.net_state, self.pool.kv,
                 jnp.asarray(self.block_tables), jnp.asarray(token_mat),
                 jnp.asarray(self.pos), jnp.asarray(n_valid),
                 jnp.asarray(self.keys), jnp.asarray(self.emit_idx),

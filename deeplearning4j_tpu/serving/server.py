@@ -914,6 +914,13 @@ class GenerationServer(ParallelInference):
                 "/ (n_slots x max_blocks): 100 where it gathers every "
                 "slot's whole table",
                 buckets=(1, 2, 5, 10, 20, 35, 50, 75, 100), **lbl),
+            "weight_gb": reg.histogram(
+                "serving_decode_weight_gb",
+                "bytes / 1e9 of the params tree a decode dispatch's "
+                "program was given: a mixed net's compute-dtype copy, "
+                "the net's own tree otherwise",
+                buckets=(0.001, 0.01, 0.1, 0.5, 1, 2, 4, 8, 16, 32),
+                **lbl),
             "overlap_pct": reg.histogram(
                 "serving_decode_overlap_pct",
                 "at each decode launch, 100 if an earlier decode step "
@@ -1186,6 +1193,7 @@ class GenerationServer(ParallelInference):
                     m["tokens"].inc(n_tok)
                     m["batch_slots"].observe(len(emitted))
                     m["kv_read_pct"].observe(eng.kv_read_pct)
+                    m["weight_gb"].observe(eng.weight_gb)
                     m["overlap_pct"].observe(
                         100.0 if eng.overlapped else 0.0)
                     self._observe_layer_counts(eng, m, decode=True)

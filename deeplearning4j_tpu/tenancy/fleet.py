@@ -13,7 +13,10 @@ composed over a single shared base net held in this process:
   composing a tenant allocates the rank-r factors and a tree spine,
   nothing else. With `quantize="int8"` the base is quantized ONCE
   (`quant.serving_params` on the base net) and tenants share the int8
-  copy — int8 base + fp adapter, composed inside the matmul.
+  copy — int8 base + fp adapter, composed inside the matmul. A base
+  whose policy is mixed is cast to its compute dtype ONCE the same way,
+  and a tenant's own serving tree adds only its factors' cast: a base
+  leaf already in the compute dtype is the same object there.
 - **Composed-params cache.** Keyed on
   `(base version, adapter version, quantize mode)` and on the
   IDENTITY of the base net's params tree (the

@@ -619,6 +619,210 @@ class TestQuantizedDecode:
             np.testing.assert_array_equal(got[r], ref)
 
 
+class TestMixedPolicyServingCopy:
+    """A net whose policy is mixed (float32 masters, bfloat16 compute)
+    is served from ONE copy of its weights in the compute dtype, cast
+    when the tree is first asked for and again only when `net.params`
+    is another object; a net that is not mixed is read in place
+    (nd/quant.py `serving_tree`). The programs compute on the operands
+    their own `cast_params` made of the masters: streams bit-equal."""
+
+    @staticmethod
+    def lm(policy, seed=3):
+        from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
+        conf = TransformerLM(vocab_size=V, d_model=D, n_layers=LAYERS,
+                             n_heads=HEADS, max_len=MAXLEN,
+                             seed=seed).conf()
+        conf.dtype_policy = policy
+        return MultiLayerNetwork(conf).init()
+
+    @staticmethod
+    def dtypes(tree):
+        import jax
+        return {str(x.dtype) for x in jax.tree_util.tree_leaves(tree)}
+
+    @pytest.fixture(scope="class")
+    def mixed(self):
+        return self.lm("mixed_bf16")
+
+    def test_engine_reads_one_bf16_copy_and_masters_stay(self, mixed,
+                                                         prompts):
+        eng = PagedDecodeEngine(mixed, n_slots=2, n_blocks=8, block_len=BL)
+        served = eng._params
+        assert served is not mixed.params
+        assert self.dtypes(served) == {"bfloat16"}
+        assert self.dtypes(mixed.params) == {"float32"}
+        slot, _, _ = eng.admit(prompts[0], 4)
+        eng.step()
+        assert eng._params is served
+        eng.step()
+        assert eng._params is served, "the copy was remade between steps"
+        # the copy holds the values the in-program cast made of the
+        # masters, leaf for leaf
+        import jax
+        for m, c in zip(jax.tree_util.tree_leaves(mixed.params),
+                        jax.tree_util.tree_leaves(served)):
+            np.testing.assert_array_equal(
+                np.asarray(m.astype(jnp.bfloat16).astype(jnp.float32)),
+                np.asarray(c.astype(jnp.float32)))
+
+    @pytest.mark.parametrize("policy", ["float32", "bf16_params"])
+    def test_a_net_that_is_not_mixed_is_read_in_place(self, policy):
+        from deeplearning4j_tpu.nd import quant
+        net = self.lm(policy)
+        assert quant.serving_params(net, None) is net.params
+        eng = PagedDecodeEngine(net, n_slots=1, n_blocks=4, block_len=BL)
+        assert eng._params is net.params
+        tree, nbytes = quant.serving_tree(net, None)
+        assert tree is net.params
+        assert nbytes == quant.weight_bytes(net.params)
+
+    def test_reassigned_params_are_cast_once_more(self, monkeypatch):
+        import jax
+
+        from deeplearning4j_tpu.nd import quant
+        net = self.lm("mixed_bf16", seed=5)
+        calls = []
+        cast = quant._cast_leaves
+        monkeypatch.setattr(
+            quant, "_cast_leaves",
+            lambda policy, leaves: calls.append(len(leaves))
+            or cast(policy, leaves))
+        first = quant.serving_params(net, None)
+        assert quant.serving_params(net, None) is first
+        n_leaves = len(jax.tree_util.tree_leaves(net.params))
+        assert calls == [n_leaves]          # one call, every leaf in it
+        net.params = jax.tree_util.tree_map(lambda x: x * 2, net.params)
+        second = quant.serving_params(net, None)
+        assert second is not first
+        assert quant.serving_params(net, None) is second
+        assert calls == [n_leaves, n_leaves]
+        np.testing.assert_array_equal(
+            np.asarray(second["0"]["W"].astype(jnp.float32)),
+            np.asarray(net.params["0"]["W"].astype(jnp.bfloat16)
+                       .astype(jnp.float32)))
+        assert self.dtypes(net.params) == {"float32"}
+
+    def test_a_leaf_already_in_the_compute_dtype_is_not_copied(self):
+        """What keeps a tenant's composed tree (bases shared with the
+        base net's serving copy) and an int8 tree's `q` at one copy."""
+        from deeplearning4j_tpu.nd import dtype as dt, quant
+        held = jnp.ones((4, 4), jnp.bfloat16)
+        ids = jnp.arange(4)
+        tree = {"a": {"W": held, "b": jnp.ones((4,), jnp.float32)},
+                "ids": ids}
+        out = quant.compute_copy(dt.mixed_bf16(), tree)
+        assert out["a"]["W"] is held and out["ids"] is ids
+        assert out["a"]["b"].dtype == jnp.bfloat16
+        assert tree["a"]["b"].dtype == jnp.float32
+        assert quant.compute_copy(dt.bf16_params(), tree) is tree
+        assert quant.compute_copy(dt.DataTypePolicy(), tree) is tree
+
+    def test_a_tenants_tree_shares_the_mixed_bases_copy(self, mixed):
+        """tenancy/fleet.py composes a tenant's params over the base
+        net's serving tree; the tenant's own serving tree (its engine's
+        `_params`) holds those base leaves themselves and casts only
+        the adapter's factors."""
+        from deeplearning4j_tpu.nd import quant
+        from deeplearning4j_tpu.tenancy import lora
+        from deeplearning4j_tpu.tenancy.fleet import _TenantNetView
+        base = quant.serving_params(mixed, None)
+        adapter = lora.init_adapter(mixed, rank=2, seed=1)
+        view = _TenantNetView(mixed, lora.compose_params(
+            base, adapter, rank=2, alpha=4.0))
+        served = quant.serving_params(view, None)
+        assert self.dtypes(served) == {"bfloat16"}
+        n = 0
+        for lk, lv in served.items():
+            for pk, w in lv.items():
+                if isinstance(w, lora.LoRAWeight):
+                    assert w.base is base[lk][pk]
+                    n += 1
+                else:
+                    assert w is base[lk][pk]
+        assert n > 0
+
+    def test_int8_tree_of_a_mixed_net_is_cast_with_it(self, mixed):
+        from deeplearning4j_tpu.nd import quant
+        qp = quant.serving_params(mixed, "int8")
+        assert quant.serving_params(mixed, "int8") is qp
+        assert self.dtypes(qp) == {"int8", "bfloat16"}
+        prompts = np.random.default_rng(5).integers(0, V, (2, 3))
+        eng = PagedDecodeEngine(mixed, n_slots=2, n_blocks=8,
+                                block_len=BL, quantize="int8")
+        out = {0: [], 1: []}
+        slot2req = {}
+        for r in range(2):
+            slot, first, _ = eng.admit(prompts[r], 5)
+            slot2req[slot] = r
+            out[r].append(first)
+        drain_engine(eng, slot2req, out)
+        ref = generate(mixed, prompts, 5, temperature=0, quantize="int8")
+        for r in range(2):
+            np.testing.assert_array_equal(out[r], ref[r])
+
+    @pytest.mark.parametrize("sampled", [False, True],
+                             ids=["greedy", "sampled"])
+    def test_decode_program_is_bit_equal_on_masters_and_copy(
+            self, mixed, prompts, sampled):
+        """The decode program (GPT-2 block) given the raw float32 tree
+        and given the serving copy: the same tokens, the same pool."""
+        import jax
+        eng = PagedDecodeEngine(mixed, n_slots=2, n_blocks=8, block_len=BL)
+        kw = (dict(temperature=0.8, top_p=0.95,
+                   rng=np.asarray([0, 7], np.uint32)) if sampled else {})
+        eng.admit(prompts[0], 4, **kw)
+        eng.admit(prompts[1], 4)
+        step = jax.jit(eng._decode_body(greedy_only=not sampled))
+        rest = (mixed.net_state, eng.pool.kv, *eng._decode_args())
+        on_masters = step(mixed.params, *rest)
+        on_copy = step(eng._params, *rest)
+        a, b = (jax.tree_util.tree_leaves(t) for t in (on_masters, on_copy))
+        assert len(a) == len(b) > 2
+        for x, y in zip(a, b):
+            assert x.dtype == y.dtype
+            np.testing.assert_array_equal(
+                np.asarray(x.astype(jnp.float32)),
+                np.asarray(y.astype(jnp.float32)))
+
+    def test_server_streams_equal_generate(self, mixed, prompts):
+        ref = generate(mixed, prompts, 6, temperature=0)
+        srv = GenerationServer(mixed, n_slots=2, n_blocks=16,
+                               block_len=BL).start()
+        try:
+            streams = [srv.generate_async(prompts[r], 6) for r in range(6)]
+            for r, s in enumerate(streams):
+                np.testing.assert_array_equal(s.result(timeout=120), ref[r])
+        finally:
+            srv.stop()
+        assert self.dtypes(mixed.params) == {"float32"}
+
+    @pytest.mark.parametrize("policy", ["mixed_bf16", "float32"])
+    def test_weight_gb_family_reads_the_served_tree(self, policy, prompts):
+        from deeplearning4j_tpu import monitor
+        from deeplearning4j_tpu.monitor.registry import MetricsRegistry
+        from deeplearning4j_tpu.nd import quant
+        net = self.lm(policy)
+        reg = monitor.enable(registry=MetricsRegistry())
+        srv = GenerationServer(net, n_slots=2, n_blocks=16,
+                               block_len=BL).start()
+        try:
+            for s in [srv.generate_async(prompts[r], 6) for r in range(3)]:
+                s.result(timeout=120)
+        finally:
+            srv.stop()
+            monitor.disable()
+            monitor._STATE.registry = monitor.GLOBAL_REGISTRY
+        snap = reg.snapshot()
+        fam = snap["serving_decode_weight_gb"]["values"][0]
+        steps = snap["serving_decode_batch_slots"]["values"][0]["count"]
+        assert fam["count"] == steps > 0
+        master = quant.weight_bytes(net.params)
+        want = master / 2 if policy == "mixed_bf16" else master
+        assert fam["sum"] / fam["count"] == pytest.approx(want / 1e9)
+        assert srv.engine.weight_gb == want / 1e9
+
+
 class TestSampledDeterminism:
     def test_same_stream_alone_or_batched(self, net, prompts):
         """The serving rng contract: token t of a request derives from

@@ -110,6 +110,35 @@ print(json.dumps({"kernels": names, "in_place": list(eng._in_place),
 '''
 
 
+_DECODE_WEIGHTS = '''
+# the same decode step, given the float32 masters and given the tree
+# the engine serves from (a mixed net's one bfloat16 copy)
+from deeplearning4j_tpu.nd import quant
+from deeplearning4j_tpu.serving import PagedDecodeEngine
+net = lm(vocab_size=128, d_model=128, n_layers=2, n_heads=2, max_len=64)
+eng = PagedDecodeEngine(net, n_slots=4, n_blocks=16, block_len=16)
+rest = (tree(net.net_state), tree(eng.pool.kv)) + tuple(
+    sds(a) for a in eng._decode_args())
+dims = {",".join(map(str, a.shape))
+        for a in jax.tree_util.tree_leaves(net.params)}
+out = {}
+for name, params in (("masters", net.params), ("served", eng._params)):
+    c = jax.jit(eng._decode_body(greedy_only=True),
+                donate_argnums=2).lower(tree(params), *rest).compile()
+    hlo = c.as_text()
+    out[name] = {
+        "f32_weight_args": sum(
+            d in dims for d in re.findall(
+                r"= f32\\[([0-9,]+)\\]\\S* parameter\\(", hlo)),
+        "weight_converts": sum(
+            d in dims for d in re.findall(
+                r"= bf16\\[([0-9,]+)\\]\\S* convert\\(", hlo)),
+        "argument_bytes": c.memory_analysis().argument_size_in_bytes}
+out["master_bytes"] = quant.weight_bytes(net.params)
+print(json.dumps(out))
+'''
+
+
 _MLA_DECODE = '''
 # sarvam-105b's decode attention at its published widths: 32 slots, 64
 # heads, a cache row of 576 padded to 640 lanes, bf16 pages of 64
@@ -197,6 +226,24 @@ def test_decode_step_attends_over_the_pool_in_place_on_v5e():
     assert out["in_place"] == [True, True]
     assert KERNEL_NAME in out["kernels"]
     assert out["pool_seen"] and out["pool_copies"] == 0
+
+
+def test_decode_step_reads_the_served_copy_and_casts_no_weight_on_v5e():
+    """Given the tree a mixed net is served from, the compiled decode
+    program has no float32 argument of a weight's shape and no
+    float32 -> bfloat16 `convert` of one (given the masters it has one
+    of each a leaf), and its arguments are smaller by half the
+    masters' bytes (`memory_analysis()`)."""
+    proc, out = _child(_DECODE_WEIGHTS)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["masters"]["f32_weight_args"] > 0
+    assert out["masters"]["weight_converts"] > 0
+    assert out["served"]["f32_weight_args"] == 0
+    assert out["served"]["weight_converts"] == 0
+    # to the tile padding of the small leaves
+    assert (out["masters"]["argument_bytes"]
+            - out["served"]["argument_bytes"]) == pytest.approx(
+                out["master_bytes"] / 2, rel=0.02)
 
 
 def test_latent_decode_kernel_compiles_at_published_widths_on_v5e():
