@@ -240,7 +240,7 @@ def _expected_family(layer: Layer) -> str:
     if name in ("lstm", "graves_lstm", "graves_bidirectional_lstm", "simple_rnn",
                 "rnn_output", "convolution1d", "subsampling1d", "zeropadding1d",
                 "upsampling1d", "last_time_step", "multi_head_attention",
-                "lm_head"):
+                "lm_head", "tied_lm_head"):
         return "rnn"
     if name in ("batchnorm", "activation", "dropout_layer", "global_pooling",
                 "loss", "reshape", "permute", "layernorm",
@@ -248,7 +248,8 @@ def _expected_family(layer: Layer) -> str:
                 # position; positional-encoding/transformer blocks keep
                 # [B,T,D] — none of them wants a time-flattening insert
                 "embedding", "positional_encoding", "transformer_encoder",
-                "latent_attention_block", "rms_norm"):
+                "latent_attention_block", "rms_norm",
+                "parallel_attention_moe_block", "gain_layer_norm"):
         return "any"
     return "ff"
 

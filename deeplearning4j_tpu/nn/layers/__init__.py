@@ -68,3 +68,8 @@ from deeplearning4j_tpu.nn.layers.latent import (
     LMHead,
     RMSNormLayer,
 )
+from deeplearning4j_tpu.nn.layers.parallel import (
+    GainLayerNorm,
+    ParallelAttentionMoEBlock,
+    TiedLMHead,
+)

@@ -384,12 +384,7 @@ class LatentAttentionBlock(BaseRecurrentLayer):
             flat, chosen, gates, params["e_gate"], params["e_up"],
             params["e_down"], first=self.held_first,
             valid=None if valid is None else valid.reshape(-1))
-        if stats is not None:
-            rows, ratio = moe.expert_load_stats(sizes)
-            stats["moe_rows"] = stats.get("moe_rows", 0.0) + rows
-            stats["moe_load_max_over_mean"] = stats.get(
-                "moe_load_max_over_mean", 0.0) + ratio
-            stats["moe_layers"] = stats.get("moe_layers", 0) + 1
+        moe.record_load(stats, sizes)
         shared = swiglu(h, params["s_gate"], params["s_up"], params["s_down"])
         return x + y.reshape(B, T, D) + shared
 

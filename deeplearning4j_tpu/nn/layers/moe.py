@@ -193,3 +193,26 @@ def expert_load_stats(sizes):
     mean = rows / sizes.shape[0]
     return rows, jnp.where(rows > 0, jnp.max(sizes) / jnp.maximum(mean, 1e-9),
                            0.0)
+
+
+def record_load(stats, sizes):
+    """Add one routed expert layer's `expert_load_stats` to `stats` (a
+    dict a traced forward hands down, or None): the serving engine
+    reads the means over the layers back with the tokens."""
+    if stats is None:
+        return
+    rows, ratio = expert_load_stats(sizes)
+    stats["moe_rows"] = stats.get("moe_rows", 0.0) + rows
+    stats["moe_load_max_over_mean"] = stats.get(
+        "moe_load_max_over_mean", 0.0) + ratio
+    stats["moe_layers"] = stats.get("moe_layers", 0) + 1
+
+
+def shared_experts_mean(h, w_gate, w_up, w_down, n_shared: int):
+    """`(1/n) sum_j SwiGLU_j(h)`: the average of `n_shared` shared
+    experts held side by side, w_gate and w_up [D, n*F] (expert j's F
+    columns at `j*F`), w_down [n*F, D]: one product `n*F` wide, the
+    `1/n` on its output."""
+    a = jnp.matmul(h, w_gate)
+    y = jnp.matmul(jax.nn.silu(a) * jnp.matmul(h, w_up), w_down)
+    return y if n_shared == 1 else y * (1.0 / n_shared)

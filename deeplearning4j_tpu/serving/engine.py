@@ -75,6 +75,7 @@ from deeplearning4j_tpu.serving.paged import (
     PagedKVPool,
     RadixPrefixCache,
     blocks_needed,
+    plan_table,
 )
 
 
@@ -103,13 +104,16 @@ def _moe_means(stats: dict):
 class Slot:
     """Host mirror of one serving slot's in-flight sequence."""
 
-    __slots__ = ("request_id", "blocks", "prompt_len", "n_tokens",
-                 "emitted", "pos", "emit_base", "history")
+    __slots__ = ("request_id", "blocks", "window_blocks", "prompt_len",
+                 "n_tokens", "emitted", "pos", "emit_base", "history")
 
     def __init__(self, request_id, blocks, prompt_len, n_tokens,
-                 emit_base=0, history=None):
+                 emit_base=0, history=None, window_blocks=None):
         self.request_id = request_id
         self.blocks = blocks
+        # the ring of blocks the window layers keep for the slot (their
+        # own pool's ids); None for a net with no window layer
+        self.window_blocks = window_blocks
         self.prompt_len = prompt_len
         self.n_tokens = n_tokens
         self.emitted = 0
@@ -154,7 +158,8 @@ class PagedDecodeEngine:
                  prefix_cache: str = "registered",
                  max_positions: Optional[int] = None,
                  max_prefill_tokens: Optional[int] = None,
-                 min_prefill_bucket: int = 1):
+                 min_prefill_bucket: int = 1,
+                 window_blocks: Optional[int] = None):
         if not getattr(net, "_initialized", False):
             net.init()
         self.net = net
@@ -252,8 +257,23 @@ class PagedDecodeEngine:
                 f"speculative depth {self.spec_k} exceeds the stream "
                 f"budget {budget} — no slot could ever take a full-"
                 f"depth dispatch")
-        self.pool = PagedKVPool(net, n_blocks, block_len)
+        self.pool = PagedKVPool(net, n_blocks, block_len, window_blocks)
         self.block_len = int(block_len)
+        # two kinds of cache: the window layers' ring of blocks a slot
+        # (`window_ring` table columns, their own pool and allocator);
+        # 0 for a net with no window layer, whose programs and tables
+        # are then what they always were
+        self.window_ring = (0 if self.pool.window is None
+                            else self.pool.ring_blocks(self.max_blocks))
+        if self.window_ring:
+            if prefix_cache == "radix":
+                self._refuse_two_pools("the radix prefix cache")
+            if self.spec_k is not None and self.spec_k > self.block_len + 1:
+                raise ValueError(
+                    f"speculative depth {self.spec_k} exceeds block_len + 1 "
+                    f"= {self.block_len + 1}: the k writes of one score "
+                    f"dispatch would reach back into a block of a window "
+                    f"layer's ring that its first query still reads")
         # per transformer block: do the single-token programs attend
         # over the pool in place (`dl4tpu_paged_decode`) or gather it?
         # The layer decides when a program is traced, from the kernels'
@@ -293,7 +313,12 @@ class PagedDecodeEngine:
         pool_j = 0
         for i, layer in enumerate(net.layers):
             if getattr(layer, "paged_cache", False):
-                self._plan.append(("block", i, pool_j))
+                # a two-pool net's entries also say which table the
+                # layer reads: 0 the full one, 1 the window layers' ring
+                self._plan.append(
+                    ("block", i, pool_j,
+                     int(self.pool.window_layers[pool_j]))
+                    if self.window_ring else ("block", i, pool_j))
                 pool_j += 1
             elif isinstance(layer, PositionalEncodingLayer):
                 self._plan.append(("pos", i))
@@ -328,6 +353,8 @@ class PagedDecodeEngine:
         # host slot state (uploaded per step; a few [S] vectors)
         S = self.n_slots
         self.block_tables = np.zeros((S, self.max_blocks), np.int32)
+        self.window_tables = (np.zeros((S, self.window_ring), np.int32)
+                              if self.window_ring else None)
         self.pos = np.zeros(S, np.int32)
         self.active = np.zeros(S, bool)
         self.remaining = np.zeros(S, np.int32)
@@ -450,6 +477,20 @@ class PagedDecodeEngine:
         # Read back with the tokens: no transfer of their own
         self.positions_read = 0
         self.moe_stats: Optional[Tuple[float, float]] = None
+        # of the same dispatch, for a two-pool net: 100 x the positions
+        # the window layers hold for the decoding slots over the
+        # positions those slots have reached (None: no window layer)
+        self.window_held_pct: Optional[float] = None
+
+    def _refuse_two_pools(self, what: str):
+        """What cannot take a second kind of pool yet refuses such a net
+        loudly: it names ONE block list a slot."""
+        if self.window_ring:
+            raise NotImplementedError(
+                f"{what} keeps one list of blocks a sequence; this net's "
+                f"window layers keep a ring of {self.window_ring} blocks "
+                f"of their own pool beside it (two kinds of cache in one "
+                f"manager): not supported yet")
 
     # ------------------------------------------------------------ queries
     @property
@@ -488,6 +529,11 @@ class PagedDecodeEngine:
         if self.allocation == "incremental":
             return blocks_needed(prompt_len, self.block_len)
         return blocks_needed(prompt_len + n_tokens, self.block_len)
+
+    def _ring_need(self, n_blocks: int) -> int:
+        """Blocks of the window layers' pool a slot holds when the
+        others hold `n_blocks`: as many, up to the ring."""
+        return min(int(n_blocks), self.window_ring)
 
     @property
     def has_prefixes(self) -> bool:
@@ -582,8 +628,11 @@ class PagedDecodeEngine:
                               else prompt_len + n_tokens)
                 return (self._cow_fresh_blocks(entry, map_tokens)
                         <= self._reclaimable_blocks())
-        return self._admit_blocks(prompt_len, n_tokens) \
-            <= self._reclaimable_blocks()
+        need = self._admit_blocks(prompt_len, n_tokens)
+        if self.window_ring and (self._ring_need(need)
+                                 > self.pool.window_allocator.free_blocks):
+            return False
+        return need <= self._reclaimable_blocks()
 
     def check_budget(self, prompt_len: int, n_tokens: int,
                      prompt_ids=None):
@@ -621,6 +670,14 @@ class PagedDecodeEngine:
             entry = self._match_prefix(np.asarray(prompt_ids))
             if entry is not None:
                 needed = self._cow_fresh_blocks(entry, total)
+        if self.window_ring and (self._ring_need(needed)
+                                 > self.pool.window_blocks - 1):
+            raise ValueError(
+                f"request needs {self._ring_need(needed)} blocks of the "
+                f"window layers' pool but it only has "
+                f"{self.pool.window_blocks - 1} usable (window_blocks "
+                f"{self.pool.window_blocks} incl. the reserved garbage "
+                f"block); it can never be admitted — grow window_blocks")
         if needed > usable:
             raise ValueError(
                 f"request needs {needed} "
@@ -673,6 +730,17 @@ class PagedDecodeEngine:
             fn = cache[key] = builder()
         return fn
 
+    def _tables_arg(self, full=None, window=None):
+        """Fresh device copies of the host's tables (or of the ones
+        given: a draft's masked tables, the row tables an admission
+        scatters by) as the programs take them: the one table, or the
+        (full, window) pair of a two-pool net."""
+        full = self.block_tables if full is None else full
+        if not self.window_ring:
+            return jnp.asarray(full)
+        return (jnp.asarray(full), jnp.asarray(
+            self.window_tables if window is None else window))
+
     def _decode_body(self, greedy_only: bool):
         """The decode-chunk python body (jitted by `_build_decode`;
         traced directly by `decode_cost_report` for the byte-table
@@ -697,7 +765,8 @@ class PagedDecodeEngine:
                 else:
                     j = entry[2]
                     h, kv[j] = layer.paged_step(
-                        lp, h, kv[j], block_tables, pos, live, stats=stats)
+                        lp, h, kv[j], plan_table(block_tables, entry),
+                        pos, live, stats=stats)
             probs = h[:, -1]                   # [S, V]
             return (tuple(kv), self._sample_ids(probs, keys, emit_idx,
                                                 temp, top_p,
@@ -867,6 +936,7 @@ class PagedDecodeEngine:
         baseline pays, so it must be amortized for continuous batching
         to win."""
         bl = self.block_len
+        two, kinds = bool(self.window_ring), self.pool.window_layers
 
         def admit_finish(kv, rows, block_carries, probs, keys, emit0,
                          temp, top_p):
@@ -876,10 +946,12 @@ class PagedDecodeEngine:
             # the sampled-rng emit offset (nonzero for a requeued
             # continuation — its stream keeps the fold_in(key, t)
             # indices it would have had uninterrupted)
+            # a two-pool net's `rows` is the (full, window) pair
             out = []
-            for pools, caches in zip(kv, block_carries):
+            for pools, caches, ring in zip(kv, block_carries, kinds):
                 C = caches[0].shape[1]     # [k, C, ...] -> pages
-                flat_rows = rows[:, :C // bl].reshape(-1)
+                flat_rows = (rows[ring] if two else rows)[
+                    :, :C // bl].reshape(-1)
                 out.append(tuple(
                     pool.at[flat_rows].set(cache.reshape(
                         (k * (C // bl), bl, pool.shape[-1])
@@ -890,7 +962,8 @@ class PagedDecodeEngine:
             return tuple(out), firsts
 
         return self._shared_jit(
-            ("admit", int(k), greedy_only, self.block_len, self.top_k),
+            ("admit", int(k), greedy_only, self.block_len, self.top_k)
+            + ((kinds,) if two else ()),
             lambda: jax.jit(admit_finish,
                             donate_argnums=donate_argnums(0)))
 
@@ -1046,7 +1119,8 @@ class PagedDecodeEngine:
                     else:
                         j = entry[2]
                         h, kv[j] = layer.paged_step(
-                            lp, h, kv[j], block_tables, pos, live)
+                            lp, h, kv[j],
+                            plan_table(block_tables, entry), pos, live)
                 nxt = jnp.argmax(h[:, -1], axis=-1).astype(jnp.int32)
                 return (tuple(kv), nxt, pos + 1), nxt
 
@@ -1069,8 +1143,12 @@ class PagedDecodeEngine:
         mask = np.zeros(S, bool)
         for s, _ in trunc_slots:
             mask[s] = True
-        tables = np.where(mask[:, None], self.block_tables,
-                          GARBAGE_BLOCK).astype(np.int32)
+        tables = self._tables_arg(
+            np.where(mask[:, None], self.block_tables,
+                     GARBAGE_BLOCK).astype(np.int32),
+            np.where(mask[:, None], self.window_tables,
+                     GARBAGE_BLOCK).astype(np.int32)
+            if self.window_ring else None)
         if self._draft_fn is None:
             self._draft_fn = self._shared_jit(
                 ("draft", self.spec_k, tuple(self._draft_plan or ()),
@@ -1079,7 +1157,7 @@ class PagedDecodeEngine:
                                 donate_argnums=donate_argnums(2)))
         kv, drafts = self._draft_fn(
             self._params, self.net.net_state, self.pool.kv,
-            jnp.asarray(tables), jnp.asarray(self.last_token),
+            tables, jnp.asarray(self.last_token),
             jnp.asarray(self.pos), jnp.asarray(mask))
         self.pool.kv = kv
         self.spec_draft_dispatches_total += 1
@@ -1100,6 +1178,7 @@ class PagedDecodeEngine:
         sequence; returns the registry key. Raises when the pool
         cannot host the prefix right now — registration is a capacity
         commitment, not a best-effort hint."""
+        self._refuse_two_pools("a registered prefix")
         prompt = np.asarray(token_ids)
         if prompt.ndim == 2 and prompt.shape[0] == 1:
             prompt = prompt[0]
@@ -1236,8 +1315,16 @@ class PagedDecodeEngine:
                         blocks = self._alloc_admit(nb)
                         if blocks is None:
                             break
+                        ring = None
+                        if self.window_ring:
+                            # all-or-nothing across both kinds of pool
+                            ring = self.pool.window_allocator.allocate(
+                                self._ring_need(nb))
+                            if ring is None:
+                                self.pool.allocator.free(blocks)
+                                break
                         w = dict(blocks=blocks, grants=nb, entry=None,
-                                 fork=None)
+                                 fork=None, window_blocks=ring)
                     else:
                         w = self._cow_admit_blocks(entry, P, n_tokens)
                         if w is None:
@@ -1281,6 +1368,9 @@ class PagedDecodeEngine:
                 if s is None or s.blocks is not w["blocks"]:
                     try:
                         self.pool.allocator.free(w["blocks"])
+                        if w.get("window_blocks"):
+                            self.pool.window_allocator.free(
+                                w["window_blocks"])
                     except ValueError:
                         pass   # already back in the pool
             raise
@@ -1390,6 +1480,8 @@ class PagedDecodeEngine:
             max_rows = max(c[0].shape[1] // self.block_len
                            for c in block_carries)
             rows = np.full((k2, max_rows), GARBAGE_BLOCK, np.int32)
+            ring_rows = (np.full((k2, max_rows), GARBAGE_BLOCK, np.int32)
+                         if self.window_ring else None)
             keys = np.zeros((k2, 2), np.uint32)
             emit0 = np.zeros(k2, np.int32)
             temps = np.zeros(k2, np.float32)
@@ -1398,6 +1490,14 @@ class PagedDecodeEngine:
                 # an upfront grant may pass the rows the prefill wrote
                 n = min(len(w["blocks"]), max_rows)
                 rows[j, :n] = w["blocks"][:n]
+                if ring_rows is not None:
+                    # of the prompt's logical blocks the ring keeps the
+                    # last `window_ring`, block b at column b % ring;
+                    # the earlier ones go to the garbage block
+                    nb = blocks_needed(len(w["prompt"]), self.block_len)
+                    b = np.arange(max(0, nb - self.window_ring), nb)
+                    ring_rows[j, b] = np.asarray(
+                        w["window_blocks"])[b % self.window_ring]
                 r = w["r"]
                 if r.get("rng") is not None:
                     keys[j] = np.asarray(r["rng"], np.uint32).reshape(2)
@@ -1414,7 +1514,8 @@ class PagedDecodeEngine:
                 fin = self._admit_finish[(k2, greedy)] = \
                     self._build_admit_finish(k2, greedy)
             self.pool.kv, firsts = fin(
-                self.pool.kv, jnp.asarray(rows), block_carries, probs,
+                self.pool.kv, self._tables_arg(rows, ring_rows),
+                block_carries, probs,
                 jnp.asarray(keys), jnp.asarray(emit0), jnp.asarray(temps),
                 jnp.asarray(top_ps))
         with monitor.span("serve/admit/wait", it=it) as sp:
@@ -1450,11 +1551,15 @@ class PagedDecodeEngine:
         s = Slot(r.get("request_id"), blocks, len(prompt), n_tokens,
                  emit_base=emit0,
                  history=([int(t) for t in prompt] + [first]
-                          if self.spec_k else []))
+                          if self.spec_k else []),
+                 window_blocks=w.get("window_blocks"))
         s.emitted = 1
         self.slots[slot] = s
         self.block_tables[slot] = GARBAGE_BLOCK
         self.block_tables[slot, :len(blocks)] = blocks
+        if s.window_blocks is not None:
+            self.window_tables[slot] = GARBAGE_BLOCK
+            self.window_tables[slot, :len(s.window_blocks)] = s.window_blocks
         self.pos[slot] = len(prompt)
         self.remaining[slot] = n_tokens - 1
         self.emit_idx[slot] = emit0 + 1
@@ -1637,24 +1742,26 @@ class PagedDecodeEngine:
         out, self._preempted = self._preempted, []
         return out
 
-    def _allocate_under_pressure(self, s: int, n: int):
-        """Allocate `n` blocks for slot `s`, preempting the lowest-
-        progress slot under pool pressure (requeue, not deadlock);
-        returns None when `s` itself lost the pool race (it has been
-        preempted and released)."""
-        got = self.pool.allocator.allocate(n)
+    def _allocate_under_pressure(self, s: int, n: int, allocator=None):
+        """Allocate `n` blocks for slot `s` (from `allocator`; by
+        default the pool of the layers that keep every position),
+        preempting the lowest-progress slot under pool pressure
+        (requeue, not deadlock); returns None when `s` itself lost the
+        pool race (it has been preempted and released)."""
+        allocator = allocator or self.pool.allocator
+        got = allocator.allocate(n)
         while got is None:
             # radix LRU leaves go first — cache-only references, no
             # re-prefill cost — before any live slot is preempted
             if self._radix is not None and self._radix.evict_lru():
                 self.radix_evictions_total += 1
-                got = self.pool.allocator.allocate(n)
+                got = allocator.allocate(n)
                 continue
             victim = self._lowest_progress_active()
             self._preempt(victim)
             if victim == s:
                 return None            # s itself lost the pool race
-            got = self.pool.allocator.allocate(n)
+            got = allocator.allocate(n)
         return got
 
     def _grow_block_tables(self, tokens_by_slot=None):
@@ -1694,6 +1801,19 @@ class PagedDecodeEngine:
                 slot.blocks.extend(got)
                 self.block_tables[s, have:needed] = got
                 self.block_grants_total += len(got)
+            if slot.window_blocks is not None:
+                # the window layers' ring grows with the slot up to its
+                # width and is then written round
+                have = len(slot.window_blocks)
+                ring = self._ring_need(needed)
+                if ring > have:
+                    got = self._allocate_under_pressure(
+                        s, ring - have, self.pool.window_allocator)
+                    if got is None or self.slots[s] is None:
+                        continue
+                    slot.window_blocks.extend(got)
+                    self.window_tables[s, have:ring] = got
+                    self.block_grants_total += len(got)
             # copy-on-first-write fork of shared write-window blocks
             first_b = int(self.pos[s]) // self.block_len
             last_b = (int(self.pos[s]) + tokens - 1) // self.block_len
@@ -1783,6 +1903,7 @@ class PagedDecodeEngine:
         self.wait_s = 0.0
         self.kv_read_pct = 100.0     # the K-wide score path gathers
         self.positions_read = 0
+        self.window_held_pct = None
         self.moe_stats = None
         self.overlapped = False
         self.launched = False
@@ -1862,8 +1983,11 @@ class PagedDecodeEngine:
 
     def _decode_args(self):
         """The decode program's arguments after the pool."""
-        return (self._device("block_tables", self.block_tables),
-                self._carry, self._fresh_rows(),
+        tables = self._device("block_tables", self.block_tables)
+        if self.window_ring:
+            tables = (tables, self._device("window_tables",
+                                           self.window_tables))
+        return (tables, self._carry, self._fresh_rows(),
                 self._device("keys", self.keys),
                 self._device("temp", self.temp),
                 self._device("top_p", self.top_p))
@@ -1897,6 +2021,7 @@ class PagedDecodeEngine:
                         greedy_only=True)
                 decode = self._decode_greedy
             kv_read_pct, positions_read = self._kv_read()
+            window_held_pct = self._window_held()
             # was the step before this one still unread at the launch?
             overlapped = self._flight is not None
             params, weight_bytes = quant.serving_tree(self.net,
@@ -1940,6 +2065,7 @@ class PagedDecodeEngine:
                         taken=taken, finished=finished,
                         kv_read_pct=kv_read_pct,
                         positions_read=positions_read,
+                        window_held_pct=window_held_pct,
                         weight_gb=weight_bytes / 1e9,
                         overlapped=overlapped)
 
@@ -1956,6 +2082,7 @@ class PagedDecodeEngine:
         with monitor.span("serve/decode/post", it=it):
             self.kv_read_pct = flight["kv_read_pct"]
             self.positions_read = flight["positions_read"]
+            self.window_held_pct = flight["window_held_pct"]
             self.weight_gb = flight["weight_gb"]
             self.overlapped = flight["overlapped"]
             taken = flight["taken"]
@@ -1972,30 +2099,50 @@ class PagedDecodeEngine:
 
     def _kv_read(self) -> Tuple[float, int]:
         """Of the decode dispatch about to launch: (100 x the pool
-        blocks of K (and as many of V) it reads in a layer, over the
-        `steps_per_dispatch x n_slots x max_blocks` a gather of every
-        slot's whole table moves; the positions its attention reads,
-        summed over the paged layers). An in-place layer reads
-        `ceil((pos+1)/block_len)` blocks for each slot whose
-        `remaining > 0` at that micro-step (the program's own
-        validity) and none for the others; a gathering layer reads
-        everything — the share is the mean over the layers."""
-        whole = self.steps_per_dispatch * self.n_slots * self.max_blocks
+        blocks of K (and as many of V) it reads, over the table entries
+        its layers have, `steps_per_dispatch x n_slots x` the columns of
+        each layer's table, which is what a gather of every slot's whole
+        table moves; the positions its attention reads, summed over the
+        paged layers). An in-place layer reads `ceil((pos+1)/block_len)`
+        blocks for each slot whose `remaining > 0` at that micro-step
+        (the program's own validity) and none for the others — a window
+        layer those from its window's first position on, counted in
+        whole blocks; a gathering layer reads everything."""
+        per_slot = self.steps_per_dispatch * self.n_slots
         j = np.arange(self.steps_per_dispatch)[:, None]      # [J, 1]
         live = self.remaining[None, :] > j
         # a slot's position at micro-step j: it advances while live
         at = self.pos[None, :] + j
-        read = int(np.where(live, -(-(at + 1) // self.block_len), 0).sum())
-        n_in_place = sum(self._in_place)
-        n_layers = len(self._in_place)
-        # positions, not blocks: an in-place layer reads the `pos + 1`
-        # rows a live slot holds, a gathering layer the whole budget of
-        # every slot
-        positions = (n_in_place * int(np.where(live, at + 1, 0).sum())
-                     + (n_layers - n_in_place) * whole * self.block_len)
-        return (100.0 * (n_in_place * read
-                         + (n_layers - n_in_place) * whole)
-                / (n_layers * whole), positions)
+        blocks = np.where(live, -(-(at + 1) // self.block_len), 0)
+        reads = {False: (int(blocks.sum()),
+                         int(np.where(live, at + 1, 0).sum()))}
+        if self.window_ring:
+            first = np.maximum(at + 1 - self.pool.window, 0) \
+                // self.block_len
+            ring = int(np.where(live, blocks - first, 0).sum())
+            reads[True] = (ring, ring * self.block_len)
+        read = whole = positions = 0
+        for in_place, ring in zip(self._in_place, self.pool.window_layers):
+            cols = per_slot * (self.window_ring if ring else self.max_blocks)
+            whole += cols
+            got = reads[ring] if in_place else (cols, cols * self.block_len)
+            read += got[0]
+            positions += got[1]
+        return 100.0 * read / whole, positions
+
+    def _window_held(self) -> Optional[float]:
+        """Of the decode dispatch about to launch, for a net with window
+        layers: 100 x the positions those layers hold for the decoding
+        slots (a slot's ring, or all it has reached while that is less)
+        over the positions the slots have reached."""
+        if not self.window_ring:
+            return None
+        reached = held = 0
+        for s in np.flatnonzero(self.active & (self.remaining > 0)):
+            n = int(self.pos[s]) + 1
+            reached += n
+            held += min(n, len(self.slots[s].window_blocks) * self.block_len)
+        return 100.0 * held / reached if reached else None
 
     # ------------------------------------------------- speculative decode
     def _propose(self, s: int, max_draft: int) -> List[int]:
@@ -2131,7 +2278,7 @@ class PagedDecodeEngine:
             self.weight_gb = weight_bytes / 1e9
             out = score(
                 params, self.net.net_state, self.pool.kv,
-                jnp.asarray(self.block_tables), jnp.asarray(token_mat),
+                self._tables_arg(), jnp.asarray(token_mat),
                 jnp.asarray(self.pos), jnp.asarray(n_valid),
                 jnp.asarray(self.keys), jnp.asarray(self.emit_idx),
                 jnp.asarray(self.temp), jnp.asarray(self.top_p))
@@ -2225,6 +2372,9 @@ class PagedDecodeEngine:
     def _release(self, slot: int):
         s = self.slots[slot]
         self.pool.allocator.free(s.blocks)
+        if s.window_blocks is not None:
+            self.pool.window_allocator.free(s.window_blocks)
+            self.window_tables[slot] = GARBAGE_BLOCK
         self.slots[slot] = None
         self.active[slot] = False
         self.remaining[slot] = 0
@@ -2288,6 +2438,7 @@ class PagedDecodeEngine:
         n_blocks, block_len, heads, head_dim]`: (K, V) pages of heads.
         A pool whose layers declare other arrays (a latent pool: one
         array, no head axis) has no place on it."""
+        self._refuse_two_pools("the prefill->decode handoff wire")
         for i, arrays in zip(self.pool.layer_indices, self.pool.kv):
             layer = self.net.layers[i]
             if (len(arrays) != 2

@@ -40,6 +40,14 @@ def blocks_needed(total_tokens: int, block_len: int) -> int:
     return -(-int(total_tokens) // int(block_len))
 
 
+def plan_table(block_tables, entry):
+    """The table a block entry of the engine's plan reads: entries are
+    ("block", layer, pool), or ("block", layer, pool, kind) in a net
+    with two kinds of pool, whose programs take the (full, window) pair
+    of tables and read the one `kind` names."""
+    return block_tables[entry[3]] if len(entry) > 3 else block_tables
+
+
 class BlockAllocator:
     """Host-side free-list over pool block ids 1..n_blocks-1 (id 0 is
     the reserved garbage block). Allocation is all-or-nothing: a
@@ -325,7 +333,7 @@ class RadixPrefixCache:
 
 
 class PagedKVPool:
-    """The per-layer block pools for one model + the shared allocator.
+    """The per-layer block pools for one model + their allocators.
 
     The arrays are whatever each paged layer DECLARES
     (`layer.paged_pool_arrays(n_blocks, block_len, dtype)`, the paged
@@ -335,9 +343,21 @@ class PagedKVPool:
     (K, V) of `n_heads * head_dim` for a `TransformerEncoderBlock`, one
     latent array for a `LatentAttentionBlock`. It is a plain pytree:
     jitted programs take it as an argument and return the updated
-    pools."""
+    pools.
 
-    def __init__(self, net, n_blocks: int, block_len: int):
+    Two kinds of cache in one manager: a layer that declares
+    `paged_window` (the positions back from a query it ever reads)
+    keeps a slot's pages in a RING of `ceil(window / block_len) + 1`
+    blocks, logical block b at table column `b % ring`, whatever the
+    slot's length; every other layer keeps a block for every
+    `block_len` positions. The window layers' arrays hold
+    `window_blocks` blocks and are granted by `window_allocator`, the
+    others `n_blocks` by `allocator`: one block id names the same page
+    in every layer of its kind. `window` is None, and there is one
+    allocator, for a net with no window layer."""
+
+    def __init__(self, net, n_blocks: int, block_len: int,
+                 window_blocks: Optional[int] = None):
         if block_len < 1:
             raise ValueError(f"block_len must be >= 1; got {block_len}")
         self.block_len = int(block_len)
@@ -350,12 +370,42 @@ class PagedKVPool:
                 "paged protocol (TransformerEncoderBlock, "
                 "LatentAttentionBlock); got "
                 f"{[type(l).__name__ for l in net.layers]}")
+        windows = [getattr(net.layers[i], "paged_window", None)
+                   for i in self.layer_indices]
+        # per paged layer: does it keep a ring (True) or every block?
+        self.window_layers: Tuple[bool, ...] = tuple(
+            w is not None for w in windows)
+        distinct = sorted({int(w) for w in windows if w is not None})
+        if len(distinct) > 1:
+            raise ValueError(
+                f"paged layers declare windows {distinct}: one pool of "
+                f"rings serves one window length")
+        if distinct and all(self.window_layers):
+            raise ValueError(
+                "every paged layer declares a window: the pool manager "
+                "keeps the slots' lengths by the layers that hold every "
+                "position, and this net has none")
+        self.window: Optional[int] = distinct[0] if distinct else None
+        if self.window is None and window_blocks is not None:
+            raise ValueError(
+                "window_blocks given for a net with no window layer")
+        self.window_blocks = (None if self.window is None else
+                              int(window_blocks or n_blocks))
         dtype = net.dtype.compute_dtype
         self.kv: Tuple = tuple(
             tuple(net.layers[i].paged_pool_arrays(
-                self.n_blocks, self.block_len, dtype))
-            for i in self.layer_indices)
+                self.window_blocks if ring else self.n_blocks,
+                self.block_len, dtype))
+            for i, ring in zip(self.layer_indices, self.window_layers))
         self.allocator = BlockAllocator(self.n_blocks)
+        self.window_allocator = (None if self.window is None else
+                                 BlockAllocator(self.window_blocks))
+
+    def ring_blocks(self, max_blocks: int) -> int:
+        """Columns of a window layer's block table: the blocks the
+        window can touch at once, and never more than the budget's."""
+        return min(int(max_blocks),
+                   blocks_needed(self.window, self.block_len) + 1)
 
     @property
     def free_blocks(self) -> int:

@@ -236,6 +236,7 @@ class GenerationServer(ParallelInference):
                  max_positions: Optional[int] = None,
                  max_prefill_tokens: Optional[int] = None,
                  min_prefill_bucket: int = 1,
+                 window_blocks: Optional[int] = None,
                  name: Optional[str] = None,
                  slo: Optional[SLOObjective] = None):
         super().__init__(net)
@@ -259,7 +260,8 @@ class GenerationServer(ParallelInference):
             spec_draft_layers=spec_draft_layers,
             prefix_cache=prefix_cache, max_positions=max_positions,
             max_prefill_tokens=max_prefill_tokens,
-            min_prefill_bucket=min_prefill_bucket)
+            min_prefill_bucket=min_prefill_bucket,
+            window_blocks=window_blocks)
         self._metrics_cache = None
         # speculative-decoding policy: drafting is only worth its
         # k-wide scoring dispatch while the proposer's tokens actually
@@ -582,7 +584,7 @@ class GenerationServer(ParallelInference):
                 score = eng._get_score(K, variant)
                 eng.pool.kv = score(
                     eng._params, eng.net.net_state, eng.pool.kv,
-                    jnp.asarray(eng.block_tables),
+                    eng._tables_arg(),
                     jnp.zeros((S, K), jnp.int32),
                     jnp.zeros(S, jnp.int32), jnp.zeros(S, jnp.int32),
                     jnp.zeros((S, 2), jnp.uint32),
@@ -971,6 +973,22 @@ class GenerationServer(ParallelInference):
         # pack's acceptance-collapse rule (min over series < floor)
         # fires on every freshly-built server before its first
         # speculative dispatch
+        if self.engine.pool.window_allocator is not None:
+            # a net with window layers keeps a second pool: the unlabeled
+            # pool series are then the pool of the layers that keep
+            # every position, these the window layers' rings
+            fams["ring_free"] = reg.gauge(
+                "serving_pool_blocks_free",
+                "free KV-pool blocks (allocator view)", pool="window", **lbl)
+            fams["ring_used"] = reg.gauge(
+                "serving_pool_blocks_used", "granted KV-pool blocks",
+                pool="window", **lbl)
+            fams["window_held"] = reg.histogram(
+                "serving_window_kv_held_pct",
+                "100 x positions the window layers hold for the "
+                "decoding slots / positions those slots have reached, "
+                "a decode dispatch",
+                buckets=(10, 20, 30, 40, 50, 60, 70, 80, 90, 100), **lbl)
         fams["spec_accept"].set(1.0)
         for g in fams["spec_accept_by"].values():
             g.set(1.0)
@@ -1255,6 +1273,8 @@ class GenerationServer(ParallelInference):
             m["moe_load"].observe(eng.moe_stats[1])
         if decode and eng.positions_read:
             m["positions_read"].inc(eng.positions_read)
+        if decode and eng.window_held_pct is not None:
+            m["window_held"].observe(eng.window_held_pct)
 
     def _intake(self, eng, m) -> bool:
         """Control requests, cancellations, and the submit queue drained
@@ -1440,6 +1460,9 @@ class GenerationServer(ParallelInference):
         m["blocks"].set(eng.free_blocks)
         m["pool_free"].set(eng.pool.free_blocks)
         m["pool_used"].set(eng.pool.used_blocks)
+        if eng.pool.window_allocator is not None:
+            m["ring_free"].set(eng.pool.window_allocator.free_blocks)
+            m["ring_used"].set(eng.pool.window_allocator.used_blocks)
         if eng.block_grants_total > self._grants_seen:
             m["grants"].inc(eng.block_grants_total
                             - self._grants_seen)

@@ -217,7 +217,9 @@ def paged_score_forward(net, plan, params, state, kv, block_tables,
     positions `pos[s] .. pos[s]+K-1`; `n_valid` [S] bounds each slot's
     real lanes (0 = slot sits this dispatch out — its writes land in
     the garbage block, its output rows are discarded). `plan` is the
-    engine's layer walk (("plain"|"pos", i) / ("block", i, pool_j)).
+    engine's layer walk (("plain"|"pos", i) / ("block", i, pool_j), or
+    ("block", i, pool_j, kind) where `block_tables` is the (full,
+    window) pair of a net with two kinds of pool).
     Returns (kv', probs [S, K, V]) where probs[s, j] is the target's
     next-token distribution AFTER consuming token j — per-lane
     bit-equal to K sequential single-token decode dispatches, which is
@@ -225,6 +227,8 @@ def paged_score_forward(net, plan, params, state, kv, block_tables,
     `get_prefill`/`get_prefill_bucketed` because it is the same program
     family: the engine jits it per (K, sampling-variant)."""
     import jax.numpy as jnp
+
+    from deeplearning4j_tpu.serving.paged import plan_table
 
     layers = net.layers
     K = token_mat.shape[1]
@@ -243,7 +247,7 @@ def paged_score_forward(net, plan, params, state, kv, block_tables,
         else:
             j = entry[2]
             h, kv[j] = layer.paged_step_multi(
-                lp, h, kv[j], block_tables, pos, n_valid)
+                lp, h, kv[j], plan_table(block_tables, entry), pos, n_valid)
     return tuple(kv), h                                  # [S, K, V]
 
 

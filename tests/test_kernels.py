@@ -597,6 +597,116 @@ class TestFlashBf16:
                                    rtol=2e-2, atol=2e-2)
 
 
+class TestPagedDecodeGroupedWindowed:
+    """`dl4tpu_paged_decode` with fewer key heads than query heads and a
+    first position (`nn/layers/parallel.py`'s decode step), interpret
+    mode, against a plain numpy softmax a slot and a head at a time.
+    Every pool position no slot may read (before its first position,
+    past its length, other blocks) holds 1e30."""
+
+    @staticmethod
+    def _case(rng, n_heads, n_kv, dh, bl, window, budget=96):
+        S = 6
+        max_blocks = budget // bl
+        ring = (min(max_blocks, -(-window // bl) + 1) if window
+                else max_blocks)
+        pos = np.array([0, 7, 8, 45, 95, 30], np.int32)
+        live = np.array([1, 1, 1, 1, 1, 0], bool)
+        lengths = np.where(live, pos + 1, 0).astype(np.int32)
+        starts = (np.maximum(lengths - window, 0) if window
+                  else np.zeros(S)).astype(np.int32)
+        nb = 1 + S * ring
+        table = np.zeros((S, ring), np.int32)
+        ids = rng.permutation(np.arange(1, nb))
+        c = 0
+        for s in np.flatnonzero(live):
+            n = min(-(-int(lengths[s]) // bl), ring)
+            table[s, :n] = ids[c:c + n]
+            c += n
+        kp = np.full((nb, bl, n_kv * dh), 1e30, np.float32)
+        vp = kp.copy()
+        for s in range(S):
+            for p in range(int(starts[s]), int(lengths[s])):
+                b = table[s, (p // bl) % ring]
+                kp[b, p % bl] = rng.standard_normal(n_kv * dh)
+                vp[b, p % bl] = rng.standard_normal(n_kv * dh)
+        q = rng.standard_normal((S, 1, n_heads * dh)).astype(np.float32)
+        return q, kp, vp, table, lengths, starts, ring
+
+    @staticmethod
+    def _plain(q, kp, vp, table, lengths, starts, ring, n_heads, n_kv):
+        S, bl = q.shape[0], kp.shape[1]
+        dh = kp.shape[2] // n_kv
+        out = np.zeros((S, n_heads, dh), np.float32)
+        for s in range(S):
+            ps = np.arange(int(starts[s]), int(lengths[s]))
+            if not len(ps):
+                continue
+            blk = table[s, (ps // bl) % ring]
+            k = kp[blk, ps % bl].reshape(len(ps), n_kv, dh)
+            v = vp[blk, ps % bl].reshape(len(ps), n_kv, dh)
+            for h in range(n_heads):
+                g = h // (n_heads // n_kv)
+                sc = k[:, g] @ q[s, 0].reshape(n_heads, dh)[h] / np.sqrt(dh)
+                p = np.exp(sc - sc.max())
+                out[s, h] = (p / p.sum()) @ v[:, g]
+        return out.reshape(S, 1, n_heads * dh)
+
+    @pytest.mark.parametrize("n_heads,n_kv,dh,window,dtype,tol", [
+        (4, 1, 128, None, jnp.float32, 2e-5),    # one key head, from 0
+        (4, 2, 128, None, jnp.float32, 2e-5),    # two key heads, from 0
+        (4, 2, 128, 20, jnp.float32, 2e-5),      # first position past a
+        (4, 1, 128, 20, jnp.float32, 2e-5),      #   block's edge, a ring
+        (2, 2, 64, 20, jnp.float32, 2e-5),       # as many key heads: the
+        (2, 2, 64, None, jnp.float32, 2e-5),     #   block-diagonal variant
+        (32, 2, 128, 20, jnp.bfloat16, 3e-2),
+    ])
+    def test_matches_a_plain_softmax(self, n_heads, n_kv, dh, window,
+                                     dtype, tol):
+        from deeplearning4j_tpu.kernels.paged_attention import (
+            paged_decode_attention)
+        rng = np.random.default_rng(0)
+        q, kp, vp, table, lengths, starts, ring = self._case(
+            rng, n_heads, n_kv, dh, 8 if dtype == jnp.float32 else 16,
+            window)
+        got = paged_decode_attention(
+            jnp.asarray(q, dtype), jnp.asarray(kp, dtype),
+            jnp.asarray(vp, dtype), jnp.asarray(table), jnp.asarray(lengths),
+            n_heads=n_heads, n_kv_heads=n_kv,
+            starts=None if window is None else jnp.asarray(starts),
+            interpret=True)
+        want = self._plain(
+            np.asarray(jnp.asarray(q, dtype), np.float32),
+            np.asarray(jnp.asarray(kp, dtype), np.float32),
+            np.asarray(jnp.asarray(vp, dtype), np.float32),
+            table, lengths, starts, ring, n_heads, n_kv)
+        got = np.asarray(got, np.float32)
+        assert np.isfinite(got).all()                # no 1e30 was read
+        np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+        assert not got[5].any()                      # length 0: zeros
+
+    def test_group_size_is_no_part_of_the_answer(self):
+        from deeplearning4j_tpu.kernels.paged_attention import (
+            paged_decode_attention)
+        q, kp, vp, table, lengths, starts, _ = self._case(
+            np.random.default_rng(1), 4, 2, 128, 8, 20)
+        args = [jnp.asarray(a) for a in (q, kp, vp, table, lengths)]
+        a, b = (paged_decode_attention(
+            *args, n_heads=4, n_kv_heads=2, starts=jnp.asarray(starts),
+            group_positions=g, interpret=True) for g in (8, 32))
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-6)
+
+    def test_shapes_it_cannot_tile_say_why(self):
+        from deeplearning4j_tpu.kernels.paged_attention import (
+            unsupported_reason)
+        assert unsupported_reason((2081, 64, 1024), jnp.bfloat16, 128, 8) \
+            is None
+        assert "grouped-query" in unsupported_reason(
+            (40, 8, 128), jnp.float32, 4, 2)         # head_dim 64
+        assert "multiple of n_kv_heads" in unsupported_reason(
+            (40, 8, 384), jnp.float32, 4, 3)
+
+
 class TestPagedDecodeKernel:
     """`dl4tpu_paged_decode` (interpret mode) against the plain
     reference it replaces on the chip: gather by block table +

@@ -178,6 +178,48 @@ print(json.dumps({"kernels": names, "name": KERNEL_NAME,
 '''
 
 
+_GQA_DECODE = '''
+# command-a-plus-05-2026's decode attention at its published widths: 32
+# slots, 128 query heads over 8 key heads of 128, bf16 pages of 64
+# positions; a full layer over 9,216 positions a slot and a window layer
+# over its ring of 65 blocks from a first position; then one window
+# block's whole paged step round it (narrow experts: the kernel is what
+# is tried)
+from deeplearning4j_tpu.kernels.paged_attention import (
+    KERNEL_NAME, paged_decode_attention)
+from deeplearning4j_tpu.nn.layers.parallel import ParallelAttentionMoEBlock
+bf, i32 = jnp.bfloat16, jnp.int32
+shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=S)
+names = set()
+for blocks, cols, windowed in ((4609, 144, False), (2081, 65, True)):
+    args = [shape((32, 1, 16384), bf), shape((blocks, 64, 1024), bf),
+            shape((blocks, 64, 1024), bf), shape((32, cols), i32),
+            shape((32,), i32)] + [shape((32,), i32)] * windowed
+    low = jax.jit(lambda q, k, v, bt, ln, st=None: paged_decode_attention(
+        q, k, v, bt, ln, n_heads=128, n_kv_heads=8, starts=st)).lower(*args)
+    names |= set(re.findall(r'kernel_name = "([^"]+)"', low.as_text()))
+    low.compile()
+blk = ParallelAttentionMoEBlock(
+    n_in=4096, n_heads=128, n_kv_heads=8, head_dim=128, window=4096,
+    rotary=True, rope_theta=50000.0, ffn_hidden=256, n_routed=128,
+    experts_per_token=8, held_count=16, n_shared=4)
+params = jax.tree_util.tree_map(
+    lambda a: shape(a.shape, a.dtype),
+    jax.eval_shape(lambda: blk.init_params(jax.random.PRNGKey(0), bf)))
+pools = (shape((2081, 64, 1024), bf),) * 2
+step = jax.jit(lambda p, x, pools, bt, pos, live: blk.paged_step(
+    p, x, pools, bt, pos, live), donate_argnums=2)
+hlo = step.lower(params, shape((32, 1, 4096), bf), pools,
+                 shape((32, 65), i32), shape((32,), i32),
+                 shape((32,), jnp.bool_)).compile().as_text()
+print(json.dumps({"kernels": sorted(names), "name": KERNEL_NAME,
+                  "in_place": blk.paged_in_place(pools),
+                  "step_has_kernel": KERNEL_NAME in hlo,
+                  "pool_copies": len(re.findall(
+                      r"= bf16\\[2081,64,1024\\]\\S* copy\\(", hlo))}))
+'''
+
+
 def _child(body, *, import_package=True):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("LIBTPU_INIT_ARGS", None)
@@ -254,6 +296,19 @@ def test_latent_decode_kernel_compiles_at_published_widths_on_v5e():
     proc, out = _child(_MLA_DECODE)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert out["kernels"] == [out["name"]] == ["dl4tpu_mla_paged_decode"]
+    assert out["in_place"] is True and out["step_has_kernel"] is True
+    assert out["pool_copies"] == 0
+
+
+def test_grouped_windowed_decode_compiles_at_published_widths_on_v5e():
+    """`dl4tpu_paged_decode` with 8 key heads under 128 query heads, from
+    position 0 over a full layer's table and from a first position over
+    a window layer's ring, lowers through Mosaic for a described v5e,
+    alone and inside a parallel block's paged step, which takes the
+    kernel and copies no pool."""
+    proc, out = _child(_GQA_DECODE)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["kernels"] == [out["name"]] == ["dl4tpu_paged_decode"]
     assert out["in_place"] is True and out["step_has_kernel"] is True
     assert out["pool_copies"] == 0
 
