@@ -78,6 +78,12 @@ from deeplearning4j_tpu.serving.paged import (
     plan_table,
 )
 
+# Rows of one chunk of the sampling chain (`_sample_ids`): the most that
+# cost the v5e no more than twice a chunk of one row. At 50,257 ids the
+# chain takes 553 us for one row, 666 for 8, 1,159 for 16 and 2,218 for
+# 32 (`scripts/sample_chain_cost.py`, PR 34; PERF.md 5 has the table).
+_SAMPLE_CHUNK_ROWS = 8
+
 
 def bucket_len(n: int, cap: int) -> int:
     """Pad length for mixed-length prefill: the next power of two >= n,
@@ -469,6 +475,9 @@ class PagedDecodeEngine:
         self.weight_gb = 0.0
         self.overlapped = False
         self.launched = False
+        # rows the sampling chain ran over in that step (the live slots
+        # with a temperature; 0 for a step of the greedy twin)
+        self.sample_rows = 0
         # positions the last decode dispatch's attention read (summed
         # over its paged layers), and what the routed expert layers of
         # the last dispatch (decode or admission) report: rows routed
@@ -689,27 +698,58 @@ class PagedDecodeEngine:
 
     # ----------------------------------------------------------- sampling
     def _sample_ids(self, probs, keys, emit_idx, temp, top_p,
-                    greedy_only: bool = False):
+                    greedy_only: bool = False, live=None):
         """Next token per row of `probs` [S, V]: greedy argmax where
         temp == 0 (bit-identical to `generate(temperature=0)`), else
         the same log/clip/filter/categorical chain `generate` runs —
         with a PER-SLOT key folded by emit index, the serving rng
         contract. `greedy_only=True` (a STATIC program variant the
-        scheduler picks when no sampled request is in flight) skips
-        the sort/threefry chain entirely — measured at ~half the
-        decode chunk on the CPU sandbox."""
+        scheduler picks when no sampled request is in flight) has no
+        sort or threefry operation in it at all: on the v5e
+        `gpt2-medium`'s 32-slot decode step takes 1.18 ms so, 1.22 as
+        the full variant with no row to sample for and 1.92 with one
+        (3.51 when the chain ran over all 32 rows; PERF.md 5, PR 34).
+
+        The chain runs over the rows that sample and no others: the
+        rows with `temp > 0` that are `live` (the decode step's mask: a
+        released slot keeps its temperature; None where every row is a
+        request's) are gathered `_SAMPLE_CHUNK_ROWS` at a time by a
+        loop whose trip count the device computes, so a step with no
+        sampled row sorts nothing and a step with one sorts one chunk.
+        A row's arithmetic does not depend on the rows beside it: its
+        token is the one the chain over the whole matrix gives."""
         greedy_ids = jnp.argmax(probs, axis=-1).astype(jnp.int32)
         if greedy_only:
             return greedy_ids
         from deeplearning4j_tpu.zoo.transformer import filter_logits
-        safe_t = jnp.where(temp > 0, temp, 1.0)
-        logits = jnp.log(jnp.clip(probs, 1e-9, None)) / safe_t[:, None]
-        # generate()'s own filter body, with per-slot traced p
-        # (p=1.0 keeps everything)
-        logits = filter_logits(logits, self.top_k, top_p[:, None])
-        skeys = jax.vmap(jax.random.fold_in)(keys, emit_idx)
-        sampled = jax.vmap(jax.random.categorical)(skeys, logits)
-        return jnp.where(temp > 0, sampled.astype(jnp.int32), greedy_ids)
+        R = min(_SAMPLE_CHUNK_ROWS, probs.shape[0])
+        wanted = temp > 0 if live is None else (temp > 0) & live
+        place = jnp.cumsum(wanted) - 1     # a wanted row's rank among them
+
+        def chunk(c, ids):
+            # hit[r, s]: row s is the chunk's r-th. A chunk's unfilled
+            # places read row 0, whose result no row takes
+            hit = wanted & (place == (c * R + jnp.arange(R))[:, None])
+            rows = jnp.argmax(hit, axis=1)
+            t = temp[rows]
+            safe_t = jnp.where(t > 0, t, 1.0)
+            logits = (jnp.log(jnp.clip(probs[rows], 1e-9, None))
+                      / safe_t[:, None])
+            # generate()'s own filter body, with per-slot traced p
+            # (p=1.0 keeps everything)
+            logits = filter_logits(logits, self.top_k,
+                                   top_p[rows][:, None])
+            skeys = jax.vmap(jax.random.fold_in)(keys[rows],
+                                                 emit_idx[rows])
+            sampled = jax.vmap(jax.random.categorical)(skeys, logits)
+            return jnp.where(
+                hit.any(axis=0),
+                jnp.where(hit, sampled.astype(jnp.int32)[:, None],
+                          0).sum(axis=0),
+                ids)
+
+        return jax.lax.fori_loop(0, (place[-1] + R) // R, chunk,
+                                 greedy_ids)
 
     # ------------------------------------------------------ jit builders
     def _shared_jit(self, key, builder):
@@ -770,7 +810,8 @@ class PagedDecodeEngine:
             probs = h[:, -1]                   # [S, V]
             return (tuple(kv), self._sample_ids(probs, keys, emit_idx,
                                                 temp, top_p,
-                                                greedy_only=greedy_only),
+                                                greedy_only=greedy_only,
+                                                live=live),
                     _moe_means(stats))
 
         def decode_step(params, state, kv, block_tables, carry, fresh,
@@ -1907,6 +1948,7 @@ class PagedDecodeEngine:
         self.moe_stats = None
         self.overlapped = False
         self.launched = False
+        self.sample_rows = 0
 
     def _step(self, ahead: bool, speculate, proposers):
         if speculate is None:
@@ -2010,7 +2052,8 @@ class PagedDecodeEngine:
             # two static program variants: the greedy-only decode skips
             # the sampling chain (sort + threefry) — picked whenever no
             # sampled request is in flight, the common serving case
-            if (self.temp[self.active] > 0).any():
+            sample_rows = int(((self.temp > 0) & self.active).sum())
+            if sample_rows:
                 if self._decode_full is None:
                     self._decode_full = self._build_decode(
                         greedy_only=False)
@@ -2064,6 +2107,7 @@ class PagedDecodeEngine:
             return dict(toks=toks, moe=moe, idx=idx, slots=slots,
                         taken=taken, finished=finished,
                         kv_read_pct=kv_read_pct,
+                        sample_rows=sample_rows,
                         positions_read=positions_read,
                         window_held_pct=window_held_pct,
                         weight_gb=weight_bytes / 1e9,
@@ -2081,6 +2125,7 @@ class PagedDecodeEngine:
         self.wait_s += sp.duration_s
         with monitor.span("serve/decode/post", it=it):
             self.kv_read_pct = flight["kv_read_pct"]
+            self.sample_rows = flight["sample_rows"]
             self.positions_read = flight["positions_read"]
             self.window_held_pct = flight["window_held_pct"]
             self.weight_gb = flight["weight_gb"]
@@ -2276,6 +2321,10 @@ class PagedDecodeEngine:
             params, weight_bytes = quant.serving_tree(self.net,
                                                       self.quantize)
             self.weight_gb = weight_bytes / 1e9
+            # the score program is given no live mask: every row with a
+            # temperature goes through its chain (the rs tail has its own)
+            self.sample_rows = (0 if greedy_only or use_rs
+                                else int((self.temp > 0).sum()))
             out = score(
                 params, self.net.net_state, self.pool.kv,
                 self._tables_arg(), jnp.asarray(token_mat),

@@ -910,6 +910,12 @@ class GenerationServer(ParallelInference):
                 "serving_decode_batch_slots",
                 "active slots at each decode dispatch",
                 buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256), **lbl),
+            "sample_rows": reg.histogram(
+                "serving_sample_rows",
+                "rows the sampling chain ran over at each decode "
+                "dispatch: the live slots with a temperature; 0 for a "
+                "dispatch of the greedy twin",
+                buckets=(0, 1, 2, 4, 8, 16, 32), **lbl),
             "kv_read_pct": reg.histogram(
                 "serving_decode_kv_read_pct",
                 "100 x pool blocks a decode dispatch's attention reads "
@@ -1210,6 +1216,7 @@ class GenerationServer(ParallelInference):
                 if m is not None:
                     m["tokens"].inc(n_tok)
                     m["batch_slots"].observe(len(emitted))
+                    m["sample_rows"].observe(eng.sample_rows)
                     m["kv_read_pct"].observe(eng.kv_read_pct)
                     m["weight_gb"].observe(eng.weight_gb)
                     m["overlap_pct"].observe(

@@ -866,6 +866,164 @@ class TestSampledDeterminism:
         np.testing.assert_array_equal(np.asarray(out[0]), ref_tokens[0])
 
 
+def full_matrix_chain(probs, keys, emit_idx, temp, top_p, top_k):
+    """The sampling chain over every row of `probs` [S, V], as the
+    engine ran it before it gathered the sampled rows: the plain
+    reference `_sample_ids` is held to, row for row."""
+    import jax
+    from deeplearning4j_tpu.zoo.transformer import filter_logits
+    greedy_ids = jnp.argmax(probs, axis=-1).astype(jnp.int32)
+    safe_t = jnp.where(temp > 0, temp, 1.0)
+    logits = jnp.log(jnp.clip(probs, 1e-9, None)) / safe_t[:, None]
+    logits = filter_logits(logits, top_k, top_p[:, None])
+    skeys = jax.vmap(jax.random.fold_in)(keys, emit_idx)
+    sampled = jax.vmap(jax.random.categorical)(skeys, logits)
+    return jnp.where(temp > 0, sampled.astype(jnp.int32), greedy_ids)
+
+
+def sorted_shapes(jaxpr):
+    """The operand shapes of every `sort` in `jaxpr`, nested ones too."""
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "sort":
+            out.append(e.invars[0].aval.shape)
+        for sub in e.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                out += sorted_shapes(sub)
+    return out
+
+
+class TestSampleRowsAlone:
+    """`_sample_ids` runs its chain over the rows that sample, a chunk
+    of `_SAMPLE_CHUNK_ROWS` at a time, and every row's id is the one
+    the chain over the whole matrix gives."""
+
+    S, VOCAB = 20, 211
+
+    @pytest.fixture(scope="class")
+    def rows(self):
+        """probs, keys, emit_idx and a temperature and top-p for every
+        row (the cases zero the temperatures of the rows that do not
+        sample)."""
+        import jax
+        rng = np.random.default_rng(11)
+        probs = jax.nn.softmax(jnp.asarray(
+            2.0 * rng.standard_normal((self.S, self.VOCAB)), jnp.float32))
+        keys = jnp.asarray(rng.integers(0, 2 ** 31, (self.S, 2)),
+                           jnp.uint32)
+        emit_idx = jnp.asarray(rng.integers(0, 50, self.S), jnp.int32)
+        temp = rng.choice([0.7, 0.8, 1.0, 1.3], self.S).astype(np.float32)
+        top_p = rng.choice([1.0, 0.95], self.S).astype(np.float32)
+        return probs, keys, emit_idx, temp, jnp.asarray(top_p)
+
+    @staticmethod
+    def engine(net, top_k=None):
+        return PagedDecodeEngine(net, n_slots=2, n_blocks=8, block_len=BL,
+                                 top_k=top_k)
+
+    @staticmethod
+    def sampling(rng_seed, n_rows, n_sampled):
+        """Which `n_sampled` of the rows sample: scattered, not the
+        first ones."""
+        mask = np.zeros(n_rows, bool)
+        mask[np.random.default_rng(rng_seed).choice(
+            n_rows, n_sampled, replace=False)] = True
+        return mask
+
+    @pytest.mark.parametrize("top_k", [None, 5])
+    @pytest.mark.parametrize("n_sampled", ["0", "1", "R", "R+1", "S"])
+    def test_ids_equal_the_full_matrix_chain(self, net, rows, n_sampled,
+                                             top_k):
+        from deeplearning4j_tpu.serving import engine as engine_mod
+        R = engine_mod._SAMPLE_CHUNK_ROWS
+        assert R + 1 < self.S
+        n = {"0": 0, "1": 1, "R": R, "R+1": R + 1, "S": self.S}[n_sampled]
+        probs, keys, emit_idx, temp, top_p = rows
+        temp = jnp.asarray(np.where(self.sampling(n, self.S, n), temp, 0.0))
+        want = np.asarray(full_matrix_chain(probs, keys, emit_idx, temp,
+                                            top_p, top_k))
+        got = np.asarray(self.engine(net, top_k)._sample_ids(
+            probs, keys, emit_idx, temp, top_p))
+        np.testing.assert_array_equal(got, want)
+        greedy = np.asarray(jnp.argmax(probs, axis=-1))
+        # the case means something: sampled rows left the argmax
+        assert (want != greedy).sum() >= n // 2
+        np.testing.assert_array_equal(got[np.asarray(temp) == 0],
+                                      greedy[np.asarray(temp) == 0])
+
+    def test_one_row_is_one_chunk_of_one(self, net, rows):
+        """A one-row admission's `[1, V]`: the chunk is as long as the
+        matrix, never longer."""
+        probs, keys, emit_idx, temp, top_p = rows
+        eng = self.engine(net)
+        for r in range(4):
+            sl = slice(r, r + 1)
+            args = (probs[sl], keys[sl], emit_idx[sl],
+                    jnp.asarray(temp[sl]), top_p[sl])
+            np.testing.assert_array_equal(
+                np.asarray(eng._sample_ids(*args)),
+                np.asarray(full_matrix_chain(*args, None)))
+
+    @pytest.mark.parametrize("n_stale", [1, 9])
+    def test_a_released_slots_stale_temperature_gets_no_chunk(
+            self, net, rows, n_stale):
+        """`_release` leaves a slot's temperature where it was: a row
+        that is not live is not sampled for, whatever its temperature,
+        and the live rows' ids are what they are without it."""
+        import jax
+        probs, keys, emit_idx, temp, top_p = rows
+        live = ~self.sampling(3, self.S, n_stale)
+        eng = self.engine(net)
+        got = np.asarray(eng._sample_ids(
+            probs, keys, emit_idx, jnp.asarray(temp), top_p,
+            live=jnp.asarray(live)))
+        want = np.asarray(full_matrix_chain(
+            probs, keys, emit_idx, jnp.asarray(np.where(live, temp, 0.0)),
+            top_p, None))
+        np.testing.assert_array_equal(got, want)
+        stale_sampled = np.asarray(full_matrix_chain(
+            probs, keys, emit_idx, jnp.asarray(temp), top_p, None))
+        assert (stale_sampled[~live] != want[~live]).any()
+        # and what is sorted is a chunk inside the loop, never the matrix
+        from deeplearning4j_tpu.serving import engine as engine_mod
+        jaxpr = jax.make_jaxpr(lambda lv: eng._sample_ids(
+            probs, keys, emit_idx, jnp.asarray(temp), top_p, live=lv))(
+                jnp.asarray(live))
+        (loop,) = [e for e in jaxpr.eqns if e.primitive.name == "while"]
+        assert sorted_shapes(jaxpr.jaxpr) == sorted_shapes(
+            loop.params["body_jaxpr"].jaxpr) == [
+                (engine_mod._SAMPLE_CHUNK_ROWS, self.VOCAB)]
+
+    @pytest.mark.parametrize("temperature", [0.0, 0.9])
+    def test_the_family_counts_the_rows_of_each_dispatch(
+            self, net, prompts, temperature):
+        """`serving_sample_rows`, beside `serving_decode_batch_slots`:
+        a greedy request's dispatches observe 0 rows, a sampled
+        request's, alone in the server, 1 each."""
+        from deeplearning4j_tpu import monitor
+        from deeplearning4j_tpu.monitor.registry import MetricsRegistry
+        reg = monitor.enable(registry=MetricsRegistry())
+        srv = GenerationServer(net, n_slots=4, n_blocks=24,
+                               block_len=BL).start()
+        try:
+            # one after the other: a slot released before the next
+            # request keeps its temperature, and must not be counted
+            for r in range(2):
+                srv.generate_async(
+                    prompts[r], 6, temperature=temperature,
+                    rng=np.asarray([0, r], np.uint32)).result(timeout=120)
+        finally:
+            srv.stop()
+            monitor.disable()
+            monitor._STATE.registry = monitor.GLOBAL_REGISTRY
+        snap = reg.snapshot()
+        fam = snap["serving_sample_rows"]["values"][0]
+        steps = snap["serving_decode_batch_slots"]["values"][0]["count"]
+        assert fam["count"] == steps > 0
+        assert fam["sum"] == (steps if temperature else 0)
+
+
 class TestGenerationServer:
     def test_concurrent_streams_greedy_parity(self, net, prompts,
                                               ref_tokens):

@@ -139,6 +139,26 @@ print(json.dumps(out))
 '''
 
 
+_DECODE_SORT = '''
+# the two decode variants of a 32-slot engine: what each one sorts
+from deeplearning4j_tpu.serving import PagedDecodeEngine
+from deeplearning4j_tpu.serving.engine import _SAMPLE_CHUNK_ROWS
+net = lm(vocab_size=512, d_model=128, n_layers=2, n_heads=2, max_len=64)
+eng = PagedDecodeEngine(net, n_slots=32, n_blocks=64, block_len=16)
+args = (tree(eng._params), tree(net.net_state), tree(eng.pool.kv)) + tuple(
+    sds(a) for a in eng._decode_args())
+out = {"chunk_rows": _SAMPLE_CHUNK_ROWS}
+for name, greedy in (("greedy", True), ("full", False)):
+    hlo = jax.jit(eng._decode_body(greedy_only=greedy),
+                  donate_argnums=2).lower(*args).compile().as_text()
+    out[name] = sorted(set(
+        dims for line in hlo.splitlines() if " sort(" in line
+        for dims in re.findall(r"\\[([0-9,]+)\\]",
+                               line.split(" sort(")[0])))
+print(json.dumps(out))
+'''
+
+
 _MLA_DECODE = '''
 # sarvam-105b's decode attention at its published widths: 32 slots, 64
 # heads, a cache row of 576 padded to 640 lanes, bf16 pages of 64
@@ -286,6 +306,18 @@ def test_decode_step_reads_the_served_copy_and_casts_no_weight_on_v5e():
     assert (out["masters"]["argument_bytes"]
             - out["served"]["argument_bytes"]) == pytest.approx(
                 out["master_bytes"] / 2, rel=0.02)
+
+
+def test_decode_step_sorts_a_chunk_of_sampled_rows_and_no_more_on_v5e():
+    """The full decode variant compiled for a described v5e sorts
+    `[_SAMPLE_CHUNK_ROWS, V]`, inside its loop over the sampled rows,
+    and nothing with a row for every slot; the greedy variant sorts
+    nothing."""
+    proc, out = _child(_DECODE_SORT)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["chunk_rows"] < 32
+    assert out["greedy"] == []
+    assert out["full"] == [f"{out['chunk_rows']},512"]
 
 
 def test_latent_decode_kernel_compiles_at_published_widths_on_v5e():
