@@ -1,13 +1,15 @@
 """Pallas TPU kernels — custom fast paths for ops XLA doesn't fuse
 optimally (the deeplearning4j-cuda role: hand-tuned kernels behind the
-same layer API, SURVEY §2.2). Five of them: flash attention (forward
+same layer API, SURVEY §2.2). Six of them: flash attention (forward
 and backward, `flash_attention.py`), fused LayerNorm (`layernorm.py`),
 fused Adam (`fused_adam.py`) and the serving decode step's
 length-bounded paged attention (`paged_attention.py`:
 `dl4tpu_paged_decode`, which reads the K/V pages a slot holds in place
 instead of gathering every slot's whole block table;
 `mla_paged_attention.py`: `dl4tpu_mla_paged_decode`, the same over a
-latent pool in the absorbed form).
+latent pool in the absorbed form) and a state-space layer's prefill
+recurrence (`selective_scan.py`: `dl4tpu_selective_scan`, the state in
+VMEM across time).
 
 Kernel gating (`kernels_enabled`): compiled kernels ride the TPU
 backend by default; on other backends the (slow, python-level)

@@ -249,7 +249,8 @@ def _expected_family(layer: Layer) -> str:
                 # [B,T,D] — none of them wants a time-flattening insert
                 "embedding", "positional_encoding", "transformer_encoder",
                 "latent_attention_block", "rms_norm",
-                "parallel_attention_moe_block", "gain_layer_norm"):
+                "parallel_attention_moe_block", "gain_layer_norm",
+                "hybrid_state_space_block"):
         return "any"
     return "ff"
 

@@ -73,3 +73,4 @@ from deeplearning4j_tpu.nn.layers.parallel import (
     ParallelAttentionMoEBlock,
     TiedLMHead,
 )
+from deeplearning4j_tpu.nn.layers.statespace import HybridStateSpaceBlock

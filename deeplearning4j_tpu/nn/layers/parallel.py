@@ -72,6 +72,161 @@ def rotate_interleaved(x, positions, theta: float):
                      -1).reshape(x.shape).astype(x.dtype)
 
 
+def keep_mask(q_pos, k_pos, window=None):
+    """Which keys a query sees: causal, and inside the window."""
+    keep = (k_pos >= 0) & (k_pos <= q_pos)
+    if window is not None:
+        keep = keep & (q_pos - k_pos < window)
+    return keep
+
+
+def grouped_queries(q, n_kv_heads: int, head_dim: int):
+    """q [B, T, H, Dh] -> [B, T, Hkv, G, Dh]: query head i beside the
+    others that read key head `i // G`."""
+    return q.reshape(q.shape[:2] + (n_kv_heads, -1, head_dim))
+
+
+def attend_blocks(q, k, v, wo, *, n_kv_heads: int, head_dim: int,
+                  window=None, query_block: int = 128,
+                  key_block: int = 4096):
+    """q [B, T, H, Dh], k and v [B, T, Hkv*Dh] of whole sequences at
+    positions 0..T-1 -> the attention output [B, T, D].  A block of
+    queries at a time against the keys it can see (a window layer:
+    the band alone, so the work grows as T x window), keys in chunks
+    of `key_block`: one softmax over all of them (one maximum, one
+    sum, float32), no product wider than a chunk."""
+    B, T = q.shape[:2]
+    q = grouped_queries(q, n_kv_heads, head_dim)
+    k = k.reshape(B, T, n_kv_heads, head_dim)
+    v = v.reshape(B, T, n_kv_heads, head_dim)
+    scale = head_dim ** -0.5
+    qb, kb = min(T, query_block), key_block
+    out = []
+    for q0 in range(0, T, qb):
+        q1 = min(T, q0 + qb)
+        lo = 0 if window is None else max(0, q0 - window + 1)
+        # the span in equal chunks, none wider than `key_block`
+        n_chunks = -(-(q1 - lo) // kb)
+        step = -(-(q1 - lo) // n_chunks)
+        parts = []
+        for k0 in range(lo, q1, step):
+            k1 = min(q1, k0 + step)
+            s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, q0:q1], k[:, k0:k1],
+                           preferred_element_type=jnp.float32)
+            keep = keep_mask(jnp.arange(q0, q1)[:, None],
+                             jnp.arange(k0, k1)[None, :], window)
+            parts.append((k0, k1, jnp.where(keep, s * scale, -jnp.inf)))
+        # a query sees its own key, so the maximum over the chunks
+        # is finite on every row
+        m = parts[0][2].max(-1, keepdims=True)
+        for _, _, s in parts[1:]:
+            m = jnp.maximum(m, s.max(-1, keepdims=True))
+        l, o = 0.0, 0.0
+        for k0, k1, s in parts:
+            e = jnp.exp(s - m)
+            l = l + e.sum(-1, keepdims=True)
+            o = o + jnp.einsum("bhgqk,bkhd->bqhgd", e.astype(v.dtype),
+                               v[:, k0:k1],
+                               preferred_element_type=jnp.float32)
+        out.append((o / jnp.transpose(l, (0, 3, 1, 2, 4))).astype(v.dtype))
+    o = jnp.concatenate(out, 1).reshape(B, T, -1)
+    return jnp.matmul(o, wo)
+
+
+def attend_cached(q, k_rows, v_rows, q_pos, k_pos, wo, *, n_kv_heads: int,
+                  head_dim: int, window=None):
+    """q [S, K, H, Dh] at `q_pos` [S, K] against cache rows
+    [S, L, Hkv*Dh] which hold positions `k_pos` [S, K, L] (as each
+    query sees them; negative: nothing): the plain core the kernel
+    is tested against."""
+    S, L = k_rows.shape[:2]
+    k = k_rows.reshape(S, L, n_kv_heads, head_dim)
+    v = v_rows.reshape(S, L, n_kv_heads, head_dim)
+    s = jnp.einsum("skhgd,slhd->shgkl",
+                   grouped_queries(q, n_kv_heads, head_dim),
+                   k.astype(q.dtype), preferred_element_type=jnp.float32)
+    keep = keep_mask(q_pos[:, :, None], k_pos, window)[:, None, None]
+    p = jax.nn.softmax(jnp.where(keep, s * head_dim ** -0.5, -jnp.inf),
+                       axis=-1)
+    o = jnp.einsum("shgkl,slhd->skhgd", p.astype(q.dtype),
+                   v.astype(q.dtype))
+    return jnp.matmul(o.reshape(o.shape[:2] + (-1,)), wo)
+
+
+def write_rows(pools, rows, block_table, positions, live):
+    """Scatter rows (K, V) [S, K, W] at `positions` [S, K] through
+    the table read as a ring; lanes that are not `live` land in the
+    garbage block."""
+    bl = pools[0].shape[1]
+    idx = (positions // bl) % block_table.shape[1]
+    blk = jnp.take_along_axis(block_table, idx, axis=1)
+    if live is not None:
+        blk = jnp.where(live, blk, 0)
+    return tuple(pool.at[blk, positions % bl].set(r.astype(pool.dtype))
+                 for pool, r in zip(pools, rows))
+
+
+def ring_view(pools, block_table, positions):
+    """-> (K rows, V rows [S, ring*bl, W], k_pos [S, K, ring*bl]):
+    the slot's pages gathered in table order, and the position each
+    row holds as the query at `positions` [S, K] sees it: table
+    column r holds the newest logical block `b = r (mod ring)` not
+    past the query's own, negative where there is none yet."""
+    ring, bl = block_table.shape[1], pools[0].shape[1]
+    views = tuple(p[block_table].reshape(block_table.shape[0], ring * bl,
+                                         p.shape[-1]) for p in pools)
+    at = positions[..., None] // bl                       # [S, K, 1]
+    b = at - (at - jnp.arange(ring)) % ring               # [S, K, ring]
+    k_pos = (b[..., None] * bl + jnp.arange(bl)).reshape(
+        positions.shape + (ring * bl,))
+    return views[0], views[1], k_pos
+
+
+def gqa_in_place(arrays, n_heads: int, n_kv_heads: int) -> bool:
+    """Can the single-token step attend over the (K, V) pools in place
+    (`dl4tpu_paged_decode`)?  The kernels' shared switch and the pool's
+    shape decide; a refusal is warned of once."""
+    from deeplearning4j_tpu import kernels
+    from deeplearning4j_tpu.kernels import paged_attention
+    from deeplearning4j_tpu.nn.layers.attention import (
+        _warn_paged_fallback)
+    if not kernels.kernels_enabled():
+        return False
+    reason = paged_attention.unsupported_reason(
+        arrays[0].shape, arrays[0].dtype, n_heads, n_kv_heads)
+    if reason is not None:
+        _warn_paged_fallback(reason)
+        return False
+    return True
+
+
+def gqa_paged_attend(q, arrays, block_table, pos, live, wo, *, n_heads: int,
+                     n_kv_heads: int, head_dim: int, window, in_place: bool,
+                     dtype):
+    """The single-token step's attention once the token's K and V rows
+    are in their page: q [S, 1, H, Dh] at `pos` [S] over the pages the
+    slot holds (`dl4tpu_paged_decode`, in place, from the window's first
+    position) or over a gathered view -> [S, 1, D] in `dtype`."""
+    if in_place:
+        from deeplearning4j_tpu.kernels.paged_attention import (
+            paged_decode_attention)
+        lengths = pos + 1
+        if live is not None:
+            lengths = jnp.where(live, lengths, 0)
+        starts = (None if window is None
+                  else jnp.maximum(lengths - window, 0))
+        o = paged_decode_attention(
+            q.reshape(q.shape[:2] + (-1,)), arrays[0], arrays[1],
+            block_table, lengths, n_heads=n_heads,
+            n_kv_heads=n_kv_heads, starts=starts)
+        return jnp.matmul(o.astype(dtype), wo)
+    positions = pos[:, None]
+    k_rows, v_rows, k_pos = ring_view(arrays, block_table, positions)
+    return attend_cached(q, k_rows, v_rows, positions, k_pos, wo,
+                         n_kv_heads=n_kv_heads, head_dim=head_dim,
+                         window=window)
+
+
 @register_layer
 @dataclasses.dataclass(eq=False)
 class GainLayerNorm(Layer):
@@ -232,77 +387,9 @@ class ParallelAttentionMoEBlock(BaseRecurrentLayer):
                 positions, self.rope_theta).reshape(k.shape)
         return q, k, v
 
-    def _keep(self, q_pos, k_pos):
-        """Which keys a query sees: causal, and inside the window."""
-        keep = (k_pos >= 0) & (k_pos <= q_pos)
-        if self.window is not None:
-            keep = keep & (q_pos - k_pos < self.window)
-        return keep
-
-    def _grouped(self, q):
-        """q [B, T, H, Dh] -> [B, T, Hkv, G, Dh]."""
-        return q.reshape(q.shape[:2] + (self.n_kv_heads, -1, self.head_dim))
-
-    def _attend_blocks(self, params, q, k, v):
-        """q [B, T, H, Dh], k and v [B, T, Hkv*Dh] of whole sequences at
-        positions 0..T-1 -> the attention output [B, T, D].  A block of
-        queries at a time against the keys it can see (a window layer:
-        the band alone, so the work grows as T x window), keys in chunks
-        of `key_block`: one softmax over all of them (one maximum, one
-        sum, float32), no product wider than a chunk."""
-        B, T = q.shape[:2]
-        q = self._grouped(q)
-        k = k.reshape(B, T, self.n_kv_heads, self.head_dim)
-        v = v.reshape(B, T, self.n_kv_heads, self.head_dim)
-        scale = self.head_dim ** -0.5
-        qb, kb = min(T, self.query_block), self.key_block
-        out = []
-        for q0 in range(0, T, qb):
-            q1 = min(T, q0 + qb)
-            lo = 0 if self.window is None else max(0, q0 - self.window + 1)
-            # the span in equal chunks, none wider than `key_block`
-            n_chunks = -(-(q1 - lo) // kb)
-            step = -(-(q1 - lo) // n_chunks)
-            parts = []
-            for k0 in range(lo, q1, step):
-                k1 = min(q1, k0 + step)
-                s = jnp.einsum("bqhgd,bkhd->bhgqk", q[:, q0:q1], k[:, k0:k1],
-                               preferred_element_type=jnp.float32)
-                keep = self._keep(jnp.arange(q0, q1)[:, None],
-                                  jnp.arange(k0, k1)[None, :])
-                parts.append((k0, k1, jnp.where(keep, s * scale, -jnp.inf)))
-            # a query sees its own key, so the maximum over the chunks
-            # is finite on every row
-            m = parts[0][2].max(-1, keepdims=True)
-            for _, _, s in parts[1:]:
-                m = jnp.maximum(m, s.max(-1, keepdims=True))
-            l, o = 0.0, 0.0
-            for k0, k1, s in parts:
-                e = jnp.exp(s - m)
-                l = l + e.sum(-1, keepdims=True)
-                o = o + jnp.einsum("bhgqk,bkhd->bqhgd", e.astype(v.dtype),
-                                   v[:, k0:k1],
-                                   preferred_element_type=jnp.float32)
-            out.append((o / jnp.transpose(l, (0, 3, 1, 2, 4))).astype(v.dtype))
-        o = jnp.concatenate(out, 1).reshape(B, T, -1)
-        return jnp.matmul(o, params["wo"])
-
-    def _attend_cached(self, params, q, k_rows, v_rows, q_pos, k_pos):
-        """q [S, K, H, Dh] at `q_pos` [S, K] against cache rows
-        [S, L, Hkv*Dh] which hold positions `k_pos` [S, K, L] (as each
-        query sees them; negative: nothing): the plain core the kernel
-        is tested against."""
-        S, L = k_rows.shape[:2]
-        k = k_rows.reshape(S, L, self.n_kv_heads, self.head_dim)
-        v = v_rows.reshape(S, L, self.n_kv_heads, self.head_dim)
-        s = jnp.einsum("skhgd,slhd->shgkl", self._grouped(q), k.astype(q.dtype),
-                       preferred_element_type=jnp.float32)
-        keep = self._keep(q_pos[:, :, None], k_pos)[:, None, None]
-        p = jax.nn.softmax(jnp.where(keep, s * self.head_dim ** -0.5,
-                                     -jnp.inf), axis=-1)
-        o = jnp.einsum("shgkl,slhd->skhgd", p.astype(q.dtype),
-                       v.astype(q.dtype))
-        return jnp.matmul(o.reshape(o.shape[:2] + (-1,)), params["wo"])
+    def _attn(self) -> dict:
+        return dict(n_kv_heads=self.n_kv_heads, head_dim=self.head_dim,
+                    window=self.window)
 
     def _experts(self, params, h, valid=None, stats=None):
         """h [B, T, D] (the layer's one norm) -> the held experts' part
@@ -339,7 +426,8 @@ class ParallelAttentionMoEBlock(BaseRecurrentLayer):
         pos = jnp.broadcast_to(jnp.arange(x.shape[1]), x.shape[:2])
         h = layer_norm_gain(x, params["norm"], self.eps)
         q, k, v = self._qkv(params, h, pos)
-        a = self._attend_blocks(params, q, k, v)
+        a = attend_blocks(q, k, v, params["wo"], query_block=self.query_block,
+                          key_block=self.key_block, **self._attn())
         valid = None if lengths is None else pos < lengths[:, None]
         return x + a + self._experts(params, h, valid, stats), (k, v)
 
@@ -368,7 +456,8 @@ class ParallelAttentionMoEBlock(BaseRecurrentLayer):
             v_cache, v.astype(v_cache.dtype), pos, 1)
         k_pos = jnp.broadcast_to(jnp.arange(self.cache_len),
                                  (B, T, self.cache_len))
-        a = self._attend_cached(params, q, k_cache, v_cache, positions, k_pos)
+        a = attend_cached(q, k_cache, v_cache, positions, k_pos, params["wo"],
+                          **self._attn())
         return (x + a + self._experts(params, h), {},
                 (k_cache, v_cache, pos + T))
 
@@ -382,47 +471,7 @@ class ParallelAttentionMoEBlock(BaseRecurrentLayer):
         return (carry[0], carry[1])
 
     def paged_in_place(self, arrays) -> bool:
-        from deeplearning4j_tpu import kernels
-        from deeplearning4j_tpu.kernels import paged_attention
-        from deeplearning4j_tpu.nn.layers.attention import (
-            _warn_paged_fallback)
-        if not kernels.kernels_enabled():
-            return False
-        reason = paged_attention.unsupported_reason(
-            arrays[0].shape, arrays[0].dtype, self.n_heads, self.n_kv_heads)
-        if reason is not None:
-            _warn_paged_fallback(reason)
-            return False
-        return True
-
-    @staticmethod
-    def _write_rows(pools, rows, block_table, positions, live):
-        """Scatter rows (K, V) [S, K, W] at `positions` [S, K] through
-        the table read as a ring; lanes that are not `live` land in the
-        garbage block."""
-        bl = pools[0].shape[1]
-        idx = (positions // bl) % block_table.shape[1]
-        blk = jnp.take_along_axis(block_table, idx, axis=1)
-        if live is not None:
-            blk = jnp.where(live, blk, 0)
-        return tuple(pool.at[blk, positions % bl].set(r.astype(pool.dtype))
-                     for pool, r in zip(pools, rows))
-
-    @staticmethod
-    def _ring_view(pools, block_table, positions):
-        """-> (K rows, V rows [S, ring*bl, W], k_pos [S, K, ring*bl]):
-        the slot's pages gathered in table order, and the position each
-        row holds as the query at `positions` [S, K] sees it: table
-        column r holds the newest logical block `b = r (mod ring)` not
-        past the query's own, negative where there is none yet."""
-        ring, bl = block_table.shape[1], pools[0].shape[1]
-        views = tuple(p[block_table].reshape(block_table.shape[0], ring * bl,
-                                             p.shape[-1]) for p in pools)
-        at = positions[..., None] // bl                       # [S, K, 1]
-        b = at - (at - jnp.arange(ring)) % ring               # [S, K, ring]
-        k_pos = (b[..., None] * bl + jnp.arange(bl)).reshape(
-            positions.shape + (ring * bl,))
-        return views[0], views[1], k_pos
+        return gqa_in_place(arrays, self.n_heads, self.n_kv_heads)
 
     def paged_step(self, params, x, arrays, block_table, pos, live=None, *,
                    stats=None):
@@ -434,26 +483,12 @@ class ParallelAttentionMoEBlock(BaseRecurrentLayer):
         positions = pos[:, None]
         h = layer_norm_gain(x, params["norm"], self.eps)
         q, k, v = self._qkv(params, h, positions)
-        arrays = self._write_rows(arrays, (k, v), block_table, positions,
-                                  None if live is None else live[:, None])
-        if self.paged_in_place(arrays):
-            from deeplearning4j_tpu.kernels.paged_attention import (
-                paged_decode_attention)
-            lengths = pos + 1
-            if live is not None:
-                lengths = jnp.where(live, lengths, 0)
-            starts = (None if self.window is None
-                      else jnp.maximum(lengths - self.window, 0))
-            o = paged_decode_attention(
-                q.reshape(q.shape[:2] + (-1,)), arrays[0], arrays[1],
-                block_table, lengths, n_heads=self.n_heads,
-                n_kv_heads=self.n_kv_heads, starts=starts)
-            a = jnp.matmul(o.astype(h.dtype), params["wo"])
-        else:
-            k_rows, v_rows, k_pos = self._ring_view(arrays, block_table,
-                                                    positions)
-            a = self._attend_cached(params, q, k_rows, v_rows, positions,
-                                    k_pos)
+        arrays = write_rows(arrays, (k, v), block_table, positions,
+                            None if live is None else live[:, None])
+        a = gqa_paged_attend(
+            q, arrays, block_table, pos, live, params["wo"],
+            n_heads=self.n_heads, in_place=self.paged_in_place(arrays),
+            dtype=h.dtype, **self._attn())
         valid = None if live is None else live[:, None]
         return x + a + self._experts(params, h, valid, stats), arrays
 
@@ -470,7 +505,8 @@ class ParallelAttentionMoEBlock(BaseRecurrentLayer):
         live = j < n_valid[:, None]
         h = layer_norm_gain(x, params["norm"], self.eps)
         q, k, v = self._qkv(params, h, positions)
-        arrays = self._write_rows(arrays, (k, v), block_table, positions, live)
-        k_rows, v_rows, k_pos = self._ring_view(arrays, block_table, positions)
-        a = self._attend_cached(params, q, k_rows, v_rows, positions, k_pos)
+        arrays = write_rows(arrays, (k, v), block_table, positions, live)
+        k_rows, v_rows, k_pos = ring_view(arrays, block_table, positions)
+        a = attend_cached(q, k_rows, v_rows, positions, k_pos, params["wo"],
+                          **self._attn())
         return x + a + self._experts(params, h, live, stats), arrays

@@ -263,8 +263,21 @@ class PagedDecodeEngine:
                 f"speculative depth {self.spec_k} exceeds the stream "
                 f"budget {budget} — no slot could ever take a full-"
                 f"depth dispatch")
-        self.pool = PagedKVPool(net, n_blocks, block_len, window_blocks)
+        self.pool = PagedKVPool(net, n_blocks, block_len, window_blocks,
+                                n_slots=self.n_slots)
         self.block_len = int(block_len)
+        # a THIRD kind of cache: layers that keep a state of fixed size
+        # a slot beside the pages (`pool.kv[n_paged:]`). What would need
+        # the state at a position other than a slot's last (a prefix
+        # hit, a rejected draft) or a place for it on the wire refuses
+        # such a net
+        self.state_layers = len(self.pool.state_indices)
+        if self.state_layers:
+            if prefix_cache == "radix":
+                self._refuse_state("the radix prefix cache")
+            if self.spec_k is not None:
+                self._refuse_state("speculative decoding (the K-wide "
+                                   "score program)")
         # two kinds of cache: the window layers' ring of blocks a slot
         # (`window_ring` table columns, their own pool and allocator);
         # 0 for a net with no window layer, whose programs and tables
@@ -288,7 +301,8 @@ class PagedDecodeEngine:
         # counts from
         self._in_place = tuple(
             net.layers[i].paged_in_place(arrays)
-            for i, arrays in zip(self.pool.layer_indices, self.pool.kv))
+            for i, arrays in zip(self.pool.layer_indices,
+                                 self.pool.kv[:self.pool.n_paged]))
         # prefill: a layer that implements `forward_prefill` hands back
         # the rows its pages are cut from, prompt-long, and the wave's
         # last positions alone go through the layers after the last
@@ -296,7 +310,12 @@ class PagedDecodeEngine:
         # monolithic carries (`get_prefill_bucketed`), budget-long
         self._paged_prefill = all(
             hasattr(net.layers[i], "forward_prefill")
-            for i in self.pool.layer_indices)
+            for i in self.pool.layer_indices + self.pool.state_indices)
+        if self.state_layers and not self._paged_prefill:
+            raise ValueError(
+                "a net with per-slot state layers is prefilled through "
+                "`forward_prefill` (the state each row reached at its last "
+                "real token); a paged or state layer of this net lacks it")
         # admission bounded in tokens: no prefill program is wider than
         # `max_prefill_tokens` (wave width x prompt bucket); prompts
         # pad to at least `min_prefill_bucket` (a grid no traffic uses
@@ -316,7 +335,7 @@ class PagedDecodeEngine:
                 "paged decode does not support input preprocessors "
                 f"(found at {sorted(net.conf.input_preprocessors)})")
         self._plan: List[Tuple] = []
-        pool_j = 0
+        pool_j, state_j = 0, self.pool.n_paged
         for i, layer in enumerate(net.layers):
             if getattr(layer, "paged_cache", False):
                 # a two-pool net's entries also say which table the
@@ -328,10 +347,20 @@ class PagedDecodeEngine:
                 pool_j += 1
             elif isinstance(layer, PositionalEncodingLayer):
                 self._plan.append(("pos", i))
+            elif getattr(layer, "slot_state", False):
+                # the layer's per-slot state: entry `state_j` of the
+                # pools, no table
+                self._plan.append(("state", i, state_j))
+                state_j += 1
             elif isinstance(layer, BaseRecurrentLayer):
                 raise ValueError(
                     f"layer {i} ({type(layer).__name__}) carries "
-                    "recurrent state but has no paged decode path")
+                    "recurrent state but declares no way to serve it: "
+                    "either the paged protocol (`paged_cache`, "
+                    "`paged_pool_arrays`, `paged_step`) or a per-slot "
+                    "state (`slot_state`, `slot_state_arrays(n_slots, "
+                    "dtype)`, `state_step`, `forward_prefill`); "
+                    "docs/SERVING.md")
             else:
                 self._plan.append(("plain", i))
         # truncated-drafter plan: the SAME walk minus the deep blocks —
@@ -490,6 +519,15 @@ class PagedDecodeEngine:
         # the window layers hold for the decoding slots over the
         # positions those slots have reached (None: no window layer)
         self.window_held_pct: Optional[float] = None
+        # for a net with per-slot state layers: the bytes / 1e9 of the
+        # state arrays the last decode dispatch read back was handed, in
+        # and out, each micro-step (structural: the arrays' own size,
+        # whatever the slots that decode);
+        # and of the last admission wave, 100 x the positions of its
+        # prefill (width x bucket) past their row's last token, which
+        # the layers' scan is dispatched over all the same
+        self.state_gb = 0.0
+        self.scan_pad_pct: Optional[float] = None
 
     def _refuse_two_pools(self, what: str):
         """What cannot take a second kind of pool yet refuses such a net
@@ -500,6 +538,18 @@ class PagedDecodeEngine:
                 f"window layers keep a ring of {self.window_ring} blocks "
                 f"of their own pool beside it (two kinds of cache in one "
                 f"manager): not supported yet")
+
+    def _refuse_state(self, what: str):
+        """What cannot serve a per-slot state yet refuses such a net
+        loudly."""
+        if self.state_layers:
+            raise NotImplementedError(
+                f"{what} needs a layer's state at a position other than "
+                f"the slot's last, or a place for it beside the pages; "
+                f"this net has {self.state_layers} layers that keep a "
+                f"recurrent state of fixed size a slot, of which the "
+                f"manager holds the newest alone (no snapshot): not "
+                f"supported yet")
 
     # ------------------------------------------------------------ queries
     @property
@@ -802,6 +852,9 @@ class PagedDecodeEngine:
                     h, _ = layer.forward(lp, ls, h, train=False, rng=None)
                 elif kind == "pos":
                     h, _ = layer.forward_at_positions(lp, ls, h, pos)
+                elif kind == "state":
+                    j = entry[2]
+                    h, kv[j] = layer.state_step(lp, h, kv[j], live)
                 else:
                     j = entry[2]
                     h, kv[j] = layer.paged_step(
@@ -898,19 +951,21 @@ class PagedDecodeEngine:
     def _prefill_paged_body(self):
         """Prefill through the paged protocol: each paged layer's
         `forward_prefill` returns the rows its pages are cut from (as
-        long as the prompt bucket, padded to whole blocks), and only
+        long as the prompt bucket, padded to whole blocks), each state
+        layer's the state every row reached at its last real token, and only
         each prompt's LAST position goes through the layers after the
         last paged one (the final norm, the vocabulary head: logits of
         every position of an 8k-token prompt would be gigabytes)."""
         net, layers, plan = self.net, self.net.layers, self._plan
         bl = self.block_len
-        last_block = max(n for n, e in enumerate(plan) if e[0] == "block")
+        last_cached = max(n for n, e in enumerate(plan)
+                          if e[0] in ("block", "state"))
 
         def prefill(params, state, x, last_idx):
             params = net.dtype.cast_params(params)
             h = x
             lengths = last_idx + 1
-            rows_out = []
+            rows_out, states_out = [], []
             stats = {}
             for n, entry in enumerate(plan):
                 kind, i = entry[0], entry[1]
@@ -923,6 +978,10 @@ class PagedDecodeEngine:
                     h, _ = layer.forward_at_positions(
                         lp, ls, h, jnp.broadcast_to(
                             jnp.arange(h.shape[1]), h.shape[:2]))
+                elif kind == "state":
+                    h, reached = layer.forward_prefill(lp, h, lengths,
+                                                       stats=stats)
+                    states_out.append(tuple(reached))
                 else:
                     h, rows = layer.forward_prefill(lp, h, lengths,
                                                     stats=stats)
@@ -930,9 +989,12 @@ class PagedDecodeEngine:
                     rows_out.append(tuple(
                         jnp.pad(r, ((0, 0), (0, pad), (0, 0)))
                         for r in rows))
-                if n == last_block:
+                if n == last_cached:
                     h = h[jnp.arange(h.shape[0]), last_idx][:, None]
-            return h[:, -1], tuple(rows_out), _moe_means(stats)
+            # in the pools' own order: the paged layers' rows, then the
+            # state layers' states
+            return (h[:, -1], tuple(rows_out) + tuple(states_out),
+                    _moe_means(stats))
 
         return prefill
 
@@ -978,6 +1040,7 @@ class PagedDecodeEngine:
         to win."""
         bl = self.block_len
         two, kinds = bool(self.window_ring), self.pool.window_layers
+        n_paged, n_state = self.pool.n_paged, self.state_layers
 
         def admit_finish(kv, rows, block_carries, probs, keys, emit0,
                          temp, top_p):
@@ -987,9 +1050,14 @@ class PagedDecodeEngine:
             # the sampled-rng emit offset (nonzero for a requeued
             # continuation — its stream keeps the fold_in(key, t)
             # indices it would have had uninterrupted)
-            # a two-pool net's `rows` is the (full, window) pair
+            # a two-pool net's `rows` is the (full, window) pair; a net
+            # with state layers pairs that with the wave's slots [k]
+            # (`n_slots`, out of range, for a dummy row: dropped)
+            if n_state:
+                rows, slots = rows
             out = []
-            for pools, caches, ring in zip(kv, block_carries, kinds):
+            for pools, caches, ring in zip(kv[:n_paged], block_carries,
+                                           kinds):
                 C = caches[0].shape[1]     # [k, C, ...] -> pages
                 flat_rows = (rows[ring] if two else rows)[
                     :, :C // bl].reshape(-1)
@@ -998,13 +1066,23 @@ class PagedDecodeEngine:
                         (k * (C // bl), bl, pool.shape[-1])
                     ).astype(pool.dtype))
                     for pool, cache in zip(pools, caches)))
+            for arrays, reached, axes in zip(kv[n_paged:],
+                                             block_carries[n_paged:],
+                                             self.pool.state_axes):
+                # a slot's row is overwritten whole: whatever the slot's
+                # last request left is gone
+                out.append(tuple(
+                    a.at[(slice(None),) * ax + (slots,)].set(
+                        r.astype(a.dtype), mode="drop")
+                    for a, r, ax in zip(arrays, reached, axes)))
             firsts = self._sample_ids(probs, keys, emit0, temp, top_p,
                                       greedy_only=greedy_only)
             return tuple(out), firsts
 
         return self._shared_jit(
             ("admit", int(k), greedy_only, self.block_len, self.top_k)
-            + ((kinds,) if two else ()),
+            + ((kinds,) if two else ())
+            + ((("state", n_state),) if n_state else ()),
             lambda: jax.jit(admit_finish,
                             donate_argnums=donate_argnums(0)))
 
@@ -1220,6 +1298,7 @@ class PagedDecodeEngine:
         cannot host the prefix right now — registration is a capacity
         commitment, not a best-effort hint."""
         self._refuse_two_pools("a registered prefix")
+        self._refuse_state("a registered prefix")
         prompt = np.asarray(token_ids)
         if prompt.ndim == 2 and prompt.shape[0] == 1:
             prompt = prompt[0]
@@ -1519,7 +1598,7 @@ class PagedDecodeEngine:
             probs, block_carries, moe = self._run_prefill(prompts,
                                                           last_idx)
             max_rows = max(c[0].shape[1] // self.block_len
-                           for c in block_carries)
+                           for c in block_carries[:self.pool.n_paged])
             rows = np.full((k2, max_rows), GARBAGE_BLOCK, np.int32)
             ring_rows = (np.full((k2, max_rows), GARBAGE_BLOCK, np.int32)
                          if self.window_ring else None)
@@ -1554,9 +1633,15 @@ class PagedDecodeEngine:
             if fin is None:
                 fin = self._admit_finish[(k2, greedy)] = \
                     self._build_admit_finish(k2, greedy)
+            tables = self._tables_arg(rows, ring_rows)
+            if self.state_layers:
+                # each row's slot, whose state rows the wave's are
+                # written to; a dummy row's is out of range: dropped
+                slots = np.full(k2, self.n_slots, np.int32)
+                slots[:k] = [w["slot"] for w in wave]
+                tables = (tables, jnp.asarray(slots))
             self.pool.kv, firsts = fin(
-                self.pool.kv, self._tables_arg(rows, ring_rows),
-                block_carries, probs,
+                self.pool.kv, tables, block_carries, probs,
                 jnp.asarray(keys), jnp.asarray(emit0), jnp.asarray(temps),
                 jnp.asarray(top_ps))
         with monitor.span("serve/admit/wait", it=it) as sp:
@@ -1575,6 +1660,9 @@ class PagedDecodeEngine:
                          if int(w["r"].get("emit_start") or 0))
             self.goodput.account(useful=fresh, preempt_discard=redone,
                                  pad_waste=k2 * Pb - fresh - redone)
+            if self.state_layers:
+                self.scan_pad_pct = 100.0 * (
+                    k2 * Pb - fresh - redone) / (k2 * Pb)
 
             for j, w in enumerate(wave):
                 self._finish_admission(w, int(firsts[j]), keys[j], results)
@@ -2065,6 +2153,12 @@ class PagedDecodeEngine:
                 decode = self._decode_greedy
             kv_read_pct, positions_read = self._kv_read()
             window_held_pct = self._window_held()
+            # the state arrays this dispatch's program is handed go
+            # through each micro-step whole, read and written: reckoned
+            # from the arrays themselves, so a program that is handed
+            # fewer rows moves the number
+            state_gb = (2 * self.steps_per_dispatch
+                        * self.pool.state_bytes() / 1e9)
             # was the step before this one still unread at the launch?
             overlapped = self._flight is not None
             params, weight_bytes = quant.serving_tree(self.net,
@@ -2110,7 +2204,7 @@ class PagedDecodeEngine:
                         sample_rows=sample_rows,
                         positions_read=positions_read,
                         window_held_pct=window_held_pct,
-                        weight_gb=weight_bytes / 1e9,
+                        weight_gb=weight_bytes / 1e9, state_gb=state_gb,
                         overlapped=overlapped)
 
     def _collect(self):
@@ -2129,6 +2223,7 @@ class PagedDecodeEngine:
             self.positions_read = flight["positions_read"]
             self.window_held_pct = flight["window_held_pct"]
             self.weight_gb = flight["weight_gb"]
+            self.state_gb = flight["state_gb"]
             self.overlapped = flight["overlapped"]
             taken = flight["taken"]
             emitted: Dict[int, List[int]] = {}
@@ -2488,6 +2583,7 @@ class PagedDecodeEngine:
         A pool whose layers declare other arrays (a latent pool: one
         array, no head axis) has no place on it."""
         self._refuse_two_pools("the prefill->decode handoff wire")
+        self._refuse_state("the prefill->decode handoff wire")
         for i, arrays in zip(self.pool.layer_indices, self.pool.kv):
             layer = self.net.layers[i]
             if (len(arrays) != 2

@@ -17,8 +17,10 @@ Split of responsibilities:
 
 - device: the block pools (whatever arrays each paged layer declares:
   a (K, V) pair per transformer block, one latent array per latent
-  attention block; all dtype = the net's compute dtype) and the layers'
-  own cached steps (`paged_step`: docs/SERVING.md, the paged protocol);
+  attention block; all dtype = the net's compute dtype), the per-slot
+  states of the layers that declare one, and the layers' own cached
+  steps (`paged_step`, `state_step`: docs/SERVING.md, the paged
+  protocol);
 - host: free/used accounting (`BlockAllocator`) and the block tables,
   which ride h2d once per scheduler step.
 
@@ -354,10 +356,20 @@ class PagedKVPool:
     `window_blocks` blocks and are granted by `window_allocator`, the
     others `n_blocks` by `allocator`: one block id names the same page
     in every layer of its kind. `window` is None, and there is one
-    allocator, for a net with no window layer."""
+    allocator, for a net with no window layer.
+
+    A THIRD kind: a layer that declares `slot_state` (a recurrent or
+    state-space layer, `layer.slot_state_arrays(n_slots, dtype)`) keeps
+    a state of FIXED size a serving slot, whatever the slot's length:
+    arrays with a row a slot, which has no block table and needs no
+    allocator (a slot's row is its allocation; an admission overwrites
+    it whole).  Their entries follow the paged layers' in `kv`, in layer
+    order (`kv[n_paged:]`, layers `state_indices`), so that every
+    program that is handed the pools carries, donates and returns the
+    states with them."""
 
     def __init__(self, net, n_blocks: int, block_len: int,
-                 window_blocks: Optional[int] = None):
+                 window_blocks: Optional[int] = None, n_slots: int = 0):
         if block_len < 1:
             raise ValueError(f"block_len must be >= 1; got {block_len}")
         self.block_len = int(block_len)
@@ -368,7 +380,10 @@ class PagedKVPool:
             raise ValueError(
                 "PagedKVPool needs at least one layer that implements the "
                 "paged protocol (TransformerEncoderBlock, "
-                "LatentAttentionBlock); got "
+                "LatentAttentionBlock, an attention layer of "
+                "HybridStateSpaceBlock: a layer that keeps a per-slot "
+                "state alone gives the manager no page to keep a slot's "
+                "length by); got "
                 f"{[type(l).__name__ for l in net.layers]}")
         windows = [getattr(net.layers[i], "paged_window", None)
                    for i in self.layer_indices]
@@ -397,9 +412,29 @@ class PagedKVPool:
                 self.window_blocks if ring else self.n_blocks,
                 self.block_len, dtype))
             for i, ring in zip(self.layer_indices, self.window_layers))
+        self.n_paged = len(self.kv)
+        self.state_indices = [i for i, l in enumerate(net.layers)
+                              if getattr(l, "slot_state", False)]
+        if self.state_indices and n_slots < 1:
+            raise ValueError(
+                "a net whose layers keep a per-slot state needs n_slots, "
+                "the rows of each state array")
+        self.kv += tuple(
+            tuple(net.layers[i].slot_state_arrays(int(n_slots), dtype))
+            for i in self.state_indices)
+        # which axis of each state array is the slot's
+        self.state_axes: Tuple = tuple(
+            tuple(net.layers[i].slot_state_axes) for i in self.state_indices)
         self.allocator = BlockAllocator(self.n_blocks)
         self.window_allocator = (None if self.window is None else
                                  BlockAllocator(self.window_blocks))
+
+    def state_bytes(self) -> int:
+        """Bytes of the per-slot state arrays as they stand in `kv`: what
+        a program that is handed the pools takes, and returns, of them
+        (0 for a net with no state layer)."""
+        return sum(a.nbytes for arrays in self.kv[self.n_paged:]
+                   for a in arrays)
 
     def ring_blocks(self, max_blocks: int) -> int:
         """Columns of a window layer's block table: the blocks the

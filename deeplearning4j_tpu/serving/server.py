@@ -995,6 +995,21 @@ class GenerationServer(ParallelInference):
                 "decoding slots / positions those slots have reached, "
                 "a decode dispatch",
                 buckets=(10, 20, 30, 40, 50, 60, 70, 80, 90, 100), **lbl)
+        if self.engine.state_layers:
+            # a net with layers that keep a state of fixed size a slot
+            fams["state_gb"] = reg.histogram(
+                "serving_decode_state_gb",
+                "bytes / 1e9 of per-slot recurrent state a decode "
+                "dispatch's program reads and writes: every slot's row "
+                "of every state layer, in and out, each micro-step",
+                buckets=(0.001, 0.01, 0.1, 0.5, 1, 2, 4, 8, 16), **lbl)
+            fams["scan_pad"] = reg.histogram(
+                "serving_scan_pad_pct",
+                "100 x positions of an admission wave's prefill (width x "
+                "bucket) past their row's last token, which the state "
+                "layers' scan is dispatched over all the same",
+                buckets=(0, 5, 10, 20, 30, 40, 50, 60, 70, 80, 90, 100),
+                **lbl)
         fams["spec_accept"].set(1.0)
         for g in fams["spec_accept_by"].values():
             g.set(1.0)
@@ -1274,7 +1289,9 @@ class GenerationServer(ParallelInference):
         """What the engine read back with the last dispatch's tokens:
         the routed expert layers' rows and load (decode and admission
         dispatches alike), the positions a decode dispatch's attention
-        read.  Families of a net with no such layer observe nothing."""
+        read, the state a decode dispatch moved and the padding an
+        admission wave's scan ran over.  Families of a net with no such
+        layer observe nothing."""
         if eng.moe_stats is not None:
             m["moe_rows"].observe(eng.moe_stats[0])
             m["moe_load"].observe(eng.moe_stats[1])
@@ -1282,6 +1299,11 @@ class GenerationServer(ParallelInference):
             m["positions_read"].inc(eng.positions_read)
         if decode and eng.window_held_pct is not None:
             m["window_held"].observe(eng.window_held_pct)
+        if eng.state_layers:
+            if decode:
+                m["state_gb"].observe(eng.state_gb)
+            elif eng.scan_pad_pct is not None:
+                m["scan_pad"].observe(eng.scan_pad_pct)
 
     def _intake(self, eng, m) -> bool:
         """Control requests, cancellations, and the submit queue drained

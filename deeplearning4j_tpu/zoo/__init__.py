@@ -17,3 +17,4 @@ from deeplearning4j_tpu.zoo.facenet import FaceNetNN4Small2
 from deeplearning4j_tpu.zoo.transformer import TransformerClassifier, TransformerLM
 from deeplearning4j_tpu.zoo.latent_moe import LatentMoELM
 from deeplearning4j_tpu.zoo.parallel_moe import ParallelMoELM
+from deeplearning4j_tpu.zoo.hybrid_statespace import HybridStateSpaceLM

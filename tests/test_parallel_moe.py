@@ -168,7 +168,8 @@ def test_grouped_heads_are_the_repeated_heads(ref, model):
     h = jax.random.normal(jax.random.PRNGKey(4), (1, 17, cfg["hidden_size"]))
     pos = jnp.arange(17)[None]
     q, k, v = block._qkv(p, h, pos)
-    got = block._attend_blocks(p, q, k, v)
+    from deeplearning4j_tpu.nn.layers.parallel import attend_blocks
+    got = attend_blocks(q, k, v, p["wo"], **block._attn())
     kr = jnp.repeat(k.reshape(17, Hkv, Dh), H // Hkv, axis=1)
     vr = jnp.repeat(v.reshape(17, Hkv, Dh), H // Hkv, axis=1)
     s = jnp.einsum("qhd,khd->hqk", q[0], kr) * Dh ** -0.5

@@ -240,6 +240,37 @@ print(json.dumps({"kernels": sorted(names), "name": KERNEL_NAME,
 '''
 
 
+_SSM_SCAN = '''
+# AI21-Jamba2-3B's Mamba layer at its published widths (5,120 channels,
+# 16 state columns): the prefill recurrence of a 4 x 2,048 wave through
+# the layer's own forward_prefill, and the decode step over 64 slots'
+# states (narrow MLP: the mixer is what is tried)
+from deeplearning4j_tpu.kernels.selective_scan import KERNEL_NAME
+from deeplearning4j_tpu.nn.layers.statespace import HybridStateSpaceBlock
+bf, i32 = jnp.bfloat16, jnp.int32
+shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=S)
+blk = HybridStateSpaceBlock(n_in=2560, ffn_hidden=256, dt_rank=160)
+params = jax.tree_util.tree_map(
+    lambda a: shape(a.shape, a.dtype),
+    jax.eval_shape(lambda: blk.init_params(jax.random.PRNGKey(0), bf)))
+pre = jax.jit(lambda p, x, n: blk.forward_prefill(p, x, n)).lower(
+    params, shape((4, 2048, 2560), bf), shape((4,), i32))
+names = sorted(set(re.findall(r'kernel_name = "([^"]+)"', pre.as_text())))
+pre.compile()
+state = tuple(shape(a.shape, a.dtype) for a in jax.eval_shape(
+    lambda: blk.slot_state_arrays(64, bf)))
+step = jax.jit(lambda p, x, st, live: blk.state_step(p, x, st, live),
+               donate_argnums=2)
+hlo = step.lower(params, shape((64, 1, 2560), bf), state,
+                 shape((64,), jnp.bool_)).compile().as_text()
+print(json.dumps({"kernels": names, "name": KERNEL_NAME,
+                  "state": [list(a.shape) for a in state],
+                  "step_has_kernel": KERNEL_NAME in hlo,
+                  "state_copies": len(re.findall(
+                      r"= f32\\[64,16,5120\\]\\S* copy\\(", hlo))}))
+'''
+
+
 def _child(body, *, import_package=True):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("LIBTPU_INIT_ARGS", None)
@@ -343,6 +374,18 @@ def test_grouped_windowed_decode_compiles_at_published_widths_on_v5e():
     assert out["kernels"] == [out["name"]] == ["dl4tpu_paged_decode"]
     assert out["in_place"] is True and out["step_has_kernel"] is True
     assert out["pool_copies"] == 0
+
+
+def test_selective_scan_compiles_at_published_widths_on_v5e():
+    """`dl4tpu_selective_scan` at 5,120 channels and 16 state columns
+    lowers through Mosaic for a described v5e inside the Mamba layer's
+    prefill of a 4 x 2,048 wave; the decode step over 64 slots' states is
+    XLA's own (no kernel) and copies no state array."""
+    proc, out = _child(_SSM_SCAN)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["kernels"] == [out["name"]] == ["dl4tpu_selective_scan"]
+    assert out["state"] == [[64, 16, 5120], [3, 64, 5120]]
+    assert out["step_has_kernel"] is False and out["state_copies"] == 0
 
 
 def test_256_row_prefill_compiles_with_the_packages_libtpu_stacks():
