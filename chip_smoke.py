@@ -125,7 +125,7 @@ def loss_log(on_step=None):
 def phase_train(cfg, facts, on_tpu):
     import jax
 
-    from deeplearning4j_tpu.kernels import fused_adam, layernorm
+    from deeplearning4j_tpu.kernels import layernorm
     from deeplearning4j_tpu.kernels.flash_attention import (
         KERNEL_NAMES as FLASH_KERNELS)
     from deeplearning4j_tpu.nd.donation import donation_safe
@@ -172,8 +172,7 @@ def phase_train(cfg, facts, on_tpu):
     text = net.lower_train_step(X[:cfg["batch"]], Y[:cfg["batch"]],
                                 steps=cfg["spe"]).as_text()
     mosaic = set(re.findall(r'kernel_name = "([^"]+)"', text))
-    expected = (FLASH_KERNELS + layernorm.KERNEL_NAMES
-                + (fused_adam.KERNEL_NAME,))
+    expected = FLASH_KERNELS + layernorm.KERNEL_NAMES
     facts["mosaic_kernels"] = {k: k in mosaic for k in expected}
     facts["tpu_custom_calls"] = text.count("tpu_custom_call")
     say(f"train: Mosaic-compiled kernels in the step: "

@@ -1,9 +1,9 @@
 """Pallas TPU kernels — custom fast paths for ops XLA doesn't fuse
 optimally (the deeplearning4j-cuda role: hand-tuned kernels behind the
-same layer API, SURVEY §2.2). Six of them: flash attention (forward
+same layer API, SURVEY §2.2). Five of them: flash attention (forward
 and backward, `flash_attention.py`), fused LayerNorm (`layernorm.py`),
-fused Adam (`fused_adam.py`) and the serving decode step's
-length-bounded paged attention (`paged_attention.py`:
+the serving decode step's length-bounded paged attention
+(`paged_attention.py`:
 `dl4tpu_paged_decode`, which reads the K/V pages a slot holds in place
 instead of gathering every slot's whole block table;
 `mla_paged_attention.py`: `dl4tpu_mla_paged_decode`, the same over a
@@ -30,8 +30,8 @@ _ON = ("1", "on", "true", "yes")
 
 
 def kernels_enabled() -> bool:
-    """Should the Pallas fused-kernel fast paths (LayerNorm, fused
-    Adam, paged decode attention) dispatch? Env override wins;
+    """Should the Pallas fused-kernel fast paths (LayerNorm, paged
+    decode attention) dispatch? Env override wins;
     default = TPU backend only."""
     env = os.environ.get(_ENV_VAR)
     if env is not None and env.strip():

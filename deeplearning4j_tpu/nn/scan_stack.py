@@ -16,13 +16,17 @@ to both containers:
   run's params stacked along a leading axis — the block body is traced
   and compiled once regardless of depth, and gradients flow back to the
   per-layer param tree through the stack op,
-- `pack_tree` / `unpack_tree` move that stacking to the TRAIN-STEP
-  boundary: run params/updater-state enter the fused program as one
-  stacked entry (``stacked::<keys>``), stay stacked through forward,
-  backward, and the (elementwise, therefore batch-oblivious) updater,
-  and unpack back to the per-layer tree only at program exit — so the
-  optimizer side of the program stops scaling with depth too, and no
-  per-step stack/unstack equations survive in the jaxpr,
+- `pack_tree` moves that stacking to the TRAIN-STEP boundary, for the
+  COMPUTE-dtype copy alone: the containers cast the per-layer params
+  and stack each run of the cast tree into one entry
+  (``stacked::<keys>``) that the loss is differentiated against, so
+  forward scan and backward stay depth-independent, gradients come out
+  stacked, and no per-step stack/unstack equations sit between them.
+  The float32 masters and the updater state are never stacked: the
+  updater walk hands layer `i` slice `i` of a run's stacked gradient
+  and updates each leaf where it lies (`Trainable._apply_updates`);
+  `unpack_tree` slices a stacked tree back to per-layer keys (the
+  diagnostics' view of a run's gradients, the gradient-sharing cores),
 - `remat_wrap` / `effective_remat_policy` generalize rematerialization
   from the transformer-only `remat` flag into a per-layer
   ``remat_policy`` conf field (``none | full | dots_saveable`` via
@@ -342,11 +346,14 @@ def scan_forward(template, stacked, h, *, train: bool, rng,
 
 
 # -------------------------------------------------- boundary pack/unpack
-# Train-step programs carry each homogeneous run as ONE stacked tree
-# entry instead of per-layer keys: packed at program entry, unpacked at
-# exit, stacked in between — forward, backward, AND the elementwise
-# updater all operate on the stacked representation, so no per-step
-# stack/unstack equations survive anywhere in the program body.
+# The fused train step differentiates a tree whose homogeneous runs are
+# each ONE stacked entry instead of per-layer keys: the compute-dtype
+# copy, stacked after the cast. Forward and backward operate on the
+# stacked representation; the masters and the updater state keep their
+# per-layer leaves, and the updater walk reads a run's stacked gradient
+# a slice a layer. The gradient-sharing cores (parallel/
+# gradient_sharing.py) still pack and unpack whole trees round their
+# exchange programs.
 
 RUN_PREFIX = "stacked::"
 

@@ -194,10 +194,11 @@ class TrainableNetwork:
 
     # ---------------------------------------------------------- train step
     def _packed_runs(self, params):
-        """Runs packed at the train-step boundary (nn/scan_stack.py):
-        the loss-path scan runs (the output layer never packs) filtered
-        to configs whose gradient-normalization / constraint semantics
-        survive a stacked leading axis."""
+        """Runs whose compute-dtype copy is stacked at the train-step
+        boundary (nn/scan_stack.py): the loss-path scan runs (the
+        output layer never packs) filtered to configs whose
+        gradient-normalization / constraint semantics survive a stacked
+        leading axis on the gradients."""
         runs = self._packed_runs_cache
         if runs is None:
             rwt = [(keys, self.layer_for_key(keys[0]))
@@ -206,24 +207,13 @@ class TrainableNetwork:
             self._packed_runs_cache = runs
         return runs
 
-    def _fused_state_runs(self, runs, params=None):
-        """Packed runs whose updater takes the fused-Adam kernel —
-        their m/v ride the step programs in the kernel's pre-flattened
-        [rows, 128] layout (kernels/fused_adam.py: the relayout that
-        used to happen around the kernel every micro-step now happens
-        once per program, at the pack/unpack boundary). Runs carrying
-        LoRA adapter nodes (tenancy/lora.py) stay on the per-leaf path
-        — the kernel's flat layout has no notion of a wrapped weight."""
-        from deeplearning4j_tpu.kernels import fused_adam as fa
-        from deeplearning4j_tpu.tenancy import lora
-        return [scan_stack.run_key(keys) for keys in runs
-                if fa.fused_adam_eligible(
-                    self.layer_for_key(keys[0]).updater or Sgd(1e-3))
-                and not (params is not None and any(
-                    lora.contains_lora(params.get(k, {})) for k in keys))]
-
     def _apply_updates(self, params, grads, upd_state, step):
-        from deeplearning4j_tpu.kernels import fused_adam as fa
+        """The updater walk, over per-layer trees all three: each leaf
+        of `params` / `upd_state` is read once and written once, where
+        it lies (a donated leaf comes back in its own buffer). A packed
+        run's gradients arrive as each layer's slice of the stacked
+        gradient (`_grad_update`), a read that fuses into the leaf's
+        update."""
         from deeplearning4j_tpu.tenancy import lora
         # a FROZEN attached adapter freezes the WHOLE base, not just
         # the wrapped matmul weights: biases, norms and embeddings hold
@@ -236,27 +226,13 @@ class TrainableNetwork:
             if type(w).__name__ == "LoRAWeight")
         new_params, new_upd = {}, {}
         for lk, lgrads in grads.items():
-            # a stacked run entry: the shared updater is elementwise,
-            # so one application covers the whole run (packable_runs
-            # guarantees no per-layer constraints on these layers)
             layer = self.layer_for_key(lk)
             updater = layer.updater or Sgd(1e-3)
             if frozen_base and not lora.contains_lora(params[lk]):
-                # frozen-base training, no adapter in this entry
-                # (packed runs included): nothing here may move
+                # frozen-base training, no adapter in this entry:
+                # nothing here may move
                 new_params[lk] = params[lk]
                 new_upd[lk] = upd_state[lk]
-                continue
-            if (scan_stack.is_run_key(lk)
-                    and fa.fused_adam_eligible(updater)):
-                # Pallas fast path: ONE kernel read-modify-writes the
-                # whole packed run's param/m/v stack in a single pass
-                # (bit-comparable to the per-leaf jnp path below;
-                # DL4J_PALLAS_KERNELS=0 opts out)
-                lp, lu = fa.adam_update_packed(
-                    updater, params[lk], lgrads, upd_state[lk], step)
-                new_params[lk] = lp
-                new_upd[lk] = lu
                 continue
             lp, lu = {}, {}
             for pk, g in lgrads.items():
@@ -280,35 +256,31 @@ class TrainableNetwork:
                 delta, new_s = updater.apply(g, upd_state[lk][pk], step)
                 lp[pk] = p - delta.astype(p.dtype)
                 lu[pk] = new_s
-            new_params[lk] = (lp if scan_stack.is_run_key(lk)
-                              else layer.apply_constraints(lp))
+            new_params[lk] = layer.apply_constraints(lp)
             new_upd[lk] = lu
         if self.conf.max_norm is not None:
             new_params = apply_max_norm_constraint(new_params, self.conf.max_norm)
         return new_params, new_upd
 
-    def _pack(self, params, upd, tbptt=False):
-        """Boundary packing (nn/scan_stack.py): homogeneous runs ride a
-        whole step program as ONE stacked entry — forward scan,
-        backward and updater all stay depth-independent — and fused-Adam
-        runs carry m/v in the kernel's pre-flattened [rows, 128] layout.
-        The TBPTT step threads carries through the unrolled path and
-        keeps the per-layer tree. Returns (params, upd, unpack)."""
+    def _pack(self, params, tbptt=False):
+        """What the loss differentiates: the COMPUTE-dtype copy of the
+        per-layer tree, with each packable run (nn/scan_stack.py)
+        stacked into ONE ``stacked::`` entry AFTER the cast, so the
+        pass that converts a leaf writes it straight into its slot and
+        forward scan and backward stay depth-independent. Nothing else
+        is packed: masters and updater state keep their per-layer
+        leaves through the whole program. The TBPTT step threads
+        carries through the unrolled path and stacks nothing. Returns
+        (compute tree, runs)."""
         runs = ([] if tbptt or not scan_stack.scan_enabled(self.conf)
                 else self._packed_runs(params))
-        if not runs:
-            return params, upd, lambda p, u: (p, u)
-        from deeplearning4j_tpu.kernels import fused_adam as fa
-        fused_runs = self._fused_state_runs(runs, params)
-        params, upd = fa.pack_run_trees(params, upd, runs, fused_runs)
-        return params, upd, lambda p, u: fa.unpack_run_trees(
-            p, u, runs, fused_runs)
+        return scan_stack.pack_tree(self.dtype.cast_params(params), runs), runs
 
     def _grad_update(self, params, upd, state, it, x, y, rng, fmask, lmask,
-                     carries):
-        """Loss, gradients and the updater walk of one step over
-        (possibly packed) trees. Returns (new_params, new_upd,
-        new_state, loss, new_carries, dv)."""
+                     carries, tbptt=False):
+        """Loss, gradients and the updater walk of one step over the
+        per-layer trees. Returns (new_params, new_upd, new_state, loss,
+        new_carries, dv)."""
         diag = self._diag
         want_acts = diag is not None and diag.config.activation_stats
 
@@ -317,12 +289,13 @@ class TrainableNetwork:
                                  train=True, carries=carries,
                                  act_stats=want_acts)
 
-        # differentiate wrt the COMPUTE-dtype tree (cast outside
-        # value_and_grad): under mixed_bf16 the gradients — and any
-        # data-parallel all-reduce of them — are bf16; the updater
-        # below upcasts onto the fp32 master params/state
-        (loss, aux), grads = jax.value_and_grad(
-            lf, has_aux=True)(self.dtype.cast_params(params))
+        # differentiate wrt the packed COMPUTE-dtype tree (cast and
+        # stacked outside value_and_grad): a run's gradients come out
+        # stacked, and under mixed_bf16 they — and any data-parallel
+        # all-reduce of them — are bf16; the updater walk upcasts each
+        # layer's slice onto its fp32 master params/state
+        compute, runs = self._pack(params, tbptt)
+        (loss, aux), grads = jax.value_and_grad(lf, has_aux=True)(compute)
         if want_acts:
             new_state, new_carries, acts = aux
         else:
@@ -330,6 +303,10 @@ class TrainableNetwork:
         grads = apply_gradient_normalization(
             grads, self.conf.gradient_normalization,
             self.conf.gradient_normalization_threshold)
+        # masters and updater state are never stacked or flattened: the
+        # updater walk (and the diagnostics) take each layer's slice of
+        # a run's stacked gradient
+        grads = scan_stack.unpack_tree(grads, runs)
         new_params, new_upd = self._apply_updates(params, grads, upd, it)
         # aux outputs only: the update/param math above is untouched,
         # so the trajectory stays bit-identical to diagnostics-off
@@ -343,14 +320,10 @@ class TrainableNetwork:
 
     def _make_train_step(self, tbptt: bool = False):
         def step_fn(params, upd_state, state, it, x, y, rng, fmask, lmask, carries=None):
-            params, upd_state, unpack = self._pack(params, upd_state, tbptt)
             if tbptt and carries is not None:
                 carries = jax.tree_util.tree_map(jax.lax.stop_gradient, carries)
-            new_params, new_upd, new_state, loss, new_carries, dv = \
-                self._grad_update(params, upd_state, state, it, x, y, rng,
-                                  fmask, lmask, carries)
-            new_params, new_upd = unpack(new_params, new_upd)
-            return new_params, new_upd, new_state, loss, new_carries, dv
+            return self._grad_update(params, upd_state, state, it, x, y, rng,
+                                     fmask, lmask, carries, tbptt)
 
         return jax.jit(step_fn, donate_argnums=_donate(0, 1, 2))
 
@@ -358,6 +331,9 @@ class TrainableNetwork:
         """Unjitted k-fused-steps function (`lax.scan` over the step
         body). Exposed separately so `ParallelTrainer` can re-jit the
         SAME body with mesh shardings — one copy of the fused numerics.
+        The scan carries the per-layer trees the program was handed;
+        each step stacks its own compute-dtype copy (`_pack`), exactly
+        as the per-step program does.
 
         The scan carry must keep a constant pytree structure, so state
         keys a train-mode forward emits that were absent from
@@ -377,14 +353,9 @@ class TrainableNetwork:
             return (new_params, new_upd, state, it + 1), (loss, dv)
 
         def multi(params, upd, state, it0, xs, ys, rngs):
-            # packed/unpacked once per PROGRAM, not per step: the
-            # per-micro-step optimizer-state relayout disappears from
-            # the scan body
-            params, upd, unpack = self._pack(params, upd)
             (params, upd, state, _), (losses, dvs) = jax.lax.scan(
                 one, (params, upd, state, jnp.asarray(it0, jnp.int32)),
                 (xs, ys, rngs))
-            params, upd = unpack(params, upd)
             return params, upd, state, losses, dvs
 
         return multi
@@ -398,7 +369,9 @@ class TrainableNetwork:
         steps — the reference has no analogue because its loop overhead
         is native (`MultiLayerNetwork.java:1156` fit loop); ours is the
         idiomatic XLA fix. Numerics are identical to k single steps:
-        same per-iteration RNG fold, same updater step counter.
+        same per-iteration RNG fold, same updater step counter, and the
+        same body: the per-layer trees are donated, carried by the scan
+        and returned in the buffers they arrived in.
         """
         return jax.jit(self._multi_step_fn(), donate_argnums=_donate(0, 1, 2))
 

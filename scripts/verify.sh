@@ -34,9 +34,7 @@
 #    docs/FAULT_TOLERANCE.md).
 # 7. Mixed-precision smoke: tiny-MLP bf16-vs-fp32 loss trajectory
 #    within the documented tolerance (docs/PRECISION.md), fp32 master
-#    params/updater state, bf16 gradients, and the fused-Adam Pallas
-#    kernel bit-comparable (inside jit) to the jnp updater path in
-#    interpret mode. The hlo_cost `precision` block (bf16 bytes <
+#    params/updater state. The hlo_cost `precision` block (bf16 bytes <
 #    fp32 bytes) is asserted in step [4/19] where the reports are
 #    already on disk.
 # 9. Serving smoke: `scripts/serve_loadtest.py --smoke` — >=64
@@ -344,7 +342,7 @@ echo "== [6/19] fault-drill smoke (kill@15 + auto-resume, bit parity) =="
 JAX_PLATFORMS=cpu timeout -k 10 300 python scripts/fault_drill.py --smoke
 drill_rc=$?
 
-echo "== [7/19] mixed-precision smoke (bf16 trajectory + fused-Adam parity) =="
+echo "== [7/19] mixed-precision smoke (bf16 trajectory, fp32 masters) =="
 JAX_PLATFORMS=cpu timeout -k 10 300 python - <<'PYEOF'
 import jax
 import jax.numpy as jnp
@@ -393,51 +391,8 @@ for leaf in jax.tree_util.tree_leaves(bf.params):
 for leaf in jax.tree_util.tree_leaves(bf.updater_state):
     assert leaf.dtype == jnp.float32
 
-# fused-Adam Pallas kernel vs the jnp path inside jit (interpret mode
-# on CPU — the DL4J_PALLAS_KERNELS fast path): equal to one FMA
-# rounding of the larger addend — which product of b*m + (1-b)*g
-# XLA:CPU contracts differs between the two programs
-# (tests/test_kernels.py::TestFusedAdamKernel states the contract)
-from deeplearning4j_tpu.kernels.fused_adam import adam_update_packed
-upd = Adam(0.01)
-r2 = np.random.default_rng(3)
-params = {"W": jnp.asarray(r2.standard_normal((4, 16, 16)), jnp.float32),
-          "b": jnp.asarray(r2.standard_normal((4, 16)), jnp.float32)}
-grads = {k: jnp.asarray(r2.standard_normal(v.shape), jnp.bfloat16)
-         for k, v in params.items()}
-state = {k: {"m": jnp.asarray(r2.standard_normal(v.shape),
-                              jnp.float32) * 0.1,
-             "v": jnp.abs(jnp.asarray(r2.standard_normal(v.shape),
-                                      jnp.float32)) * 0.01}
-         for k, v in params.items()}
-kp, ks = jax.jit(lambda p, g, s: adam_update_packed(
-    upd, p, g, s, 7, interpret=True))(params, grads, state)
-
-
-@jax.jit
-def ref(p, g, s):
-    out_p, out_s = {}, {}
-    for pk, gg in g.items():
-        gg = gg.astype(p[pk].dtype)
-        delta, s2 = upd.apply(gg, s[pk], 7)
-        out_p[pk] = p[pk] - delta.astype(p[pk].dtype)
-        out_s[pk] = s2
-    return out_p, out_s
-
-
-rp, rs = ref(params, grads, state)
-eps = float(np.finfo(np.float32).eps)
-for pk in params:
-    g32 = np.asarray(grads[pk], np.float32)
-    m0, v0 = np.asarray(state[pk]["m"]), np.asarray(state[pk]["v"])
-    np.testing.assert_allclose(np.asarray(kp[pk]), np.asarray(rp[pk]),
-                               rtol=2 * eps, atol=2 * eps)
-    dm = np.abs(np.asarray(ks[pk]["m"]) - np.asarray(rs[pk]["m"]))
-    dv = np.abs(np.asarray(ks[pk]["v"]) - np.asarray(rs[pk]["v"]))
-    assert (dm <= 2 * eps * (0.9 * np.abs(m0) + 0.1 * np.abs(g32))).all()
-    assert (dv <= 2 * eps * (0.999 * np.abs(v0) + 0.001 * g32 * g32)).all()
 print(f"mixed-precision smoke OK (init={init:.3f} fp32={d:.3f} "
-      f"bf16={b:.3f}, fused-Adam rounding-parity)")
+      f"bf16={b:.3f})")
 PYEOF
 mp_rc=$?
 

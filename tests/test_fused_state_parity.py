@@ -161,20 +161,16 @@ def _like(net, names, tree):
             for k, v in tree.items()}
 
 
-@pytest.mark.parametrize("fused_adam", [False, True])
 @pytest.mark.parametrize("spe", [1, 4])
-def test_list_and_graph_walk_one_trajectory(spe, fused_adam, monkeypatch):
-    monkeypatch.setenv("DL4J_PALLAS_KERNELS", "1" if fused_adam else "0")
+def test_list_and_graph_walk_one_trajectory(spe):
     x, y = _data(24)
     net = _list_chain()
     graph, names = _graph_chain()
     graph.params = _like(net, names, net.params)
     graph.updater_state = _like(net, names, net.updater_state)
-    # a packed run rides both steps, on the fused-Adam path when asked
+    # a packed run rides both steps
     assert net._packed_runs(net.params) == [["1", "2", "3"]]
     assert graph._packed_runs(graph.params) == [["d1", "d2", "d3"]]
-    for model, run in ((net, ["1", "2", "3"]), (graph, ["d1", "d2", "d3"])):
-        assert bool(model._fused_state_runs([run], model.params)) == fused_adam
     net.fit(x, y, epochs=1, batch_size=8, shuffle=False,
             steps_per_execution=spe)
     graph.fit(x, y, epochs=1, batch_size=8, steps_per_execution=spe)

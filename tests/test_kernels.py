@@ -370,218 +370,6 @@ class TestLayerNormKernel:
                                    rtol=1e-5, atol=1e-5)
 
 
-class TestFusedAdamKernel:
-    """One-kernel packed-run Adam (kernels/fused_adam.py) vs the
-    per-leaf jnp path, both inside jit (the containers always run the
-    updater inside the jitted step).
-
-    The contract is rounding-level, not bit-level, and the reason is
-    the compiler's, not the kernel's: `m = b1*m + (1-b1)*g` is two
-    products and a sum, and whether XLA:CPU contracts one product into
-    an FMA (one rounding instead of two) — and WHICH one — is decided
-    per program. Measured under jax 0.9 (PR 21): the per-leaf path
-    computes fma(b1, m, round(c*g)), the kernel body
-    fma(c, g, round(b1*m)), the eager op-by-op path neither;
-    `optimization_barrier` changes none of it. Each is within one
-    rounding of the LARGER addend of the exactly-rounded value, so two
-    of them differ by at most 2 eps x (|b1*m| + |c*g|) per element
-    (eps = 2^-23; where the addends cancel that is many ulps of the
-    small RESULT, which is why the bound is on the addends). v has the
-    same shape; the parameters inherit it through Adam's normalized
-    update, far below one ulp of an O(1) weight. Where the same program
-    runs both sides (flat vs per-leaf STATE layout) the equality stays
-    bit-exact."""
-
-    EPS = float(np.finfo(np.float32).eps)
-
-    def _assert_one_fma_apart(self, upd, got_p, got_s, ref_p, ref_s, p, g,
-                              s):
-        """Kernel vs per-leaf outputs for one leaf: m and v inside the
-        FMA-contraction bound, p inside 2 ulps."""
-        p, g = np.asarray(p), np.asarray(g, np.float32)
-        m, v = np.asarray(s["m"]), np.asarray(s["v"])
-        bound_m = 2 * self.EPS * (upd.beta1 * np.abs(m)
-                                  + (1 - upd.beta1) * np.abs(g))
-        bound_v = 2 * self.EPS * (upd.beta2 * np.abs(v)
-                                  + (1 - upd.beta2) * g * g)
-        dm = np.abs(np.asarray(got_s["m"]) - np.asarray(ref_s["m"]))
-        dv = np.abs(np.asarray(got_s["v"]) - np.asarray(ref_s["v"]))
-        assert (dm <= bound_m).all(), float((dm - bound_m).max())
-        assert (dv <= bound_v).all(), float((dv - bound_v).max())
-        np.testing.assert_allclose(np.asarray(got_p), np.asarray(ref_p),
-                                   rtol=2 * self.EPS, atol=2 * self.EPS)
-
-    def _run(self, seed=3, gdtype=jnp.float32):
-        rng = np.random.default_rng(seed)
-        params = {"W": jnp.asarray(rng.standard_normal((4, 16, 16)),
-                                   jnp.float32),
-                  "b": jnp.asarray(rng.standard_normal((4, 16)),
-                                   jnp.float32)}
-        grads = {k: jnp.asarray(rng.standard_normal(v.shape), gdtype)
-                 for k, v in params.items()}
-        state = {k: {"m": jnp.asarray(rng.standard_normal(v.shape),
-                                      jnp.float32) * 0.1,
-                     "v": jnp.abs(jnp.asarray(
-                         rng.standard_normal(v.shape), jnp.float32))
-                     * 0.01}
-                 for k, v in params.items()}
-        return params, grads, state
-
-    @pytest.mark.parametrize("gdtype", [jnp.float32, jnp.bfloat16])
-    def test_rounding_parity_vs_jnp_path(self, gdtype):
-        from deeplearning4j_tpu.common.updaters import Adam
-        from deeplearning4j_tpu.kernels.fused_adam import (
-            adam_update_packed)
-        upd = Adam(0.01)
-        params, grads, state = self._run(gdtype=gdtype)
-
-        @jax.jit
-        def kern(p, g, s):
-            return adam_update_packed(upd, p, g, s, 7, interpret=True)
-
-        @jax.jit
-        def ref(p, g, s):
-            out_p, out_s = {}, {}
-            for pk, gg in g.items():
-                gg = gg.astype(p[pk].dtype)
-                delta, s2 = upd.apply(gg, s[pk], 7)
-                out_p[pk] = p[pk] - delta.astype(p[pk].dtype)
-                out_s[pk] = s2
-            return out_p, out_s
-
-        kp, ks = kern(params, grads, state)
-        rp, rs = ref(params, grads, state)
-        for pk in params:
-            self._assert_one_fma_apart(upd, kp[pk], ks[pk], rp[pk],
-                                       rs[pk], params[pk], grads[pk],
-                                       state[pk])
-            assert kp[pk].dtype == jnp.float32    # fp32 master
-
-    def test_schedule_lr(self):
-        from deeplearning4j_tpu.common.schedules import ExponentialSchedule
-        from deeplearning4j_tpu.common.updaters import Adam
-        from deeplearning4j_tpu.kernels.fused_adam import (
-            adam_update_packed)
-        upd = Adam(ExponentialSchedule(0.01, 0.9))
-        params, grads, state = self._run()
-        kp, _ = jax.jit(lambda p, g, s: adam_update_packed(
-            upd, p, g, s, 5, interpret=True))(params, grads, state)
-        rp = {}
-        for pk, gg in grads.items():
-            delta, _ = upd.apply(gg, state[pk], 5)
-            rp[pk] = params[pk] - delta
-        for pk in params:
-            np.testing.assert_allclose(np.asarray(kp[pk]),
-                                       np.asarray(rp[pk]),
-                                       rtol=1e-6, atol=1e-7)
-
-    def test_eligibility(self, monkeypatch):
-        from deeplearning4j_tpu.common.updaters import Adam, Nadam, Sgd
-        from deeplearning4j_tpu.kernels.fused_adam import (
-            fused_adam_eligible)
-        monkeypatch.setenv("DL4J_PALLAS_KERNELS", "1")
-        assert fused_adam_eligible(Adam(0.01))
-        assert not fused_adam_eligible(Nadam(0.01))   # different math
-        assert not fused_adam_eligible(Sgd(0.01))
-        monkeypatch.setenv("DL4J_PALLAS_KERNELS", "0")
-        assert not fused_adam_eligible(Adam(0.01))
-
-    def test_flat_state_round_trip(self):
-        # pre-flattened m/v ([rows, 128] lane-aligned, kept between
-        # steps) must be an EXACT relayout of the per-leaf dicts
-        from deeplearning4j_tpu.kernels.fused_adam import (
-            FLAT_KEY,
-            flatten_opt_state,
-            is_flat_state,
-            unflatten_opt_state,
-        )
-        params, _, state = self._run()
-        flat = flatten_opt_state(params, state)
-        assert is_flat_state(flat) and not is_flat_state(state)
-        assert flat[FLAT_KEY]["m"].shape[1] == 128
-        # idempotent both ways
-        assert flatten_opt_state(params, flat) is flat
-        assert unflatten_opt_state(params, state) is state
-        back = unflatten_opt_state(params, flat)
-        for pk in state:
-            for s in ("m", "v"):
-                assert np.array_equal(np.asarray(back[pk][s]),
-                                      np.asarray(state[pk][s]))
-
-    def test_flat_state_multi_step_bit_parity(self):
-        # three consecutive updates carrying the FLAT form (what rides
-        # a fused program's scan carry) vs three per-leaf-state updates
-        # — params and (unflattened) m/v bit-identical, and the flat
-        # path's output stays flat (no per-step relayout)
-        from deeplearning4j_tpu.common.updaters import Adam
-        from deeplearning4j_tpu.kernels.fused_adam import (
-            adam_update_packed,
-            flatten_opt_state,
-            is_flat_state,
-            unflatten_opt_state,
-        )
-        upd = Adam(0.01)
-        params, grads, state = self._run(seed=11)
-
-        @jax.jit
-        def steps(p, s):
-            for t in range(3):
-                p, s = adam_update_packed(upd, p, grads, s, t,
-                                          interpret=True)
-            return p, s
-
-        fp, fs = steps(params, flatten_opt_state(params, state))
-        rp, rs = steps(params, state)
-        assert is_flat_state(fs) and not is_flat_state(rs)
-        fs = unflatten_opt_state(fp, fs)
-        for pk in params:
-            assert np.array_equal(np.asarray(fp[pk]), np.asarray(rp[pk]))
-            for s in ("m", "v"):
-                assert np.array_equal(np.asarray(fs[pk][s]),
-                                      np.asarray(rs[pk][s]))
-
-    def test_container_on_off_track_to_rounding(self, monkeypatch):
-        # whole train loop: fused-Adam kernel vs jnp path over a packed
-        # deep-MLP run, 4 optimizer steps. The per-step difference is
-        # the <= 2-ulp FMA-contraction choice above; through 4 steps of
-        # Adam's normalized update (lr 0.01) it stays inside 4 steps x
-        # (1 ulp of an O(1) weight + lr x a few eps) < 1e-6 absolute —
-        # measured 6e-8 (PR 21). fp32 eps is 1.2e-7.
-        from deeplearning4j_tpu.common.updaters import Adam
-        from deeplearning4j_tpu.nn.conf import (InputType,
-                                                NeuralNetConfiguration)
-        from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
-        from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-
-        def run(env):
-            monkeypatch.setenv("DL4J_PALLAS_KERNELS", env)
-            b = (NeuralNetConfiguration.builder().seed(7)
-                 .updater(Adam(0.01)).list())
-            for _ in range(4):
-                b = b.layer(DenseLayer(n_in=16, n_out=16,
-                                       activation="tanh"))
-            conf = (b.layer(OutputLayer(n_in=16, n_out=4,
-                                        activation="softmax",
-                                        loss="mcxent"))
-                    .set_input_type(InputType.feed_forward(16)).build())
-            net = MultiLayerNetwork(conf).init()
-            rng = np.random.default_rng(0)
-            x = rng.standard_normal((32, 16)).astype(np.float32)
-            y = np.eye(4, dtype=np.float32)[rng.integers(0, 4, 32)]
-            net.fit(x, y, epochs=2, batch_size=16, shuffle=False)
-            return net
-
-        on, off = run("1"), run("0")
-        for a, b in zip(jax.tree_util.tree_leaves(on.params),
-                        jax.tree_util.tree_leaves(off.params)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=0, atol=1e-6)
-        for a, b in zip(jax.tree_util.tree_leaves(on.updater_state),
-                        jax.tree_util.tree_leaves(off.updater_state)):
-            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
-                                       rtol=0, atol=1e-7)
-
-
 class TestFlashBf16:
     def test_flash_attention_bf16_inputs(self):
         # mixed_bf16 policy feeds the attention kernel bf16 q/k/v —

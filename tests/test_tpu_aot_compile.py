@@ -292,17 +292,15 @@ def _child(body, *, import_package=True):
 def test_train_step_kernels_are_mosaic_compiled_for_v5e():
     """Every Pallas kernel of the fused train step lowers to a
     `tpu_custom_call` under its stable name and the whole step passes
-    the TPU compilers. (PR 21: the fused-Adam kernel could not —
-    Mosaic has no lowering for `optimization_barrier` — and being ON by
-    default on a TPU it took every `fit()` of a packed Adam run down
-    with it.)"""
-    from deeplearning4j_tpu.kernels import fused_adam, layernorm
+    the TPU compilers. (PR 21: a kernel Mosaic refused — the Adam
+    kernel of the time, since deleted: PR 37 — being ON by default on
+    a TPU took every `fit()` of a packed Adam run down with it.)"""
+    from deeplearning4j_tpu.kernels import layernorm
     from deeplearning4j_tpu.kernels.flash_attention import KERNEL_NAMES
     proc, out = _child(_TRAIN)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert out["compiled"] is True
-    assert out["kernels"] == sorted(
-        KERNEL_NAMES + layernorm.KERNEL_NAMES + (fused_adam.KERNEL_NAME,))
+    assert out["kernels"] == sorted(KERNEL_NAMES + layernorm.KERNEL_NAMES)
 
 
 def test_decode_step_attends_over_the_pool_in_place_on_v5e():
