@@ -460,23 +460,6 @@ def count_jaxpr_eqns(jaxpr) -> int:
     return n
 
 
-_COMPILE_COLLECTOR = None
-
-
-def _compile_collector():
-    """One process-wide `JitCompileCollector` for every
-    `compile_program` call: jax.monitoring's listener list is
-    append-only, so a per-call collector would leak one dead listener
-    per compile probe (~10 per `--all` run). Readings are taken as
-    deltas around each compile."""
-    global _COMPILE_COLLECTOR
-    if _COMPILE_COLLECTOR is None:
-        from deeplearning4j_tpu.monitor import (JitCompileCollector,
-                                                MetricsRegistry)
-        _COMPILE_COLLECTOR = JitCompileCollector(MetricsRegistry())
-    return _COMPILE_COLLECTOR
-
-
 def compile_program(lowered) -> dict:
     """XLA-compile a lowered train step and record what the compile
     cost: wall seconds, backend-compile seconds + compile count via the
@@ -484,16 +467,15 @@ def compile_program(lowered) -> dict:
     memory analysis (peak temp = activation working set). CPU-safe —
     this is the seam the compile-time regression test and the
     `scripts/verify.sh` smoke build on."""
-    coll = _compile_collector().install()
-    s0, c0 = coll.compile_seconds(), coll.compile_count()
+    from deeplearning4j_tpu.monitor import JitCompileCollector, MetricsRegistry
+    coll = JitCompileCollector(MetricsRegistry()).install()
     out = {}
     t0 = time.perf_counter()
     try:
         compiled = lowered.compile()
         out["compile_seconds"] = round(time.perf_counter() - t0, 3)
-        out["xla_backend_compile_seconds"] = round(
-            coll.compile_seconds() - s0, 3)
-        out["xla_compiles"] = int(coll.compile_count() - c0)
+        out["xla_backend_compile_seconds"] = round(coll.compile_seconds(), 3)
+        out["xla_compiles"] = int(coll.compile_count())
         try:
             mem = compiled.memory_analysis()
             for attr in ("argument_size_in_bytes", "output_size_in_bytes",

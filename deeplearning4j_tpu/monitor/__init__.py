@@ -30,6 +30,7 @@ phase timing — exactly as `parallel/stats.py` documents.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import List, Optional
 
@@ -49,6 +50,7 @@ from deeplearning4j_tpu.monitor.tracer import (
 )
 from deeplearning4j_tpu.monitor.collectors import (
     DeviceMemoryCollector,
+    GcPauseCollector,
     JitCompileCollector,
     record_transfer as _record_transfer_impl,
 )
@@ -104,9 +106,10 @@ from deeplearning4j_tpu.monitor.alerts import (
 __all__ = [
     "MetricsRegistry", "Counter", "Gauge", "Histogram", "Timer",
     "Tracer", "Span", "MonitorListener",
-    "JitCompileCollector", "DeviceMemoryCollector",
+    "JitCompileCollector", "DeviceMemoryCollector", "GcPauseCollector",
     "enable", "disable", "is_enabled", "enabled", "registry", "tracer",
-    "span", "record_transfer", "bind_master_stats", "attach_master_stats",
+    "span", "compile_phase", "record_transfer", "bind_master_stats",
+    "attach_master_stats",
     "extra_listeners", "compile_collector", "memory_collector",
     "xprof", "ProfilerCapture", "roofline", "publish_cost_report",
     "diagnostics", "Diagnostics", "DiagnosticsConfig",
@@ -132,6 +135,15 @@ class _MonitorState:
         self.listener: Optional[MonitorListener] = None
         self.compile_collector: Optional[JitCompileCollector] = None
         self.memory_collector: Optional[DeviceMemoryCollector] = None
+        #: what `enable(jit_compile=)` asked for: the steady state a
+        #: compile phase restores
+        self.jit_compile = False
+        #: open compile phases (threads may warm servers side by side),
+        #: the collector to put back when the last one closes, and the
+        #: one `gc` hook they share
+        self.phases = 0
+        self.phase_restore: Optional[JitCompileCollector] = None
+        self.gc_collector: Optional[GcPauseCollector] = None
 
 
 _STATE = _MonitorState()
@@ -142,9 +154,11 @@ def enable(registry: Optional[MetricsRegistry] = None,
            jit_compile: bool = True,
            device_memory: bool = True) -> MetricsRegistry:
     """Turn the telemetry substrate on (idempotent). Returns the active
-    registry. `jit_compile` installs the compile-event collector;
-    `device_memory` creates the HBM gauge collector (a no-op on
-    backends without `memory_stats()`). Neither inserts device syncs."""
+    registry. `jit_compile` installs the compile-event collector (a
+    `compile_phase`, such as a server's warm-up, installs it for its own
+    length whatever this says); `device_memory` creates the HBM gauge
+    collector (a no-op on backends without `memory_stats()`). Neither
+    inserts device syncs."""
     with _STATE.lock:
         if registry is not None:
             _STATE.registry = registry
@@ -162,18 +176,17 @@ def enable(registry: Optional[MetricsRegistry] = None,
             "tracer_events_dropped_total",
             help="trace events evicted by the tracer ring buffer")
         _STATE.listener = MonitorListener(_STATE.registry)
-        # a collector pointed at a superseded registry must be torn down
-        # (jax's listener list is append-only: an orphaned active
-        # collector would keep feeding — and pinning — the old registry)
+        # the collector follows the active registry
         if (_STATE.compile_collector is not None
                 and _STATE.compile_collector.registry is not _STATE.registry):
             _STATE.compile_collector.uninstall()
             _STATE.compile_collector = None
+        _STATE.jit_compile = bool(jit_compile)
         if jit_compile:
             if _STATE.compile_collector is None:
                 _STATE.compile_collector = JitCompileCollector(_STATE.registry)
             _STATE.compile_collector.install()
-        elif _STATE.compile_collector is not None:
+        elif _STATE.compile_collector is not None and not _STATE.phases:
             _STATE.compile_collector.uninstall()
         if device_memory:
             _STATE.memory_collector = DeviceMemoryCollector(_STATE.registry)
@@ -235,6 +248,83 @@ def compile_collector() -> Optional[JitCompileCollector]:
 
 def memory_collector() -> Optional[DeviceMemoryCollector]:
     return _STATE.memory_collector
+
+
+def _open_phase():
+    with _STATE.lock:
+        coll = _STATE.compile_collector
+        if _STATE.phases == 0:
+            _STATE.phase_restore = coll
+            _STATE.gc_collector = GcPauseCollector(_STATE.registry).install()
+        if coll is None or coll.registry is not _STATE.registry:
+            coll = _STATE.compile_collector = JitCompileCollector(
+                _STATE.registry)
+        coll.install()
+        _STATE.phases += 1
+        return coll, _STATE.gc_collector
+
+
+def _close_phase():
+    with _STATE.lock:
+        _STATE.phases -= 1
+        if _STATE.phases:
+            return
+        _STATE.gc_collector.uninstall()
+        _STATE.gc_collector = None
+        coll = _STATE.compile_collector
+        if coll is not None and not (_STATE.enabled and _STATE.jit_compile):
+            coll.uninstall()
+            _STATE.compile_collector = _STATE.phase_restore
+        _STATE.phase_restore = None
+
+
+#: the compile stages a phase reports, by `jax_compile_seconds_total`'s
+#: stage label
+_PHASE_STAGES = (("trace", "jaxpr_trace_duration"),
+                 ("lower", "jaxpr_to_mlir_module_duration"),
+                 ("compile", "backend_compile_duration"))
+
+
+@contextlib.contextmanager
+def compile_phase(name: str, family: str, **args):
+    """A set-up phase that compiles (a server's warm-up grid), timed where
+    it happens. Disabled: the shared no-op span and nothing installed.
+    Enabled: the active registry's `JitCompileCollector` is installed for
+    the phase whatever `enable(jit_compile=)` said (and put back as it
+    was after), one `gc.callbacks` hook counts the collector's pauses, and
+    the phase is the span `name` (args: `programs_before`, the backend
+    compile events the registry counted before it, and `args`). At its
+    end it adds to `<family>_seconds_total` the span's seconds, to
+    `<family>_stage_seconds_total{stage}` what the collector counted in
+    it (`trace`, `lower`, `compile`: the backend's stage, a cache load
+    where the persistent cache hits; and `gc`, which may overlap the
+    others), and to `<family>_programs_total` its backend compile
+    events: one source for each number."""
+    if not _STATE.enabled:
+        yield NOOP_SPAN
+        return
+    coll, gcc = _open_phase()
+    reg = coll.registry
+    try:
+        programs0, stages0, gc0 = (coll.compile_count(),
+                                   coll.stage_seconds(), gcc.seconds())
+        with span(name, programs_before=int(programs0), **args) as sp:
+            yield sp
+        stages1 = coll.stage_seconds()
+        reg.counter(f"{family}_seconds_total",
+                    help=f"{name} span seconds").inc(sp.duration_s)
+        for stage, key in _PHASE_STAGES:
+            reg.counter(f"{family}_stage_seconds_total",
+                        help=f"{name} seconds by compile stage and gc",
+                        stage=stage).inc(
+                            stages1.get(key, 0.0) - stages0.get(key, 0.0))
+        reg.counter(f"{family}_stage_seconds_total",
+                    stage="gc").inc(gcc.seconds() - gc0)
+        reg.counter(f"{family}_programs_total",
+                    help=f"backend compile events in {name}").inc(
+                        coll.compile_count() - programs0)
+    finally:
+        _close_phase()
 
 
 def span(name: str, **args):

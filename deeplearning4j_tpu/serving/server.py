@@ -455,8 +455,11 @@ class GenerationServer(ParallelInference):
         XLA compile inside a live admission wave stalls every queued
         request behind ~seconds of tracing (measured as a p50==p99
         TTFT cliff on the CPU sandbox; stack sampling showed the
-        scheduler thread pinned in backend_compile)."""
-        from deeplearning4j_tpu.serving.engine import bucket_len
+        scheduler thread pinned in backend_compile).
+
+        With monitoring on, the grid is the compile phase `serve/warmup`
+        (`monitor.compile_phase`: the `serving_warmup_*` families);
+        off, each wave makes one no-op span and nothing is installed."""
         if self._running:
             raise RuntimeError("warmup() must run before start()")
         eng = self.engine
@@ -476,6 +479,29 @@ class GenerationServer(ParallelInference):
             b *= 2
         if buckets[-1] != top_bucket:
             buckets.append(top_bucket)     # budget-clamped odd bucket
+        with monitor.compile_phase("serve/warmup", "serving_warmup",
+                                   widths=widths, buckets=buckets):
+            self._warm_grid(widths, buckets, int(prompt_len), n_tokens)
+        # the warmup grid's grants/preemptions are not serving traffic:
+        # reset the engine totals so the registry deltas (_drain) and
+        # ledger reads count real requests only (prefix pins and their
+        # hit/fork counters predate traffic too)
+        eng.block_grants_total = 0
+        eng.evict_requeue_total = 0
+        eng.prefix_forks_total = 0
+        eng.prefix_hits_total = 0
+        eng.prefix_tokens_saved_total = 0
+        eng.spec_draft_dispatches_total = 0
+        eng.radix_hit_tokens_total = 0
+        eng.radix_evictions_total = 0
+        return self
+
+    def _warm_grid(self, widths, buckets, prompt_len: int, n_tokens: int):
+        """`warmup()`'s programs: the (width, bucket) waves, then the
+        speculative, fork and first-token programs; each wave is a
+        `serve/warmup/wave` span."""
+        from deeplearning4j_tpu.serving.engine import bucket_len
+        eng = self.engine
         # each (width, bucket) warms BOTH admit variants (all-greedy
         # and the sampling chain) — a mixed wave keys a different
         # program — and the first sampled wave also compiles the
@@ -520,25 +546,30 @@ class GenerationServer(ParallelInference):
                         if pw < 1:
                             continue
                     for sampled_head in (False, True):
-                        reqs = [dict(prompt_ids=np.zeros(pw, np.int32),
-                                     n_tokens=n_b)
-                                for _ in range(k)]
-                        if sampled_head:
-                            reqs[0].update(temperature=1.0,
-                                           rng=np.zeros(2, np.uint32))
-                        admitted = eng.admit_many(reqs)
-                        while eng.active.any():
-                            # speculate=False: the grid warms the
-                            # CHUNKED decode programs — the accept-rate
-                            # fallback path must be as cold-start-free
-                            # as the speculative one (warmed below)
-                            eng.step(speculate=False)
-                        eng.drain_preempted()  # warmup traffic isn't real
-                        for slot, _, done in admitted:
-                            if not done and eng.slots[slot] is not None:
-                                eng.evict(slot)
-                        if len(admitted) < k and short_wave is None:
-                            short_wave = (len(admitted), k)
+                        with monitor.span("serve/warmup/wave", width=k,
+                                          bucket=pl, sampled=sampled_head):
+                            reqs = [dict(prompt_ids=np.zeros(pw, np.int32),
+                                         n_tokens=n_b)
+                                    for _ in range(k)]
+                            if sampled_head:
+                                reqs[0].update(temperature=1.0,
+                                               rng=np.zeros(2, np.uint32))
+                            admitted = eng.admit_many(reqs)
+                            while eng.active.any():
+                                # speculate=False: the grid warms the
+                                # CHUNKED decode programs — the
+                                # accept-rate fallback path must be as
+                                # cold-start-free as the speculative one
+                                # (warmed below)
+                                eng.step(speculate=False)
+                            # warmup traffic isn't real
+                            eng.drain_preempted()
+                            for slot, _, done in admitted:
+                                if (not done
+                                        and eng.slots[slot] is not None):
+                                    eng.evict(slot)
+                            if len(admitted) < k and short_wave is None:
+                                short_wave = (len(admitted), k)
                 if short_wave is not None:
                     # pool too small for this width (at SOME bucket)
                     # even at warmup's minimal n_tokens — real waves of
@@ -628,19 +659,6 @@ class GenerationServer(ParallelInference):
                 if w >= S:
                     break
                 w *= 2
-        # the warmup grid's grants/preemptions are not serving traffic:
-        # reset the engine totals so the registry deltas (_drain) and
-        # ledger reads count real requests only (prefix pins and their
-        # hit/fork counters predate traffic too)
-        eng.block_grants_total = 0
-        eng.evict_requeue_total = 0
-        eng.prefix_forks_total = 0
-        eng.prefix_hits_total = 0
-        eng.prefix_tokens_saved_total = 0
-        eng.spec_draft_dispatches_total = 0
-        eng.radix_hit_tokens_total = 0
-        eng.radix_evictions_total = 0
-        return self
 
     # ------------------------------------------------------------- submit
     def generate_async(self, prompt_ids, n_tokens: int, *,
