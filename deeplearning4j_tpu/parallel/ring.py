@@ -187,11 +187,11 @@ def _ring_flash_bwd(axis_name, causal, res, g):
     def contrib_for(chunk_causal):
         def f(kb, vb):
             dq_c = _bwd_dq_chunk(q, kb, vb, g, lse, delta,
-                                 causal=chunk_causal, block_q=512,
-                                 block_k=1024, interpret=None)
+                                 causal=chunk_causal, block_q=None,
+                                 block_k=None, interpret=None)
             dk_c, dv_c = _bwd_dkv_chunk(q, kb, vb, g, lse, delta,
-                                        causal=chunk_causal, block_q=512,
-                                        block_k=1024, interpret=None)
+                                        causal=chunk_causal, block_q=None,
+                                        block_k=None, interpret=None)
             return (dq_c.astype(jnp.float32), dk_c.astype(jnp.float32),
                     dv_c.astype(jnp.float32))
         return f

@@ -1,13 +1,14 @@
 #!/usr/bin/env python3
 """Print a hash of the lowered StableHLO of a serving cell's decode step
-(`gpt2m_serve_chat`'s by default), so a PR that extends the decode kernel
-or the engine can show that the program this cell runs did not move with
-it.
+and of its prefill (`gpt2m_serve_chat`'s by default), so a PR that extends
+a kernel or the engine can show that the programs this cell runs did not
+move with it.
 
     JAX_PLATFORMS=cpu python3 scripts/decode_step_hlo.py [--cell CELL] [--repo DIR] [--dump DIR]
 
 The greedy and the sampling variant of `PagedDecodeEngine._decode_body`
-under the cell's own server arguments, at the configuration's published
+and the prefill of a two-prompt wave at the smallest bucket, under the
+cell's own server arguments, at the configuration's published
 widths with TWO layers of each kind it has (the `hash_layers` group of the
 configuration's own file: a layer is a layer, and the sandbox builds no
 full-size net), lowered from shapes for a
@@ -94,14 +95,9 @@ def main():
         net.params = jax.eval_shape(lambda k: model.to_program(
             ref.init_params(cfg, k), cfg), jax.random.PRNGKey(0))
         net.net_state, net.updater_state, net._initialized = {}, {}, True
-    eng = PagedDecodeEngine(net, **cell["server"])
-    args = shapes((eng._params, net.net_state, eng.pool.kv)
-                  + tuple(eng._decode_args()))
-    for name, greedy in (("decode_greedy", True), ("decode_sampling", False)):
-        text = re.sub(
-            r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', kernel,
-            jax.jit(eng._decode_body(greedy_only=greedy),
-                    donate_argnums=2).lower(*args).as_text())
+    def show(name, lowered):
+        text = re.sub(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22', kernel,
+                      lowered.as_text())
         print(name, hashlib.sha256(text.encode()).hexdigest(),
               f"{len(text):,} bytes", "kernels",
               sorted(set(re.findall(r'kernel_name = "([^"]+)"', text))),
@@ -110,6 +106,34 @@ def main():
             os.makedirs(a.dump, exist_ok=True)
             with open(os.path.join(a.dump, name + ".mlir"), "w") as f:
                 f.write(text)
+
+    eng = PagedDecodeEngine(net, **cell["server"])
+    args = shapes((eng._params, net.net_state, eng.pool.kv)
+                  + tuple(eng._decode_args()))
+    for name, greedy in (("decode_greedy", True), ("decode_sampling", False)):
+        show(name, jax.jit(eng._decode_body(greedy_only=greedy),
+                           donate_argnums=2).lower(*args))
+
+    # the admission wave's prefill, two prompts at the smallest bucket the
+    # cell's server pads to, through whichever path the engine takes
+    import jax.numpy as jnp
+    k, Pb = 2, max(128, cell["server"].get("min_prefill_bucket", 0))
+    ids = jax.ShapeDtypeStruct((k, Pb), jnp.int32, sharding=one)
+    last = jax.ShapeDtypeStruct((k,), jnp.int32, sharding=one)
+    weights = shapes((eng._params, net.net_state))
+    if eng._paged_prefill:
+        show("prefill", jax.jit(eng._prefill_paged_body()).lower(
+            *weights, ids, last))
+    else:
+        from deeplearning4j_tpu.nn.layers.recurrent import BaseRecurrentLayer
+        from deeplearning4j_tpu.zoo.transformer import get_prefill_bucketed
+        carries = {str(i): shapes(jax.eval_shape(
+            lambda layer=layer: layer.init_carry(
+                k, net.dtype.compute_dtype)))
+            for i, layer in enumerate(net.layers)
+            if isinstance(layer, BaseRecurrentLayer)}
+        show("prefill", get_prefill_bucketed(net).lower(
+            *weights, ids, carries, last))
 
 
 if __name__ == "__main__":

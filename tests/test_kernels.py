@@ -384,6 +384,102 @@ class TestFlashBf16:
                                    np.asarray(want, np.float32),
                                    rtol=2e-2, atol=2e-2)
 
+    @pytest.mark.parametrize("T, D, blocks, causal", [
+        (64, 16, (16, 16), True),      # skipped, interior and diagonal tiles
+        (64, 16, (16, 32), True),      # rectangular blocks: run square
+        (64, 8, (16, 16), True),       # 1/sqrt(8): scale on the fp32 scores
+        (100, 16, (32, 32), True),     # ragged: padded keys and queries
+        (300, 16, (None, None), True),  # the chooser's one tile of T
+        (64, 16, (32, 32), True),      # the diagonal tile whole
+        (128, 16, (64, 64), True),     # the diagonal tile in sub-blocks
+        (100, 16, (32, 32), False),    # padding mask on the edge tile only
+    ])
+    def test_bf16_products_against_fp32_reference(self, T, D, blocks,
+                                                   causal):
+        # bfloat16 operands into every product (P and dS rounded to it),
+        # fp32 statistics and accumulators: forward values and all three
+        # gradients against the float32 XLA attention, in bfloat16's band
+        ks = jax.random.split(jax.random.PRNGKey(T + D), 4)
+        q, k, v = (jax.random.normal(kk, (1, T, 2, D), jnp.bfloat16)
+                   for kk in ks[:3])
+        w = jax.random.normal(ks[3], (1, T, 2, D))
+        bq, bk = blocks
+
+        def loss_flash(q_, k_, v_):
+            o = flash_attention(q_, k_, v_, causal, bq, bk, True)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+
+        def loss_ref(q_, k_, v_):
+            o = _xla_attention(q_, k_, v_, causal)
+            return jnp.sum(o * w), o
+
+        f32 = tuple(a.astype(jnp.float32) for a in (q, k, v))
+        (_, o1), g1 = jax.value_and_grad(loss_flash, argnums=(0, 1, 2),
+                                         has_aux=True)(q, k, v)
+        (_, o2), g2 = jax.value_and_grad(loss_ref, argnums=(0, 1, 2),
+                                         has_aux=True)(*f32)
+        assert o1.dtype == jnp.bfloat16
+        for a, b in zip((o1,) + g1, (o2,) + g2):
+            a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+            scale = np.abs(b).max()
+            np.testing.assert_allclose(a, b, rtol=0, atol=2e-2 * scale)
+            assert np.abs(a - b).mean() < 3e-3 * scale
+
+    @pytest.mark.parametrize("T, asked, causal, tiles", [
+        (4096, (None, None), True, (1024, 1024)),  # square: static diagonal
+        (4096, (None, None), False, (512, 1024)),  # nothing to skip
+        (1024, (None, None), True, (1024, 1024)),  # the cell: one tile
+        (300, (None, None), True, (300, 300)),     # one tile of T
+        (300, (None, None), False, (300, 300)),
+        (4096, (512, 1024), True, (512, 512)),     # causal: the smaller
+        (4096, (512, 1024), False, (512, 1024)),   # as asked
+    ])
+    def test_tile_choice_follows_causality(self, T, asked, causal, tiles):
+        from deeplearning4j_tpu.kernels.flash_attention import _tiles
+        assert _tiles(T, T, *asked, causal) == tiles
+
+    def test_causal_needs_as_many_queries_as_keys(self):
+        # square causal tiles leave padded keys unmasked off the
+        # diagonal, which is exact only where every padded key lies
+        # after every real query
+        from deeplearning4j_tpu.kernels.flash_attention import _tiles
+        with pytest.raises(ValueError, match="as many queries as keys"):
+            _tiles(96, 80, 32, 32, True)
+        assert _tiles(96, 80, 32, 32, False) == (32, 32)
+        ks = jax.random.split(jax.random.PRNGKey(4), 3)
+        q = jax.random.normal(ks[0], (1, 48, 2, 8))
+        k, v = (jax.random.normal(kk, (1, 40, 2, 8)) for kk in ks[1:])
+        with pytest.raises(ValueError, match="as many queries as keys"):
+            flash_attention(q, k, v, True, 16, 16, True)
+
+    @pytest.mark.parametrize("b, split, sub", [
+        (1024, 4, 256), (512, 4, 128), (64, 4, 16), (32, 4, 32),
+        (600, 4, 600), (48, 4, 48), (1024, 2, 512), (32, 2, 16),
+        (1024, 1, 1024),
+    ])
+    def test_diagonal_tile_sub_blocks(self, b, split, sub):
+        # the diagonal tile is cut `split` ways a side where each piece
+        # is whole bfloat16 sublane tiles; otherwise it runs whole, masked
+        from deeplearning4j_tpu.kernels.flash_attention import _sub_block
+        assert _sub_block(b, split) == sub
+
+    @pytest.mark.parametrize("i, j, k_tile, q_tile", [
+        (0, 0, 0, 0), (0, 3, 0, 3), (1, 2, 1, 2), (3, 3, 3, 3),
+        (2, 0, 0, 2), (3, 1, 1, 3),
+    ])
+    def test_skipped_steps_repeat_the_resident_block(self, i, j, k_tile,
+                                                     q_tile):
+        # causal, 4 x 4 square tiles, grid step (i, j): a step the
+        # kernel skips maps its operand to a block a neighbouring real
+        # step holds, so it copies nothing (forward / dQ, query tile i:
+        # key steps past tile i repeat it; dK/dV, key tile i: query
+        # steps before tile i repeat it)
+        from deeplearning4j_tpu.kernels.flash_attention import (
+            _k_index_map, _q_index_map)
+        assert int(_k_index_map(True)(0, 0, i, j)[2]) == k_tile
+        assert int(_q_index_map(True)(0, 0, i, j)[2]) == q_tile
+        assert _k_index_map(False)(0, 0, i, j)[2] == j
+
 
 class TestPagedDecodeGroupedWindowed:
     """`dl4tpu_paged_decode` with fewer key heads than query heads and a

@@ -64,9 +64,29 @@ low = net._jit_multi_step.lower(
     jax.ShapeDtypeStruct((k, B, T), jnp.float32, sharding=S),
     jax.ShapeDtypeStruct((k, B, T, V), jnp.float32, sharding=S),
     jax.ShapeDtypeStruct((k,) + key.shape, key.dtype, sharding=S))
-names = sorted(set(re.findall(r'kernel_name = "([^"]+)"', low.as_text())))
+text = low.as_text()
+names = sorted(set(re.findall(r'kernel_name = "([^"]+)"', text)))
+# each kernel's products: (lhs, rhs, accumulator) element types of every
+# tpu.matmul in its serialized Mosaic module
+import base64
+from jax._src.interpreters import mlir
+from jax._src.lib.mlir import ir
+ctx = mlir.make_ir_context()
+ctx.allow_unregistered_dialects = True
+vec = r"vector<[\\dx]+x(\\w+)>"
+products = {}
+for body, name in re.findall(
+        r'\\\\22body\\\\22: \\\\22([A-Za-z0-9+/=]+)\\\\22.*?kernel_name = "([^"]+)"',
+        text):
+    with ctx:
+        asm = ir.Module.parse(base64.b64decode(body), ctx).operation.get_asm(
+            enable_debug_info=False)
+    products.setdefault(name, set()).update(
+        "/".join(m) for m in re.findall(
+            r"tpu\\.matmul.*: \\(" + ", ".join([vec] * 3) + r"\\)", asm))
 low.compile()
-print(json.dumps({"kernels": names, "compiled": True}))
+print(json.dumps({"kernels": names, "compiled": True,
+                  "products": {n: sorted(p) for n, p in products.items()}}))
 '''
 
 _PREFILL = '''
@@ -292,15 +312,20 @@ def _child(body, *, import_package=True):
 def test_train_step_kernels_are_mosaic_compiled_for_v5e():
     """Every Pallas kernel of the fused train step lowers to a
     `tpu_custom_call` under its stable name and the whole step passes
-    the TPU compilers. (PR 21: a kernel Mosaic refused — the Adam
-    kernel of the time, since deleted: PR 37 — being ON by default on
-    a TPU took every `fit()` of a packed Adam run down with it.)"""
+    the TPU compilers: a kernel Mosaic refuses, on by default on a TPU,
+    takes every `fit()` down with it, as a since-deleted Adam kernel
+    once did. The flash kernels' products take the compute dtype,
+    bfloat16, not an fp32 upcast of it."""
     from deeplearning4j_tpu.kernels import layernorm
     from deeplearning4j_tpu.kernels.flash_attention import KERNEL_NAMES
     proc, out = _child(_TRAIN)
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert out["compiled"] is True
     assert out["kernels"] == sorted(KERNEL_NAMES + layernorm.KERNEL_NAMES)
+    # the step is mixed: every product of the three flash kernels takes
+    # bfloat16 operands into the MXU and accumulates in float32
+    for name in KERNEL_NAMES:
+        assert out["products"][name] == ["bf16/bf16/f32"], name
 
 
 def test_decode_step_attends_over_the_pool_in_place_on_v5e():
