@@ -43,8 +43,15 @@ no block diagonal: the G rows fill the MXU's sublanes instead).  With
 alone: pages wholly before `starts[s]` are never read, and the block
 table is a RING, logical page b at column `b % max_blocks`, so a window
 layer's table holds `ceil(window / bl) + 1` columns whatever the slot's
-length.  Both are static variants of the one kernel: without them the
-program is what it was.
+length.  A key head narrower than the 128 lanes (`Dh` 64: LFM2) cannot
+be sliced out of its page: the query then arrives `[G, Hkv*Dh]` (row g
+holds query head `h*G + g` in key head h's lanes), is spread to a
+block-diagonal `[G*Hkv, Hkv*Dh]` (row `g*Hkv + h` keeps key head h's
+lanes), scored against the whole page in one product, and the diagonal
+blocks of `P @ V` summed over the key heads give `[G, Hkv*Dh]`: the
+block-diagonal trick of the plain variant, G rows at a time.  All three
+are static variants of the one kernel: without them the program is what
+it was.
 
 Interpret mode on the CPU (the parity tests), Mosaic on the chip;
 `MultiHeadAttention` selects it through `kernels_enabled()` and
@@ -94,7 +101,9 @@ def unsupported_reason(pool_shape, dtype, n_heads: int,
     dtype's sublane tile, `H*Dh` a multiple of the 128 lanes (`H` the
     key heads a page holds: `n_kv_heads`, by default `n_heads`).  With
     fewer key heads than query heads each key head's lanes are sliced
-    out of the page, so `Dh` itself must be whole lane tiles."""
+    out of the page where `Dh` is whole lane tiles; a narrower key head
+    takes the block-diagonal form, whose `[G, Hkv, Dh]` blocks need
+    `Hkv` a multiple of the 8 float32 sublanes."""
     if len(pool_shape) != 3:
         return f"pool rank {len(pool_shape)} is not [n_blocks, bl, H*Dh]"
     _, bl, hd = pool_shape
@@ -103,9 +112,10 @@ def unsupported_reason(pool_shape, dtype, n_heads: int,
         return f"n_heads {n_heads} is not a multiple of n_kv_heads {n_kv}"
     if hd % n_kv:
         return f"H*Dh {hd} is not a multiple of n_heads {n_kv}"
-    if n_kv != n_heads and (hd // n_kv) % 128:
+    if n_kv != n_heads and (hd // n_kv) % 128 and n_kv % 8:
         return (f"head_dim {hd // n_kv} of a grouped-query pool is not a "
-                f"multiple of the 128 lanes")
+                f"multiple of the 128 lanes, and its {n_kv} key heads are "
+                f"not a multiple of the 8 sublanes")
     if hd % 128:
         return f"H*Dh {hd} is not a multiple of the 128 lanes"
     if bl % _sublane_tile(dtype):
@@ -117,8 +127,9 @@ def unsupported_reason(pool_shape, dtype, n_heads: int,
 
 def _paged_decode_kernel(*refs, bl: int, pages: int, head_dim: int,
                          scale: float, grouped: bool, windowed: bool,
-                         ring: int):
-    """One slot: q_ref/o_ref [1, H*Dh] (grouped: [Hkv, G, Dh]);
+                         ring: int, narrow: bool = False):
+    """One slot: q_ref/o_ref [1, H*Dh] (grouped: [Hkv, G, Dh]; narrow
+    grouped: [G, Hkv*Dh]);
     k_hbm/v_hbm the whole pools [n_blocks, bl, H*Dh] in HBM; kbuf/vbuf
     [2, pages*bl, H*Dh].  `windowed`: a third scalar operand, each
     slot's first position, and a table of `ring` columns read as a
@@ -202,7 +213,22 @@ def _paged_decode_kernel(*refs, bl: int, pages: int, head_dim: int,
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     nt = (((1,), (1,)), ((), ()))
-    if grouped:
+    if narrow:
+        G = q_ref.shape[0]
+        n_kv = HD // head_dim
+        # [G, Hkv, HD]: block (g, h) keeps key head h's lanes
+        h_of = jax.lax.broadcasted_iota(jnp.int32, (G, n_kv, HD), 1)
+        col = jax.lax.broadcasted_iota(jnp.int32, (G, n_kv, HD), 2)
+        diag = jnp.logical_and(col >= h_of * head_dim,
+                               col < (h_of + 1) * head_dim)
+        q_bd = jnp.where(diag, q_ref[...].astype(jnp.float32)[:, None, :],
+                         0.0).reshape(G * n_kv, HD).astype(kbuf.dtype)
+
+        def attend(g, k, v):
+            sc = jax.lax.dot_general(
+                q_bd, k, nt, preferred_element_type=jnp.float32) * scale
+            online(masked(sc, g), v, m_scr, l_scr, acc_scr)   # [G*Hkv, T]
+    elif grouped:
         n_kv = q_ref.shape[0]
         q = q_ref[...].astype(kbuf.dtype)                 # [Hkv, G, Dh]
 
@@ -251,7 +277,11 @@ def _paged_decode_kernel(*refs, bl: int, pages: int, head_dim: int,
 
     l = l_scr[...]
     inv = 1.0 / jnp.where(l == 0.0, 1.0, l)               # length 0 -> 0
-    if grouped:
+    if narrow:
+        o = jnp.sum(jnp.where(diag, (acc_scr[...] * inv).reshape(
+            G, n_kv, HD), 0.0), axis=1)                   # [G, HD]
+        o_ref[...] = o.astype(o_ref.dtype)
+    elif grouped:
         o_ref[...] = (acc_scr[...] * inv).astype(o_ref.dtype)
     else:
         o = jnp.sum(jnp.where(diag, acc_scr[...] * inv, 0.0), axis=0,
@@ -293,12 +323,22 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
     bl, HD = k_pool.shape[1:]
     head_dim = HD // n_kv
     grouped, windowed = n_kv != n_heads, starts is not None
+    narrow = grouped and head_dim % 128 != 0
     max_blocks = block_table.shape[1]
     group = group_positions or (_GROUPED_GROUP_POSITIONS if grouped
                                 else _GROUP_POSITIONS)
     pages = max(1, min(group // bl, max_blocks))
     T = pages * bl
-    if grouped:
+    if narrow:
+        G = n_heads // n_kv
+        # row g: query head h*G + g in key head h's lanes
+        q_in = q.reshape(S, n_kv, G, head_dim).transpose(0, 2, 1, 3).reshape(
+            S, G, HD)
+        row = pl.BlockSpec((pl.squeezed, G, HD), lambda s, *_: (s, 0, 0))
+        state = [pltpu.VMEM((n_heads, 1), jnp.float32),      # running max
+                 pltpu.VMEM((n_heads, 1), jnp.float32),      # running denom
+                 pltpu.VMEM((n_heads, HD), jnp.float32)]     # accumulator
+    elif grouped:
         G = n_heads // n_kv
         q_in = q.reshape(S, n_kv, G, head_dim)
         row = pl.BlockSpec((pl.squeezed, n_kv, G, head_dim),
@@ -322,7 +362,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
                           head_dim=head_dim,
                           scale=1.0 / float(np.sqrt(head_dim)),
                           grouped=grouped, windowed=windowed,
-                          ring=max_blocks),
+                          ring=max_blocks, narrow=narrow),
         out_shape=jax.ShapeDtypeStruct(q_in.shape, q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(scalars),
@@ -340,4 +380,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
         interpret=interpret,
         name=KERNEL_NAME,
     )(*scalars, q_in, k_pool, v_pool)
+    if narrow:
+        out = out.reshape(S, G, n_kv, head_dim).transpose(0, 2, 1, 3)
     return out.reshape(q.shape)

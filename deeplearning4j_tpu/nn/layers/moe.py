@@ -129,17 +129,23 @@ class MixtureOfExperts(Layer):
 # absent experts would add is left out: on one chip the layer runs
 # without its exchange, and nothing here stands in for it.
 # --------------------------------------------------------------------------
-def sigmoid_topk_route(h, router, bias, top_k: int, scaling: float):
+def sigmoid_topk_route(h, router, bias, top_k: int, scaling: float,
+                       eps: float = 0.0):
     """h [N, D] -> (chosen [N, k] expert ids, gates [N, k] float32).
     Scores are `sigmoid(h router)` in float32; the `top_k` largest of
     `score + bias` are chosen (the bias chooses and does not weigh:
     DeepSeek-V3's auxiliary-loss-free balancing); the gates are the
-    chosen scores normalised over the chosen and scaled."""
+    chosen scores over their sum (plus `eps` where a family's code adds
+    one to the sum) and scaled."""
     logits = jnp.matmul(h, router, preferred_element_type=jnp.float32)
     score = jax.nn.sigmoid(logits)
     _, chosen = jax.lax.top_k(score + bias.astype(jnp.float32), top_k)
     picked = jnp.take_along_axis(score, chosen, axis=-1)
-    gates = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
+    if eps:
+        gates = scaling * picked / (jnp.sum(picked, axis=-1, keepdims=True)
+                                    + eps)
+    else:
+        gates = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
     return chosen.astype(jnp.int32), gates
 
 
@@ -187,12 +193,16 @@ def held_experts_swiglu(h, chosen, gates, w_gate, w_up, w_down, *,
 
 
 def expert_load_stats(sizes):
-    """(rows, fullest over mean) of one dispatch's held experts, as
-    float32 scalars; the ratio reads 0 where no row was routed here."""
+    """(rows, fullest over mean, touched share) of one dispatch's held
+    experts, as float32 scalars: the ratio reads 0 where no row was
+    routed here; the share is 100 x the held experts given at least one
+    row, which is the share of the held expert weights the dispatch
+    reads."""
     rows = jnp.sum(sizes).astype(jnp.float32)
     mean = rows / sizes.shape[0]
+    touched = 100.0 * jnp.mean((sizes > 0).astype(jnp.float32))
     return rows, jnp.where(rows > 0, jnp.max(sizes) / jnp.maximum(mean, 1e-9),
-                           0.0)
+                           0.0), touched
 
 
 def record_load(stats, sizes):
@@ -201,10 +211,11 @@ def record_load(stats, sizes):
     reads the means over the layers back with the tokens."""
     if stats is None:
         return
-    rows, ratio = expert_load_stats(sizes)
+    rows, ratio, touched = expert_load_stats(sizes)
     stats["moe_rows"] = stats.get("moe_rows", 0.0) + rows
     stats["moe_load_max_over_mean"] = stats.get(
         "moe_load_max_over_mean", 0.0) + ratio
+    stats["moe_touched_pct"] = stats.get("moe_touched_pct", 0.0) + touched
     stats["moe_layers"] = stats.get("moe_layers", 0) + 1
 
 

@@ -72,6 +72,22 @@ def rotate_interleaved(x, positions, theta: float):
                      -1).reshape(x.shape).astype(x.dtype)
 
 
+def rotate_half(x, positions, theta: float):
+    """x [..., H, Dh] at `positions` (x's axes before the head axis):
+    columns i and i + Dh/2 turned by `position * theta^(-2i/Dh)`, each
+    back in its own place (the rotate-half rotation of GPT-NeoX and its
+    descendants)."""
+    dh = x.shape[-1]
+    f = jnp.asarray(theta ** (-np.arange(0, dh, 2, dtype=np.float64) / dh),
+                    jnp.float32)
+    ang = positions.astype(jnp.float32)[..., None, None] * f
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    a, b = xf[..., :dh // 2], xf[..., dh // 2:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           -1).astype(x.dtype)
+
+
 def keep_mask(q_pos, k_pos, window=None):
     """Which keys a query sees: causal, and inside the window."""
     keep = (k_pos >= 0) & (k_pos <= q_pos)

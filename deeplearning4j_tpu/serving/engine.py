@@ -98,13 +98,15 @@ def bucket_len(n: int, cap: int) -> int:
 
 def _moe_means(stats: dict):
     """What the routed expert layers of one traced forward reported
-    (`stats`, filled by `paged_step` / `forward_prefill`) as the two
-    means over those layers: (rows routed to held experts, fullest held
-    expert over the mean); () for a net with no such layer."""
+    (`stats`, filled by `paged_step` / `state_step` / `forward_prefill`)
+    as the three means over those layers: (rows routed to held experts,
+    fullest held expert over the mean, share of held experts touched);
+    () for a net with no such layer."""
     n = stats.get("moe_layers", 0)
     if not n:
         return ()
-    return (stats["moe_rows"] / n, stats["moe_load_max_over_mean"] / n)
+    return (stats["moe_rows"] / n, stats["moe_load_max_over_mean"] / n,
+            stats["moe_touched_pct"] / n)
 
 
 class Slot:
@@ -514,7 +516,7 @@ class PagedDecodeEngine:
         # each a mean over those layers; None where the net has none.
         # Read back with the tokens: no transfer of their own
         self.positions_read = 0
-        self.moe_stats: Optional[Tuple[float, float]] = None
+        self.moe_stats: Optional[Tuple[float, float, float]] = None
         # of the same dispatch, for a two-pool net: 100 x the positions
         # the window layers hold for the decoding slots over the
         # positions those slots have reached (None: no window layer)
@@ -854,7 +856,8 @@ class PagedDecodeEngine:
                     h, _ = layer.forward_at_positions(lp, ls, h, pos)
                 elif kind == "state":
                     j = entry[2]
-                    h, kv[j] = layer.state_step(lp, h, kv[j], live)
+                    h, kv[j] = layer.state_step(lp, h, kv[j], live,
+                                                stats=stats)
                 else:
                     j = entry[2]
                     h, kv[j] = layer.paged_step(
@@ -1023,7 +1026,7 @@ class PagedDecodeEngine:
 
     def _take_moe(self, moe):
         """Device scalars from a program's routed expert layers -> the
-        host pair `moe_stats` (the mean over a fused chunk's steps), at
+        host triple `moe_stats` (the mean over a fused chunk's steps), at
         a point where the caller has already blocked on that program's
         tokens."""
         self.moe_stats = (tuple(float(np.mean(np.asarray(m))) for m in moe)

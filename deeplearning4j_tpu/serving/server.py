@@ -966,6 +966,13 @@ class GenerationServer(ParallelInference):
                 "expert's, a dispatch, mean over its routed expert "
                 "layers",
                 buckets=(1, 1.25, 1.5, 2, 3, 4, 8, 16, 32), **lbl),
+            "moe_touched": reg.histogram(
+                "serving_moe_touched_pct",
+                "100 x held experts given at least one row / held "
+                "experts, a dispatch, mean over its routed expert "
+                "layers: the share of the held expert weights it reads",
+                buckets=(5, 10, 20, 30, 40, 50, 60, 70, 80, 90, 100),
+                **lbl),
             "positions_read": reg.counter(
                 "serving_latent_positions_read",
                 "cache positions the decode dispatches' attention read, "
@@ -1313,6 +1320,7 @@ class GenerationServer(ParallelInference):
         if eng.moe_stats is not None:
             m["moe_rows"].observe(eng.moe_stats[0])
             m["moe_load"].observe(eng.moe_stats[1])
+            m["moe_touched"].observe(eng.moe_stats[2])
         if decode and eng.positions_read:
             m["positions_read"].inc(eng.positions_read)
         if decode and eng.window_held_pct is not None:

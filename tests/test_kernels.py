@@ -544,6 +544,9 @@ class TestPagedDecodeGroupedWindowed:
         (2, 2, 64, 20, jnp.float32, 2e-5),       # as many key heads: the
         (2, 2, 64, None, jnp.float32, 2e-5),     #   block-diagonal variant
         (32, 2, 128, 20, jnp.bfloat16, 3e-2),
+        (16, 8, 16, None, jnp.float32, 2e-5),    # key heads narrower than
+        (16, 8, 16, 20, jnp.float32, 2e-5),      #   the lanes: blocks of
+        (32, 8, 64, None, jnp.bfloat16, 3e-2),   #   G rows (LFM2's shape)
     ])
     def test_matches_a_plain_softmax(self, n_heads, n_kv, dh, window,
                                      dtype, tol):
@@ -589,6 +592,9 @@ class TestPagedDecodeGroupedWindowed:
             (40, 8, 128), jnp.float32, 4, 2)         # head_dim 64
         assert "multiple of n_kv_heads" in unsupported_reason(
             (40, 8, 384), jnp.float32, 4, 3)
+        # 8 key heads of 64: the block-diagonal form takes them
+        assert unsupported_reason((5633, 64, 512), jnp.bfloat16, 32, 8) \
+            is None
 
 
 class TestPagedDecodeKernel:

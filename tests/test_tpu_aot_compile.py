@@ -291,6 +291,51 @@ print(json.dumps({"kernels": names, "name": KERNEL_NAME,
 '''
 
 
+_LFM2_LAYERS = '''
+# LFM2-8B-A1B's layers at their published widths (2,048 columns): an
+# attention layer's paged step over 128 slots' bf16 pages of 64 positions,
+# 32 query heads over 8 key heads of 64 (narrower than the lanes), QK
+# norms and rotation; a short-convolution layer's prefill of a 4 x 2,048
+# wave and its decode step over 128 slots' two rows (narrow experts, all
+# held: the mixers are what is tried)
+from deeplearning4j_tpu.kernels.paged_attention import KERNEL_NAME
+from deeplearning4j_tpu.nn.layers.statespace import HybridStateSpaceBlock
+bf, i32 = jnp.bfloat16, jnp.int32
+shape = lambda s, d: jax.ShapeDtypeStruct(s, d, sharding=S)
+kw = dict(n_in=2048, ffn_hidden=64, n_routed=32, experts_per_token=4,
+          route_eps=1e-6, eps=1e-5, d_conv=3)
+def weights(blk):
+    return jax.tree_util.tree_map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(lambda: blk.init_params(jax.random.PRNGKey(0), bf)))
+att = HybridStateSpaceBlock(mixer="attention", n_heads=32, n_kv_heads=8,
+                            head_dim=64, qk_norm=True, rope_theta=1e6, **kw)
+pools = (shape((5633, 64, 512), bf),) * 2
+step = jax.jit(lambda p, x, pools, bt, pos, live: att.paged_step(
+    p, x, pools, bt, pos, live), donate_argnums=2)
+hlo = step.lower(weights(att), shape((128, 1, 2048), bf), pools,
+                 shape((128, 44), i32), shape((128,), i32),
+                 shape((128,), jnp.bool_)).compile().as_text()
+conv = HybridStateSpaceBlock(mixer="conv", **kw)
+cp = weights(conv)
+jax.jit(lambda p, x, n: conv.forward_prefill(p, x, n)).lower(
+    cp, shape((4, 2048, 2048), bf), shape((4,), i32)).compile()
+state = tuple(shape(a.shape, a.dtype) for a in jax.eval_shape(
+    lambda: conv.slot_state_arrays(128, bf)))
+chlo = jax.jit(lambda p, x, st, live: conv.state_step(p, x, st, live),
+               donate_argnums=2).lower(
+    cp, shape((128, 1, 2048), bf), state,
+    shape((128,), jnp.bool_)).compile().as_text()
+print(json.dumps({"name": KERNEL_NAME, "in_place": att.paged_in_place(pools),
+                  "step_has_kernel": KERNEL_NAME in hlo,
+                  "pool_copies": len(re.findall(
+                      r"= bf16\\[5633,64,512\\]\\S* copy\\(", hlo)),
+                  "state": [list(a.shape) for a in state],
+                  "state_copies": len(re.findall(
+                      r"= bf16\\[2,128,2048\\]\\S* copy\\(", chlo))}))
+'''
+
+
 def _child(body, *, import_package=True):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("LIBTPU_INIT_ARGS", None)
@@ -397,6 +442,18 @@ def test_grouped_windowed_decode_compiles_at_published_widths_on_v5e():
     assert out["kernels"] == [out["name"]] == ["dl4tpu_paged_decode"]
     assert out["in_place"] is True and out["step_has_kernel"] is True
     assert out["pool_copies"] == 0
+
+
+def test_lfm2_layers_compile_at_published_widths_on_v5e():
+    """An LFM2 attention layer's paged step takes `dl4tpu_paged_decode`
+    for key heads of 64 lanes (the block-diagonal form) and copies no
+    pool; a short-convolution layer's prefill and decode step compile,
+    and the step keeps its 128 slots' two rows in place."""
+    proc, out = _child(_LFM2_LAYERS)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["in_place"] is True and out["step_has_kernel"] is True
+    assert out["pool_copies"] == 0
+    assert out["state"] == [[2, 128, 2048]] and out["state_copies"] == 0
 
 
 def test_selective_scan_compiles_at_published_widths_on_v5e():
