@@ -1,15 +1,17 @@
 """Pallas TPU kernels — custom fast paths for ops XLA doesn't fuse
 optimally (the deeplearning4j-cuda role: hand-tuned kernels behind the
-same layer API, SURVEY §2.2). Five of them: flash attention (forward
+same layer API, SURVEY §2.2). Six of them: flash attention (forward
 and backward, `flash_attention.py`), fused LayerNorm (`layernorm.py`),
 the serving decode step's length-bounded paged attention
 (`paged_attention.py`:
 `dl4tpu_paged_decode`, which reads the K/V pages a slot holds in place
 instead of gathering every slot's whole block table;
 `mla_paged_attention.py`: `dl4tpu_mla_paged_decode`, the same over a
-latent pool in the absorbed form) and a state-space layer's prefill
+latent pool in the absorbed form), a state-space layer's prefill
 recurrence (`selective_scan.py`: `dl4tpu_selective_scan`, the state in
-VMEM across time).
+VMEM across time) and the held experts' grouped SwiGLU over a few rows
+(`grouped_swiglu.py`: `dl4tpu_grouped_swiglu`, each touched expert's
+weights read once).
 
 Kernel gating (`kernels_enabled`): compiled kernels ride the TPU
 backend by default; on other backends the (slow, python-level)

@@ -380,11 +380,10 @@ class LatentAttentionBlock(BaseRecurrentLayer):
         chosen, gates = moe.sigmoid_topk_route(
             flat, params["router"], params["router_bias"],
             self.experts_per_token, self.routed_scaling)
-        y, sizes = moe.held_experts_swiglu(
+        y = moe.held_experts_swiglu(
             flat, chosen, gates, params["e_gate"], params["e_up"],
             params["e_down"], first=self.held_first,
-            valid=None if valid is None else valid.reshape(-1))
-        moe.record_load(stats, sizes)
+            valid=None if valid is None else valid.reshape(-1), stats=stats)
         shared = swiglu(h, params["s_gate"], params["s_up"], params["s_down"])
         return x + y.reshape(B, T, D) + shared
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from deeplearning4j_tpu.common.weights import init_weights
+from deeplearning4j_tpu.kernels import grouped_swiglu, kernels_enabled
 from deeplearning4j_tpu.nn.conf.inputs import InputType, InputTypeRecurrent
 from deeplearning4j_tpu.nn.layers.base import Layer, register_layer
 
@@ -125,8 +126,8 @@ class MixtureOfExperts(Layer):
 # published width), then computes the tokens routed to the experts it
 # holds.  The functions below are that chip's part: no capacity, no
 # dropped token, rows sorted by expert and multiplied in groups
-# (`jax.lax.ragged_dot`), never every expert on every token.  What the
-# absent experts would add is left out: on one chip the layer runs
+# (`kernels/grouped_swiglu.py`), never every expert on every token.  What
+# the absent experts would add is left out: on one chip the layer runs
 # without its exchange, and nothing here stands in for it.
 # --------------------------------------------------------------------------
 def sigmoid_topk_route(h, router, bias, top_k: int, scaling: float,
@@ -168,19 +169,25 @@ def held_expert_groups(chosen, valid, first: int, count: int):
 
 
 def held_experts_swiglu(h, chosen, gates, w_gate, w_up, w_down, *,
-                        first: int, valid=None):
+                        first: int, valid=None, stats=None):
     """The held experts' part of `sum_e g_e SwiGLU_e(h)` for h [N, D]:
     w_gate, w_up [E, D, F], w_down [E, F, D] are the experts
-    `first .. first+E-1` of the layer.  -> (y [N, D] in h.dtype, sizes
-    [E]: rows each held expert multiplied)."""
+    `first .. first+E-1` of the layer.  -> y [N, D] in h.dtype; `stats`
+    (a dict, or None) gets the layer's load added (`record_load`).
+
+    The grouped products run in `dl4tpu_grouped_swiglu`, which reads each
+    touched expert's weights once and an untouched one's not at all,
+    where the `N x k` rows fit it (every decode dispatch) and kernels
+    run; XLA's `ragged_dot` otherwise (a long prefill wave)."""
     N, k = chosen.shape
     E = w_gate.shape[0]
     order, sizes, held = held_expert_groups(chosen, valid, first, E)
     rows = h[order // k]                                     # [N*k, D]
-    a = jax.lax.ragged_dot(rows, w_gate, sizes)
-    b = jax.lax.ragged_dot(rows, w_up, sizes)
-    y = jax.lax.ragged_dot((jax.nn.silu(a) * b).astype(h.dtype), w_down,
-                           sizes)                            # [N*k, D]
+    kernel = N * k <= grouped_swiglu.MAX_ROWS and kernels_enabled()
+    y = (grouped_swiglu.grouped_swiglu if kernel
+         else grouped_swiglu.grouped_swiglu_xla)(rows, sizes, w_gate, w_up,
+                                                 w_down)     # [N*k, D]
+    record_load(stats, sizes, kernel)
     # back to (token, chosen) order, then each token's held experts are
     # weighed and summed in float32. Pairs of no group are selected out,
     # not multiplied by nought: their rows are whatever the grouped
@@ -189,7 +196,7 @@ def held_experts_swiglu(h, chosen, gates, w_gate, w_up, w_down, *,
     weight = jnp.where(held, gates, 0.0)[..., None]
     out = jnp.sum(jnp.where(weight != 0, y.astype(jnp.float32) * weight,
                             0.0), axis=1)
-    return out.astype(h.dtype), sizes
+    return out.astype(h.dtype)
 
 
 def expert_load_stats(sizes):
@@ -205,10 +212,12 @@ def expert_load_stats(sizes):
                            0.0), touched
 
 
-def record_load(stats, sizes):
+def record_load(stats, sizes, kernel: bool):
     """Add one routed expert layer's `expert_load_stats` to `stats` (a
-    dict a traced forward hands down, or None): the serving engine
-    reads the means over the layers back with the tokens."""
+    dict a traced forward hands down, or None), and its rows again under
+    `moe_kernel_rows` where `dl4tpu_grouped_swiglu` multiplied them: the
+    serving engine reads the means over the layers back with the
+    tokens."""
     if stats is None:
         return
     rows, ratio, touched = expert_load_stats(sizes)
@@ -216,6 +225,8 @@ def record_load(stats, sizes):
     stats["moe_load_max_over_mean"] = stats.get(
         "moe_load_max_over_mean", 0.0) + ratio
     stats["moe_touched_pct"] = stats.get("moe_touched_pct", 0.0) + touched
+    stats["moe_kernel_rows"] = stats.get("moe_kernel_rows", 0.0) + (
+        rows if kernel else 0.0)
     stats["moe_layers"] = stats.get("moe_layers", 0) + 1
 
 

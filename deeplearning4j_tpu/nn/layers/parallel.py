@@ -418,11 +418,10 @@ class ParallelAttentionMoEBlock(BaseRecurrentLayer):
         chosen, gates = moe.sigmoid_topk_route(
             flat, params["router"], jnp.zeros((self.n_routed,), jnp.float32),
             self.experts_per_token, 1.0)
-        y, sizes = moe.held_experts_swiglu(
+        y = moe.held_experts_swiglu(
             flat, chosen, gates, params["e_gate"], params["e_up"],
             params["e_down"], first=self.held_first,
-            valid=None if valid is None else valid.reshape(-1))
-        moe.record_load(stats, sizes)
+            valid=None if valid is None else valid.reshape(-1), stats=stats)
         shared = moe.shared_experts_mean(h, params["s_gate"], params["s_up"],
                                          params["s_down"], self.n_shared)
         return y.reshape(B, T, D) + shared

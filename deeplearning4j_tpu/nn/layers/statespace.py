@@ -363,11 +363,10 @@ class HybridStateSpaceBlock(BaseRecurrentLayer):
         chosen, gates = moe.sigmoid_topk_route(
             flat, params["router"], params["expert_bias"],
             self.experts_per_token, self.routed_scaling, eps=self.route_eps)
-        y, sizes = moe.held_experts_swiglu(
+        y = moe.held_experts_swiglu(
             flat, chosen, gates, params["e_gate"], params["e_up"],
             params["e_down"], first=0,
-            valid=None if valid is None else valid.reshape(-1))
-        moe.record_load(stats, sizes)
+            valid=None if valid is None else valid.reshape(-1), stats=stats)
         return x + y.reshape(B, T, D)
 
     # --------------------------------------------------- attention mixer

@@ -100,13 +100,17 @@ def _moe_means(stats: dict):
     """What the routed expert layers of one traced forward reported
     (`stats`, filled by `paged_step` / `state_step` / `forward_prefill`)
     as the three means over those layers: (rows routed to held experts,
-    fullest held expert over the mean, share of held experts touched);
-    () for a net with no such layer."""
+    fullest held expert over the mean, share of held experts touched),
+    then 100 x the rows `dl4tpu_grouped_swiglu` multiplied over all the
+    rows (0 where none was routed); () for a net with no such layer."""
     n = stats.get("moe_layers", 0)
     if not n:
         return ()
-    return (stats["moe_rows"] / n, stats["moe_load_max_over_mean"] / n,
-            stats["moe_touched_pct"] / n)
+    rows = stats["moe_rows"]
+    return (rows / n, stats["moe_load_max_over_mean"] / n,
+            stats["moe_touched_pct"] / n,
+            jnp.where(rows > 0, 100.0 * stats["moe_kernel_rows"]
+                      / jnp.maximum(rows, 1.0), 0.0))
 
 
 class Slot:
@@ -516,7 +520,7 @@ class PagedDecodeEngine:
         # each a mean over those layers; None where the net has none.
         # Read back with the tokens: no transfer of their own
         self.positions_read = 0
-        self.moe_stats: Optional[Tuple[float, float, float]] = None
+        self.moe_stats: Optional[Tuple[float, ...]] = None
         # of the same dispatch, for a two-pool net: 100 x the positions
         # the window layers hold for the decoding slots over the
         # positions those slots have reached (None: no window layer)
@@ -1026,7 +1030,7 @@ class PagedDecodeEngine:
 
     def _take_moe(self, moe):
         """Device scalars from a program's routed expert layers -> the
-        host triple `moe_stats` (the mean over a fused chunk's steps), at
+        host tuple `moe_stats` (the mean over a fused chunk's steps), at
         a point where the caller has already blocked on that program's
         tokens."""
         self.moe_stats = (tuple(float(np.mean(np.asarray(m))) for m in moe)

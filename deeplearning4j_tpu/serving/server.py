@@ -973,6 +973,13 @@ class GenerationServer(ParallelInference):
                 "layers: the share of the held expert weights it reads",
                 buckets=(5, 10, 20, 30, 40, 50, 60, 70, 80, 90, 100),
                 **lbl),
+            "moe_kernel": reg.histogram(
+                "serving_moe_kernel_pct",
+                "100 x the routed rows the grouped SwiGLU kernel "
+                "multiplied / all routed rows, a dispatch: how often the "
+                "held experts' products ran in dl4tpu_grouped_swiglu "
+                "and not XLA's ragged_dot",
+                buckets=(0, 50, 100), **lbl),
             "positions_read": reg.counter(
                 "serving_latent_positions_read",
                 "cache positions the decode dispatches' attention read, "
@@ -1321,6 +1328,7 @@ class GenerationServer(ParallelInference):
             m["moe_rows"].observe(eng.moe_stats[0])
             m["moe_load"].observe(eng.moe_stats[1])
             m["moe_touched"].observe(eng.moe_stats[2])
+            m["moe_kernel"].observe(eng.moe_stats[3])
         if decode and eng.positions_read:
             m["positions_read"].inc(eng.positions_read)
         if decode and eng.window_held_pct is not None:

@@ -724,3 +724,126 @@ class TestPagedDecodeKernel:
         said = [r for r in caplog.records
                 if "fell back to the gather path" in r.getMessage()]
         assert len(said) == 1 and why in said[0].getMessage()
+
+
+class TestGroupedSwiGLU:
+    """`dl4tpu_grouped_swiglu` (interpret mode) inside
+    `moe.held_experts_swiglu` against XLA's `ragged_dot` path at small
+    widths with each expert configuration's F / D: which path a row count
+    takes, the layer's result, the blocks the grid reads, the gradient."""
+
+    # (D, F) at 128 columns with the configuration's F / D: LFM2 1,792 /
+    # 2,048, sarvam 2,048 / 4,096, command-a 4,096 / 4,096
+    WIDTHS = {"lfm2": (128, 112), "sarvam": (128, 64),
+              "command-a": (128, 128)}
+    E = 8
+
+    def _case(self, widths, n, k, route, dtype=jnp.float32):
+        D, F = self.WIDTHS[widths]
+        E = self.E
+        ks = jax.random.split(jax.random.PRNGKey(n * 7 + len(route)), 6)
+        h = jax.random.normal(ks[0], (n, D), jnp.float32).astype(dtype)
+        w = [(0.1 * jax.random.normal(kk, s, jnp.float32)).astype(dtype)
+             for kk, s in zip(ks[1:4], ((E, D, F), (E, D, F), (E, F, D)))]
+        gates = jax.random.uniform(ks[4], (n, k), jnp.float32, 0.1, 1.0)
+        valid = None
+        if route == "empty first and last":
+            chosen = jax.random.randint(ks[5], (n, k), 1, E - 1)
+        elif route == "one group":
+            chosen = jnp.full((n, k), 3)
+        elif route == "no routed rows":
+            chosen = jax.random.randint(ks[5], (n, k), 0, E)
+            valid = jnp.zeros((n,), bool)
+        elif route == "rows of no group":
+            # experts E .. E+3 are another chip's, the last tokens idle
+            chosen = jax.random.randint(ks[5], (n, k), 0, E + 4)
+            valid = jnp.arange(n) < n - 3
+        else:
+            chosen = jax.random.randint(ks[5], (n, k), 0, E)
+        return h, chosen.astype(jnp.int32), gates, w, valid
+
+    @staticmethod
+    def _layer(monkeypatch, kernels_on, case):
+        from deeplearning4j_tpu.nn.layers import moe
+        h, chosen, gates, w, valid = case
+        monkeypatch.setenv("DL4J_PALLAS_KERNELS", "1" if kernels_on else "0")
+
+        def fn(h, gates, wg, wu, wd, stats=None):
+            return moe.held_experts_swiglu(h, chosen, gates, wg, wu, wd,
+                                           first=0, valid=valid, stats=stats)
+        args = (h, gates, *w)
+        return fn, args, str(jax.make_jaxpr(fn)(*args))
+
+    @pytest.mark.parametrize("widths,n,k,route,dtype", [
+        ("lfm2", 12, 4, "empty first and last", jnp.float32),
+        ("sarvam", 12, 4, "one group", jnp.float32),
+        ("command-a", 12, 4, "no routed rows", jnp.float32),
+        ("lfm2", 12, 4, "rows of no group", jnp.float32),
+        ("sarvam", 9, 4, "spread", jnp.bfloat16),
+        ("command-a", 256, 4, "spread", jnp.float32),   # at the bound
+        ("lfm2", 260, 4, "spread", jnp.float32),        # a row tile past
+    ])
+    def test_kernel_path_matches_ragged_dot(self, monkeypatch, widths, n, k,
+                                            route, dtype):
+        from deeplearning4j_tpu.kernels import grouped_swiglu as gs
+        assert 256 * 4 == gs.MAX_ROWS
+        case = self._case(widths, n, k, route, dtype)
+        fn, args, xla_jaxpr = self._layer(monkeypatch, False, case)
+        want = np.asarray(jax.jit(fn)(*args), np.float32)
+        fn, args, jaxpr = self._layer(monkeypatch, True, case)
+        got = np.asarray(jax.jit(fn)(*args), np.float32)
+        assert "ragged_dot" in xla_jaxpr and "pallas_call" not in xla_jaxpr
+        if n * k <= gs.MAX_ROWS:
+            assert "pallas_call" in jaxpr and "ragged_dot" not in jaxpr
+        else:
+            assert jaxpr == xla_jaxpr
+        assert np.isfinite(got).all()
+        stats = {}
+        fn(*args, stats=stats)
+        kernel = n * k <= gs.MAX_ROWS
+        assert float(stats["moe_kernel_rows"]) == (
+            float(stats["moe_rows"]) if kernel else 0.0)
+        if route == "no routed rows":
+            assert not got.any() and not want.any()
+        scale = max(float(np.abs(want).max()), 1e-6)
+        tol = 2e-2 if dtype == jnp.bfloat16 else 1e-5
+        np.testing.assert_allclose(got, want, atol=tol * scale, rtol=0)
+
+    @pytest.mark.parametrize("sizes,nf", [
+        ([0, 3, 0, 0, 2, 0], 2), ([5, 0, 0, 0], 3), ([0, 0, 0, 0], 2),
+        ([1, 1, 1, 1], 1), ([0, 0, 0, 4], 4),
+    ])
+    def test_an_expert_with_no_rows_reads_no_weights(self, sizes, nf):
+        """Walking the grid in order, the weight block changes (a DMA)
+        only at the steps of an expert with rows, which read their own
+        blocks; a call with no rows at all reads one block."""
+        from deeplearning4j_tpu.kernels import grouped_swiglu as gs
+        starts, blk = gs.group_blocks(jnp.asarray(sizes, jnp.int32), nf)
+        seen, fetches = None, 0
+        for e in range(len(sizes)):
+            for j in range(nf):
+                b = tuple(int(x) for x in gs.weight_block(
+                    e, j, starts, blk, nf=nf))
+                if sizes[e]:
+                    assert b == (e, j)
+                fetches += b != seen
+                seen = b
+        assert fetches == max(1, nf * sum(s > 0 for s in sizes))
+
+    def test_gradient_through_the_layer_is_the_ragged_dot_paths(
+            self, monkeypatch):
+        case = self._case("lfm2", 12, 4, "rows of no group")
+        cot = jax.random.normal(jax.random.PRNGKey(9), case[0].shape)
+
+        def grads(on):
+            fn, args, _ = self._layer(monkeypatch, on, case)
+            g = jax.grad(lambda *a: jnp.sum(fn(*a) * cot),
+                         argnums=tuple(range(5)))
+            text = str(jax.make_jaxpr(g)(*args))
+            return jax.jit(g)(*args), text
+        want, xla_text = grads(False)
+        got, text = grads(True)
+        assert "pallas_call" in text and "pallas_call" not in xla_text
+        for a, b in zip(got, want):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6)

@@ -472,6 +472,10 @@ def test_greedy_through_the_server_is_the_reference(ref, built):
     rows = snap["serving_moe_rows"]["values"][0]
     assert touched["count"] == rows["count"] > 0
     assert 0 < touched["sum"] / touched["count"] <= 100
+    # every dispatch reports how many of its rows the grouped kernel
+    # multiplied: none on the CPU, where the layer takes `ragged_dot`
+    kernel = snap["serving_moe_kernel_pct"]["values"][0]
+    assert kernel["count"] == rows["count"] and kernel["sum"] == 0
     state = snap["serving_decode_state_gb"]["values"][0]
     assert state["count"] > 0 and state["sum"] / state["count"] == \
         pytest.approx(2 * srv.engine.pool.state_bytes() / 1e9)

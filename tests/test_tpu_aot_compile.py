@@ -336,6 +336,46 @@ print(json.dumps({"name": KERNEL_NAME, "in_place": att.paged_in_place(pools),
 '''
 
 
+_LFM2_EXPERTS = '''
+# lfm2_8b_serve_chat128's decode step under its server's arguments and its
+# 4 x 2,048 prefill, at LFM2's published widths with the configuration's
+# `hash_layers` (two layers of each kind: four routed layers of 32 experts
+# of 1,792), from shapes: the pools are never allocated
+sys.path.insert(0, "benchmark")
+import harness
+from deeplearning4j_tpu.kernels import grouped_swiglu as gs
+from deeplearning4j_tpu.nn.layers.statespace import HybridStateSpaceBlock
+from deeplearning4j_tpu.serving import PagedDecodeEngine
+pools = HybridStateSpaceBlock.paged_pool_arrays
+HybridStateSpaceBlock.paged_pool_arrays = lambda self, *a: jax.eval_shape(
+    lambda: pools(self, *a))
+_, cell, cfg = harness.load_cell("lfm2_8b_serve_chat128")
+cfg = dict(cfg, **cfg["hash_layers"])
+model = harness.load_module("models", cell["config"])
+ref = harness.load_module("reference", cell["config"])
+net = model.build(cfg)
+net.params = jax.eval_shape(lambda k: model.to_program(
+    ref.init_params(cfg, k), cfg), jax.random.PRNGKey(0))
+net.net_state, net.updater_state, net._initialized = {}, {}, True
+eng = PagedDecodeEngine(net, **cell["server"])
+decode = jax.jit(eng._decode_body(greedy_only=False), donate_argnums=2).lower(
+    *tree((eng._params, net.net_state, eng.pool.kv)
+          + tuple(eng._decode_args()))).compile()
+i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=S)
+prefill = jax.jit(eng._prefill_paged_body()).lower(
+    *tree((eng._params, net.net_state)), i32(4, 2048), i32(4)).compile()
+D, F = cfg["hidden_size"], cfg["moe_intermediate_size"]
+def read(c):
+    text = c.as_text()
+    return {"kernel": gs.KERNEL_NAME in text,
+            "ragged": sorted(set(re.findall(r"ragged-dot[-a-z]*", text))),
+            "temp": c.memory_analysis().temp_size_in_bytes}
+print(json.dumps({"decode": read(decode), "prefill": read(prefill),
+                  "rows": 128 * cfg["num_experts_per_tok"],
+                  "vmem": gs.vmem_bytes(512, D, gs.f_tile(D, F, 2), 2)}))
+'''
+
+
 def _child(body, *, import_package=True):
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     env.pop("LIBTPU_INIT_ARGS", None)
@@ -489,3 +529,18 @@ def test_the_libtpu_crash_the_workaround_exists_for_is_still_there():
     assert out is None
     assert proc.returncode < 0, (proc.returncode, proc.stderr[-2000:])
     assert "STACK OVERFLOW" in proc.stderr
+
+
+def test_lfm2_decode_step_takes_the_grouped_expert_kernel_on_v5e():
+    """`lfm2_8b_serve_chat128`'s decode step (512 routed rows a layer)
+    runs the held experts' products in `dl4tpu_grouped_swiglu`, no
+    `ragged_dot` left, and its temporaries stay under the 0.04 GB the
+    `ragged_dot` step had plus the kernel's VMEM; the 4 x 2,048 prefill
+    (32,768 rows) keeps XLA's `ragged_dot`."""
+    proc, out = _child(_LFM2_EXPERTS)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert out["rows"] == 512
+    assert out["decode"]["kernel"] is True and out["decode"]["ragged"] == []
+    assert out["decode"]["temp"] < 0.04e9 + out["vmem"]
+    assert out["prefill"]["kernel"] is False
+    assert "ragged-dot-none" in out["prefill"]["ragged"]
